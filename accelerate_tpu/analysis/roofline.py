@@ -122,23 +122,30 @@ _DEVICE_KIND_PREFIXES = (
 
 
 def chip_spec_for(chip: "str | Any | None" = None) -> ChipSpec:
-    """Resolve a ChipSpec from a spec-table name, a jax Device (via
-    ``device_kind``), or None (auto-detect the local device; `cpu` when no
-    TPU is attached)."""
+    """Resolve a ChipSpec from a spec-table name, a device-kind string, a
+    jax Device, or None (the local device). The `cpu` stand-in is returned
+    only for a CPU device or an explicit ``"cpu"``: an accelerator whose kind
+    is not in the table raises — its numbers must never be read off the
+    stand-in's."""
     if isinstance(chip, str):
         if chip in CHIP_SPECS:
             return CHIP_SPECS[chip]
-        kind = chip
-    elif chip is not None and hasattr(chip, "device_kind"):
-        kind = chip.device_kind
+        kind, platform = chip, None
     else:
-        import jax
+        if chip is None or not hasattr(chip, "device_kind"):
+            import jax
 
-        kind = getattr(jax.devices()[0], "device_kind", "cpu")
+            chip = jax.devices()[0]
+        kind, platform = chip.device_kind, getattr(chip, "platform", None)
     for prefix, name in _DEVICE_KIND_PREFIXES:
         if kind.startswith(prefix):
             return CHIP_SPECS[name]
-    return CHIP_SPECS["cpu"]
+    if platform == "cpu":
+        return CHIP_SPECS["cpu"]
+    raise ValueError(
+        f"no ChipSpec for device kind {kind!r}: add it to CHIP_SPECS / "
+        f"_DEVICE_KIND_PREFIXES (known: {sorted(CHIP_SPECS)})"
+    )
 
 
 # --------------------------------------------------------------- HLO parse
@@ -514,7 +521,24 @@ def _conv_flops(instr: HloInstr, comp: HloComputation) -> float:
     return 2.0 * out * (_elems(rhs_shape) / max(co, 1))
 
 
-def _rated_dtype(instr: HloInstr, comp: HloComputation) -> tuple[str, str]:
+def _is_convert(definition: HloInstr, comps: dict[str, HloComputation]) -> bool:
+    """A bare `convert`, or the single-op kLoop fusion XLA wraps one in
+    (``%wrapped_convert = fusion(...), calls=%wrapped_convert_computation``)."""
+    if definition.op == "convert":
+        return True
+    if definition.op != "fusion":
+        return False
+    m = _CALLS_RE.search(definition.attrs)
+    fused = comps.get(m.group(1)) if m else None
+    if fused is None:
+        return False
+    body = [fi.op for fi in fused.instrs if fi.op != "parameter"]
+    return body == ["convert"]
+
+
+def _rated_dtype(
+    instr: HloInstr, comp: HloComputation, comps: dict[str, HloComputation]
+) -> tuple[str, str]:
     """(rated dtype, upcast source) for a dot: when an operand is a convert
     from a narrower float/int (bf16->f32, s8->bf16...), rate the dot at the
     SOURCE dtype — that is what the program meant, and what a TPU MXU would
@@ -526,14 +550,19 @@ def _rated_dtype(instr: HloInstr, comp: HloComputation) -> tuple[str, str]:
         od, _, oname = _resolve_operand(instr, i, comp)
         src = od
         definition = comp.by_name.get(oname)
-        if definition is not None and definition.op == "convert" and definition.operands:
+        converted = (
+            definition is not None
+            and bool(definition.operands)
+            and _is_convert(definition, comps)
+        )
+        if converted:
             src_d, _, _ = _resolve_operand(definition, 0, comp)
             if src_d:
                 src = src_d
         nbytes = _DTYPE_BYTES.get(src, 4)
         if src in _PEAK_CLASS and nbytes < best_bytes:
             rated, best_bytes = src, nbytes
-            if definition is not None and definition.op == "convert":
+            if converted:
                 upcast_from = src
     return rated, upcast_from
 
@@ -660,7 +689,7 @@ def analyze_hlo(text: str, chip: ChipSpec) -> RooflineResult:
             else:
                 flops = _conv_flops(instr, comp)
                 batch, m, n, k = 1, _elems(instr.shape), 1, 1
-            rated, upcast = _rated_dtype(instr, comp)
+            rated, upcast = _rated_dtype(instr, comp, comps)
             nbytes = (instr.operand_bytes + instr.out_bytes) * mult
             info = DotInfo(
                 name=instr.name,

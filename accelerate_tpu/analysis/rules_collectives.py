@@ -28,7 +28,9 @@ from .findings import Finding, Severity
 from .hbm import human_bytes
 
 _CALLBACK_PRIMS = {"pure_callback", "io_callback"}
-_DEBUG_PRIMS = {"debug_callback"}
+# jax.debug.print traces to `debug_print`, jax.debug.callback / breakpoint to
+# `debug_callback`.
+_DEBUG_PRIMS = {"debug_callback", "debug_print"}
 
 _HLO_DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,

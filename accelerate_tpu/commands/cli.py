@@ -39,6 +39,9 @@ def main(argv: list[str] | None = None) -> int:
     if args.command is None:
         parser.print_help()
         return 1
+    from ..state import configure_compile_cache
+
+    configure_compile_cache()
     return args.func(args) or 0
 
 

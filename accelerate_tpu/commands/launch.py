@@ -534,6 +534,17 @@ def _fp8_speedup_for_local_devices() -> float | None:
     return fp8_telemetry.lookup(kind)
 
 
+def _local_children_would_share_tpu(cfg: LaunchConfig, args) -> bool:
+    """Would N local children each try to open this host's TPU? Not when
+    they are pinned to the CPU (``--host_devices`` or ``JAX_PLATFORMS=cpu``
+    in the environment they inherit); otherwise ask a probe process what
+    the default device is."""
+    platforms = cfg.extra_env.get("JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", ""))
+    if args.host_devices or platforms.strip().lower() == "cpu":
+        return False
+    return (_probe_device_kind() or "").startswith("TPU")
+
+
 def _probe_device_kind() -> str | None:
     import subprocess
 
@@ -574,6 +585,18 @@ def run(args: argparse.Namespace) -> int:
     if cfg.tpu_name:
         return _tpu_pod_launch(cfg, cmd, args)
     if cfg.num_processes > 1:
+        if _local_children_would_share_tpu(cfg, args):
+            print(
+                f"[accelerate-tpu launch] refusing --num_processes "
+                f"{cfg.num_processes} on a TPU host: one process drives all "
+                "local chips (a chip belongs to one process at a time, so "
+                "the children would fail or hang on each other). Launch one "
+                "process and shard over the chips with the mesh flags "
+                "(--data/--fsdp/--tensor), or pass --host_devices N for the "
+                "CPU simulation.",
+                file=sys.stderr,
+            )
+            return 2
         return _local_multiprocess_launch(cfg, cmd, args)
     # Single host process: exec in place with the env contract.
     if cfg.max_restarts:

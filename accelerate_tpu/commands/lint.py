@@ -1018,7 +1018,7 @@ def _mh_scenario_router_recovery(processes: int = 2):
                 threads=False,
                 readmit_secs=0.001,
                 probation_completions=1,
-                engine_factory=mk_engine,
+                engine_factory=lambda _replica: mk_engine(),
             )
             for r in reqs:
                 router.submit_request(r)

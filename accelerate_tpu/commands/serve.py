@@ -216,6 +216,17 @@ def _build_model(name: str):
     )
 
 
+def replica_params(params, replica: int):
+    """Replica ``replica``'s weights, committed to local device
+    ``replica mod n``: an `Engine` keeps its KV pools where its weights live,
+    so N replicas spread over the host's chips instead of stacking on chip 0
+    (on one device they share one copy)."""
+    import jax
+
+    devices = jax.local_devices()
+    return jax.device_put(params, devices[replica % len(devices)])
+
+
 def run(args: argparse.Namespace) -> int:
     import numpy as np
 
@@ -244,11 +255,11 @@ def run(args: argparse.Namespace) -> int:
         rounded = min((b for b in bs if b >= longest), default=None)
         top = rounded if rounded is not None else -(-longest // bs[-1]) * bs[-1]
         max_len = top + new_tokens[1]
-    def mk_engine() -> Engine:
+    def mk_engine(replica: int = 0) -> Engine:
         return Engine(
             apply_fn,
             init_cache_fn,
-            params,
+            replica_params(params, replica),
             config,
             slots=args.slots,
             buckets=buckets,
@@ -264,7 +275,7 @@ def run(args: argparse.Namespace) -> int:
 
         # SIGTERM now means "drain, then exit 75" instead of dying mid-token.
         resilience.install_preemption_handler()
-        engines = [mk_engine() for _ in range(args.replicas)]
+        engines = [mk_engine(i) for i in range(args.replicas)]
         engine = engines[0]
         router = Router(
             engines,
@@ -272,8 +283,9 @@ def run(args: argparse.Namespace) -> int:
             affinity=args.affinity,
             scheduling=args.scheduling,
             readmit_secs=args.readmit_secs,
-            # A fatally wedged replica is rebuilt from scratch at probe
-            # time rather than trusting mid-step engine state.
+            # A fatally wedged replica is rebuilt from scratch (on its own
+            # device) at probe time rather than trusting mid-step engine
+            # state.
             engine_factory=mk_engine,
         )
     else:

@@ -31,7 +31,10 @@ Params = Any
 
 
 def truncated_normal_init(rng: jax.Array, shape: tuple[int, ...], stddev: float, dtype=jnp.float32) -> jax.Array:
-    return jax.random.truncated_normal(rng, -2.0, 2.0, shape, jnp.float32).astype(dtype) * stddev
+    # Scale in f32, then cast: a numpy-scalar stddev is not weakly typed, so
+    # `bf16_array * np.float64` would silently promote the result to f32.
+    sample = jax.random.truncated_normal(rng, -2.0, 2.0, shape, jnp.float32)
+    return (sample * stddev).astype(dtype)
 
 
 def remat_policy(name: str):
@@ -371,14 +374,11 @@ def cached_decode_attention(
     carries the raw int8 cache + scales so the kernel fuses the dequant.
     """
     if lengths is not None and window is None and q.shape[1] == 1:
-        try:
-            from ..native.pallas.decode_attention import maybe_flash_decode
-        except Exception:  # pragma: no cover - environment dependent
-            maybe_flash_decode = None
-        if maybe_flash_decode is not None:
-            out = maybe_flash_decode(q, k_full, v_full, lengths, kv_raw=kv_raw)
-            if out is not None:
-                return out
+        from ..native.pallas.decode_attention import maybe_flash_decode
+
+        out = maybe_flash_decode(q, k_full, v_full, lengths, kv_raw=kv_raw)
+        if out is not None:
+            return out
     return dot_product_attention(q, k_full, v_full, mask=mask)
 
 

@@ -1,11 +1,10 @@
 """Pallas hot-path kernel tier (ROADMAP direction 3).
 
-Custom TPU kernels for the three measured hot paths the XLA lowerings leave
-on the table (BENCH_r05): flash-decode attention over the slot KV cache
-(`kv16k_int8_speedup` 1.016 — decode attention ignores KV-quantization
-bandwidth headroom), fused quantize→dot→rescale matmuls for the int8/fp8
-paths (`fp8_matmul_speedup` 1.004 — fp8 round-trips through XLA's upcast),
-and a single-pass fused AdamW update (`hostoffload_adamw_mfu` 0.0898).
+Custom TPU kernels for three hot paths the XLA lowerings leave on the
+table: flash-decode attention over the slot KV cache (the fallback ignores
+KV-quantization bandwidth headroom), fused quantize→dot→rescale matmuls for
+the int8/fp8 paths (fp8 round-trips through XLA's upcast), and a
+single-pass fused AdamW update (the host-offloaded optimizer tier).
 
 Every kernel sits behind the dispatch-by-availability registry in
 `dispatch.py`: TPU backend + pallas importable + shape/dtype supported →
@@ -23,3 +22,8 @@ from .dispatch import (  # noqa: F401
     pallas_available,
     register_kernel,
 )
+
+# Importing a kernel module registers it: `kernel_status()` lists every
+# kernel as soon as the package is imported, not only those a trace has
+# already reached.
+from . import decode_attention, fused_adamw, quant_matmul  # noqa: E402,F401

@@ -78,22 +78,22 @@ def _decode_kernel(
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
 
     # Blocks entirely past the cursor contribute nothing — skip the flops
     # (this is where short sequences in a long-max_len cache win).
     @pl.when(t * blk < length)
     def _block():
         q = q_ref[0, 0].astype(jnp.float32)  # (group, h)
-        k = k_ref[0, 0].astype(jnp.float32)  # (blk, h)
-        if ks_ref is not None:
-            k = k * ks_ref[0, 0].astype(jnp.float32)[:, None]
-        s = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            * scale
+        k = k_ref[0].astype(jnp.float32)  # (blk, h)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (group, blk)
+        if ks_ref is not None:
+            # Per-token dequant scale applied to the score column it belongs
+            # to: (q . k_q) * s == q . (k_q * s), on (group, blk) not (blk, h).
+            s = s * ks_ref[0, 0].astype(jnp.float32)  # (1, blk) row
+        s = s * scale
         cols = t * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(cols < length, s, _NEG_INF)
 
@@ -102,13 +102,15 @@ def _decode_kernel(
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)  # (group, blk)
 
-        v = v_ref[0, 0].astype(jnp.float32)  # (blk, h)
-        if vs_ref is not None:
-            v = v * vs_ref[0, 0].astype(jnp.float32)[:, None]
-
         l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = p
+        if vs_ref is not None:
+            pv = p * vs_ref[0, 0].astype(jnp.float32)
         acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            pv,
+            v_ref[0].astype(jnp.float32),  # (blk, h)
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
         m_s[...] = m_new
 
@@ -117,16 +119,29 @@ def _decode_kernel(
         o_ref[0, 0] = (acc_s[...] / jnp.maximum(l_s[...], 1e-30)).astype(o_ref.dtype)
 
 
-def supported(q: jax.Array, k: jax.Array) -> bool:
+def supported(q: jax.Array, k: jax.Array, *, compiled: bool = False, quantized: bool = False) -> bool:
     """Shape support: one query token per row, GQA-divisible heads, and a
-    cache length some tile divides exactly (the kernel never pads)."""
+    cache length some tile divides exactly (the kernel never pads).
+    ``compiled`` adds what Mosaic's (8, 128) tiling asks of the blocks: the
+    per-head slice of the flattened (K*h) axis is a whole number of lane
+    tiles, and the int8 scale rows (``quantized``) are lane-aligned too."""
     if q.ndim != 4 or k.ndim != 4 or q.shape[1] != 1:
         return False
     B, _, H, h = q.shape
     T, K = k.shape[1], k.shape[2]
     if k.shape[0] != B or k.shape[3] != h or H % K != 0:
         return False
-    return pick_block(T) is not None
+    blk = pick_block(T)
+    if blk is None:
+        return False
+    if compiled:
+        if h % 128 != 0 and K != 1:
+            return False
+        if blk % 8 != 0 and blk != T:
+            return False
+        if quantized and blk % 128 != 0 and blk != T:
+            return False
+    return True
 
 
 def flash_decode(
@@ -155,26 +170,28 @@ def flash_decode(
     scale = scale if scale is not None else float(1.0 / (h**0.5))
 
     qt = q.reshape(B, K, group, h)  # head = kk * group + g, the oracle's layout
-    kt = k.transpose(0, 2, 1, 3)  # (B, K, T, h)
-    vt = v.transpose(0, 2, 1, 3)
-    lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1, 1), (B, 1))
+    # The cache is read where it lies: (B, T, K*h) is a free view, and head
+    # kk is lane-block kk of its last axis — no transposed copy per step.
+    kt = k.reshape(B, T, K * h)
+    vt = v.reshape(B, T, K * h)
+    # The cursors ride scalar prefetch (SMEM), read per program by batch row.
+    lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1), (B,))
 
-    qkv_specs = [
-        pl.BlockSpec((1, 1, group, h), lambda b, kk, t: (b, kk, 0, 0)),
-        pl.BlockSpec((1, 1, blk, h), lambda b, kk, t: (b, kk, t, 0)),
-    ]
-    scale_spec = pl.BlockSpec((1, 1, blk), lambda b, kk, t: (b, kk, t))
-    len_spec = pl.BlockSpec((1, 1), lambda b, kk, t: (b, 0))
+    q_spec = pl.BlockSpec((1, 1, group, h), lambda b, kk, t, lens: (b, kk, 0, 0))
+    kv_spec = pl.BlockSpec((1, blk, h), lambda b, kk, t, lens: (b, t, kk))
+    # Scales as lane-dense rows: (B, T, K) -> (B, K, 1, T), block (1, blk).
+    # The transpose moves 1/h of the cache bytes.
+    scale_spec = pl.BlockSpec((1, 1, 1, blk), lambda b, kk, t, lens: (b, kk, 0, t))
 
-    operands = [lengths, qt, kt]
-    in_specs = [len_spec, qkv_specs[0], qkv_specs[1]]
+    operands = [qt, kt]
+    in_specs = [q_spec, kv_spec]
     if k_scale is not None:
-        operands.append(k_scale.transpose(0, 2, 1))
+        operands.append(k_scale.transpose(0, 2, 1)[:, :, None, :])
         in_specs.append(scale_spec)
     operands.append(vt)
-    in_specs.append(qkv_specs[1])
+    in_specs.append(kv_spec)
     if v_scale is not None:
-        operands.append(v_scale.transpose(0, 2, 1))
+        operands.append(v_scale.transpose(0, 2, 1)[:, :, None, :])
         in_specs.append(scale_spec)
 
     kernel = functools.partial(
@@ -187,17 +204,20 @@ def flash_decode(
     )
     out = pl.pallas_call(
         kernel,
-        grid=(B, K, n_blocks),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, group, h), lambda b, kk, t: (b, kk, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, K, n_blocks),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((group, 1), jnp.float32),
+                pltpu.VMEM((group, 1), jnp.float32),
+                pltpu.VMEM((group, h), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, K, group, h), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, h), jnp.float32),
-        ],
         **tuned_call_kwargs(interpret, ("parallel", "parallel", "arbitrary")),
-    )(*operands)
+    )(lengths, *operands)
     return out.reshape(B, 1, H, h)
 
 
@@ -225,7 +245,9 @@ def maybe_flash_decode(
     lowering). ``kv_raw = (k_q, k_scale, v_q, v_scale)`` hands over the raw
     int8 cache so dequant fuses into the kernel."""
     mode = kernel_mode("decode_attn")
-    if mode is None or not supported(q, k):
+    if mode is None or not supported(
+        q, k, compiled=mode == "compiled", quantized=kv_raw is not None
+    ):
         return None
     interpret = mode == "interpret"
     if kv_raw is not None:

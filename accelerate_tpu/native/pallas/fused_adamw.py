@@ -15,10 +15,11 @@ The math replicates `_adamw_slice` literally (same op order, same dtypes,
 sqrt lower with TPU semantics (reciprocal / rsqrt refinement) inside the
 kernel. The disk tier's numpy-namespace call never dispatches here.
 
-Leaves are viewed as (rows, block) over their flattened size; a leaf whose
-size has no usable block divisor, or is too small to be worth a kernel
-launch, falls back per leaf — mixing kernel and fallback leaves within one
-tree step is fine, each leaf's update is independent.
+Leaves are viewed as (rows, lanes) over their flattened size and walked in
+(block_rows, lanes) slabs — whole (8, 128) tiles, which is what Mosaic
+lowers. A leaf whose size has no such view, or is too small to be worth a
+kernel launch, falls back per leaf — mixing kernel and fallback leaves
+within one tree step is fine, each leaf's update is independent.
 """
 
 from __future__ import annotations
@@ -36,6 +37,7 @@ register_kernel(
 
 if pallas_available():
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     from ...ops.autotune import cached_pick_block, tuned_call_kwargs
 
@@ -44,7 +46,7 @@ if pallas_available():
         # $ATX_AUTOTUNE_DIR), divide-exactly heuristic otherwise.
         return cached_pick_block("fused_adamw", dim, candidates)
 else:  # pragma: no cover - environment dependent
-    pl = None
+    pl = pltpu = None
 
     def pick_block(dim, candidates=(512, 256, 128, 64, 32, 16, 8)):
         return None
@@ -52,14 +54,17 @@ else:  # pragma: no cover - environment dependent
 # Below this many elements the launch overhead beats the fusion win
 # (norms, biases, tiny heads) — those leaves take the XLA fallback.
 _MIN_SIZE = 1024
-_BLOCKS = (16384, 8192, 4096, 2048, 1024, 512, 256, 128)
+_LANES = (1024, 512, 256, 128)
+# 128 rows x 1024 lanes x f32 = 512 KiB per operand slab; seven operands,
+# double-buffered, stay under half of the 16 MiB scoped VMEM.
+_BLOCK_ROWS = (128, 64, 32, 16, 8)
 
 
 def _adamw_kernel(
     s_ref, g_ref, mu_ref, nu_ref, p_ref, u_ref, mu_out, nu_out,
     *, b1, b2, eps, weight_decay, has_grad_scale,
 ):
-    # `_adamw_slice` verbatim, one (1, block) slab at a time.
+    # `_adamw_slice` verbatim, one (block_rows, lanes) slab at a time.
     mu = mu_ref[...]
     nu = nu_ref[...]
     g32 = g_ref[...].astype(mu.dtype)
@@ -67,9 +72,10 @@ def _adamw_kernel(
         g32 = g32 * s_ref[0, 2].astype(mu.dtype)
     new_mu = b1 * mu + (1.0 - b1) * g32
     new_nu = b2 * nu + (1.0 - b2) * jnp.square(g32)
-    c = s_ref[0, 0].astype(new_mu.dtype)
-    mu_hat = new_mu / (1.0 - b1**c)
-    nu_hat = new_nu / (1.0 - b2**c)
+    # s_ref[0, 0] / [0, 3] are the bias corrections 1 - b**count, taken
+    # outside: Mosaic has no scalar pow ("failed to legalize 'math.powf'").
+    mu_hat = new_mu / s_ref[0, 0].astype(new_mu.dtype)
+    nu_hat = new_nu / s_ref[0, 3].astype(new_mu.dtype)
     step = mu_hat / (jnp.sqrt(nu_hat) + eps) + weight_decay * p_ref[...].astype(
         new_mu.dtype
     )
@@ -79,12 +85,21 @@ def _adamw_kernel(
 
 
 def _plan(size: int):
+    """``(rows, lanes, block_rows)`` for a flat leaf of ``size`` elements, or
+    ``None``: lanes a multiple of 128 dividing the size, block rows a
+    multiple of 8 dividing the rows (or all of them when fewer than 8)."""
     if size < _MIN_SIZE:
         return None
-    blk = pick_block(size, _BLOCKS)
-    if blk is None:
+    lanes = pick_block(size, _LANES)
+    if lanes is None or lanes % 128 != 0:
         return None
-    return size // blk, blk
+    rows = size // lanes
+    block_rows = rows if rows < 8 else next(
+        (b for b in _BLOCK_ROWS if rows % b == 0), None
+    )
+    if block_rows is None:
+        return None
+    return rows, lanes, block_rows
 
 
 def fused_adamw_update(
@@ -103,21 +118,22 @@ def fused_adamw_update(
     # the hyperparams) can't be closed over — fall back.
     if not all(isinstance(hp, (int, float)) for hp in (b1, b2, eps, weight_decay)):
         return None
-    rows, blk = plan
+    rows, lanes, block_rows = plan
+    c = jnp.asarray(count).astype(mu.dtype).reshape(())
     scalars = jnp.stack(
         [
-            jnp.asarray(count).astype(jnp.float32).reshape(()),
+            (1.0 - b1**c).astype(jnp.float32),
             jnp.asarray(lr_t).astype(jnp.float32).reshape(()),
             (
                 jnp.asarray(grad_scale).astype(jnp.float32).reshape(())
                 if grad_scale is not None
                 else jnp.zeros((), jnp.float32)
             ),
-            jnp.zeros((), jnp.float32),
+            (1.0 - b2**c).astype(jnp.float32),
         ]
     ).reshape(1, 4)
-    view = lambda a: a.reshape(rows, blk)
-    row_spec = pl.BlockSpec((1, blk), lambda i: (i, 0))
+    view = lambda a: a.reshape(rows, lanes)
+    row_spec = pl.BlockSpec((block_rows, lanes), lambda i: (i, 0))
     kernel = functools.partial(
         _adamw_kernel,
         b1=b1,
@@ -128,13 +144,14 @@ def fused_adamw_update(
     )
     u, new_mu, new_nu = pl.pallas_call(
         kernel,
-        grid=(rows,),
-        in_specs=[pl.BlockSpec((1, 4), lambda i: (0, 0))] + [row_spec] * 4,
+        grid=(rows // block_rows,),
+        # count / lr / grad-scale are scalars: SMEM, whole array.
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [row_spec] * 4,
         out_specs=[row_spec] * 3,
         out_shape=[
-            jax.ShapeDtypeStruct((rows, blk), mu.dtype),
-            jax.ShapeDtypeStruct((rows, blk), mu.dtype),
-            jax.ShapeDtypeStruct((rows, blk), nu.dtype),
+            jax.ShapeDtypeStruct((rows, lanes), mu.dtype),
+            jax.ShapeDtypeStruct((rows, lanes), mu.dtype),
+            jax.ShapeDtypeStruct((rows, lanes), nu.dtype),
         ],
         # Moments update in place; the scalars/g/p operands stay read-only.
         input_output_aliases={2: 1, 3: 2},

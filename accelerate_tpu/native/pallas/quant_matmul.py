@@ -8,23 +8,26 @@ one of four orientations, reached by reshape (never a physical transpose).
 `_parse_matmul_eq` proves that per equation; anything it can't prove falls
 back to the reference lowering.
 
-Two kernels share the tiling (grid over (M, N) tiles, contraction axis
-resident per program):
+Two kernels share the tiling — a grid over (M, N, C) tiles with the
+contraction innermost, accumulated in a VMEM scratch, so the staged
+operands stay a few MiB whatever the contraction length (an 8B-width
+prefill, C = 14336, does not fit VMEM whole):
 
-- :func:`int8_matmul_fused` — the whole `ops.int8.int8_einsum` body in one
-  pass: per-row dynamic activation quantization (amax/127), int8×int8→int32
-  dot on the MXU, rescale by ``row scale × per-channel weight scale``.
-  Integer accumulation is exact and the elementwise ops replicate
-  `quantize_act` literally; the one divergence from the fallback is the
-  activation-scale divide, which Pallas lowers with TPU semantics
-  (reciprocal-multiply, 1 ulp off IEEE) — parity is ~1e-7 relative, not
-  bitwise, and the quantize/rescale never round-trip through HBM.
+- :func:`int8_matmul_fused` — the `ops.int8.int8_einsum` body: per-row
+  dynamic activation quantization (amax/127), int8×int8→int32 dot on the
+  MXU, rescale by ``row scale × per-channel weight scale``. The per-row
+  scale needs the whole row, so it is one small XLA reduction outside; the
+  quantized activations and the int32 accumulator never round-trip through
+  HBM. Integer accumulation is exact (tiling the contraction changes
+  nothing) and the elementwise ops replicate `quantize_act` literally; the
+  one divergence from the fallback is the activation-scale divide, which
+  Pallas lowers with TPU semantics (reciprocal-multiply, 1 ulp off IEEE) —
+  parity is ~1e-7 relative, not bitwise.
 - :func:`scaled_matmul` — the fp8 contraction `(dot(qx, qw) * scale)` with
   fp8 operands fed to the MXU directly (``preferred_element_type=f32``)
-  instead of XLA's materialized upcast (the flat 1.004
-  ``fp8_matmul_speedup``). Quantization stays OUTSIDE (the custom_vjp
-  residuals carry qx/qw for the backward); parity is to f32 tolerance
-  (different accumulation order), not bitwise.
+  instead of XLA's materialized upcast. Quantization stays OUTSIDE (the
+  custom_vjp residuals carry qx/qw for the backward); parity is to f32
+  tolerance (different accumulation order), not bitwise.
 """
 
 from __future__ import annotations
@@ -45,23 +48,27 @@ register_kernel(
 
 if pallas_available():
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     from ...ops.autotune import cached_pick_block, tuned_call_kwargs
+    from ...ops.flash_attention import pick_block as _divisor
 
     def pick_block(dim, candidates=(512, 256, 128, 64, 32, 16, 8)):
         # Persisted autotune table first (ATX_BLOCK_QUANT_MATMUL /
         # $ATX_AUTOTUNE_DIR), divide-exactly heuristic otherwise.
         return cached_pick_block("quant_matmul", dim, candidates)
 else:  # pragma: no cover - environment dependent
-    pl = None
+    pl = pltpu = None
 
     def pick_block(dim, candidates=(512, 256, 128, 64, 32, 16, 8)):
         return None
 
 
-# Contraction axes larger than this would blow the resident-operand VMEM
-# budget per program; such shapes (none in the model zoo today) fall back.
-_MAX_CONTRACT = 65536
+# Contraction tile: the staged (bm, bc) activations, their f32 quantization
+# temporaries and the (bc, bn) weights, double-buffered, stay near 10 MiB.
+_CONTRACT_BLOCKS = (1024, 512, 256, 128)
+# What one program may stage, against the chip's 16 MiB scoped-VMEM limit.
+_VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _parse_matmul_eq(eq: str):
@@ -98,26 +105,47 @@ def _parse_matmul_eq(eq: str):
     return oa, ob, len(a_rest), len(b_rest)
 
 
-def _plan(eq: str, a_shape, b_shape):
-    """2D views + tiles for ``eq``: ``(oa, ob, M, N, C, bm, bn, out_shape)``
-    or ``None`` when unsupported."""
+def _tile(dim: int, unit: int, pick) -> int:
+    """A tile of ``dim`` that Mosaic lowers: a multiple of ``unit`` (8
+    sublanes / 128 lanes) dividing it exactly, else the whole axis."""
+    blk = pick(dim)
+    return blk if blk is not None and blk % unit == 0 else dim
+
+
+def _plan(eq: str, a, b, out_dtype):
+    """2D views + tiles for ``eq``:
+    ``(oa, ob, M, N, C, bm, bn, bc, a_rest, b_rest)`` (the output shape is
+    ``a_rest + b_rest``), or ``None`` when the equation is not a matmul or
+    no tiling fits the VMEM budget."""
     parsed = _parse_matmul_eq(eq)
     if parsed is None:
         return None
     oa, ob, na, nb = parsed
+    a_shape, b_shape = a.shape, b.shape
     a_rest = a_shape[:na] if oa == "trail" else a_shape[-na:]
     b_rest = b_shape[-nb:] if ob == "lead" else b_shape[:nb]
     c_dims = a_shape[na:] if oa == "trail" else a_shape[: len(a_shape) - na]
     M = int(functools.reduce(lambda x, y: x * y, a_rest, 1))
     N = int(functools.reduce(lambda x, y: x * y, b_rest, 1))
     C = int(functools.reduce(lambda x, y: x * y, c_dims, 1))
-    if M == 0 or N == 0 or C == 0 or C > _MAX_CONTRACT:
+    if M == 0 or N == 0 or C == 0:
         return None
-    bm = pick_block(M) or (M if M <= 1024 else None)
-    bn = pick_block(N) or (N if N <= 1024 else None)
-    if bm is None or bn is None:
+    # M is the sublane axis of the output tile, and the lane axis of a
+    # leading-contracted (C, M) operand view.
+    bm = _tile(M, 128 if oa == "lead" else 8, pick_block)
+    bn = _tile(N, 128, pick_block)
+    bc = _tile(C, 128, lambda d: _divisor(d, _CONTRACT_BLOCKS))
+    a_item, b_item = jnp.dtype(a.dtype).itemsize, jnp.dtype(b.dtype).itemsize
+    staged = (
+        2 * bm * bc * a_item
+        + 2 * bc * bn * b_item
+        + 2 * bm * bn * jnp.dtype(out_dtype).itemsize
+        + bm * bn * 4  # accumulator scratch
+        + bm * bc * 9  # in-kernel quantization temporaries (2 x f32 + int8)
+    )
+    if staged > _VMEM_BUDGET:
         return None
-    return oa, ob, M, N, C, bm, bn, tuple(a_rest) + tuple(b_rest)
+    return oa, ob, M, N, C, bm, bn, bc, tuple(a_rest), tuple(b_rest)
 
 
 def _views(oa, ob, a, b, M, N, C):
@@ -126,15 +154,15 @@ def _views(oa, ob, a, b, M, N, C):
     return a2, b2
 
 
-def _specs(oa, ob, bm, bn, C):
+def _specs(oa, ob, bm, bn, bc):
     if oa == "trail":
-        a_spec = pl.BlockSpec((bm, C), lambda i, j: (i, 0))
+        a_spec = pl.BlockSpec((bm, bc), lambda i, j, c: (i, c))
     else:
-        a_spec = pl.BlockSpec((C, bm), lambda i, j: (0, i))
+        a_spec = pl.BlockSpec((bc, bm), lambda i, j, c: (c, i))
     if ob == "lead":
-        b_spec = pl.BlockSpec((C, bn), lambda i, j: (0, j))
+        b_spec = pl.BlockSpec((bc, bn), lambda i, j, c: (c, j))
     else:
-        b_spec = pl.BlockSpec((bn, C), lambda i, j: (j, 0))
+        b_spec = pl.BlockSpec((bn, bc), lambda i, j, c: (j, c))
     return a_spec, b_spec
 
 
@@ -144,23 +172,59 @@ def _dot_dims(oa, ob):
     return (((ca,), (cb,)), ((), ()))
 
 
-def _int8_kernel(a_ref, b_ref, ws_ref, o_ref, *, dims):
-    # `quantize_act` verbatim, per (bm) row block, then an exact integer
-    # dot; only the scale divide (TPU reciprocal semantics) can differ
-    # from the fallback, by 1 ulp.
+def _accumulate(acc_ref, part):
+    @pl.when(pl.program_id(2) == 0)
+    def _first():
+        acc_ref[...] = part
+
+    @pl.when(pl.program_id(2) != 0)
+    def _rest():
+        acc_ref[...] += part
+
+
+def _int8_kernel(a_ref, sx_ref, b_ref, ws_ref, o_ref, acc_ref, *, dims):
+    # `quantize_act`'s rounding verbatim on one (bm, bc) tile against the
+    # row scale, then an exact integer dot; only the scale divide (TPU
+    # reciprocal semantics) can differ from the fallback, by 1 ulp.
+    sx = sx_ref[...]  # (bm, 1)
     xf = a_ref[...].astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf), axis=1, keepdims=True)
-    sx = jnp.maximum(amax, 1e-12) / 127.0
     q = jnp.clip(jnp.round(xf / sx), -127, 127).astype(jnp.int8)
-    acc = jax.lax.dot_general(q, b_ref[...], dims, preferred_element_type=jnp.int32)
-    o_ref[...] = (acc.astype(jnp.float32) * (sx * ws_ref[...])).astype(o_ref.dtype)
-
-
-def _scaled_kernel(a_ref, b_ref, s_ref, o_ref, *, dims):
-    acc = jax.lax.dot_general(
-        a_ref[...], b_ref[...], dims, preferred_element_type=jnp.float32
+    _accumulate(
+        acc_ref,
+        jax.lax.dot_general(q, b_ref[...], dims, preferred_element_type=jnp.int32),
     )
-    o_ref[...] = (acc * s_ref[0, 0]).astype(o_ref.dtype)
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _finish():
+        o_ref[...] = (
+            acc_ref[...].astype(jnp.float32) * (sx * ws_ref[...])
+        ).astype(o_ref.dtype)
+
+
+def _scaled_kernel(a_ref, b_ref, s_ref, o_ref, acc_ref, *, dims):
+    _accumulate(
+        acc_ref,
+        jax.lax.dot_general(
+            a_ref[...], b_ref[...], dims, preferred_element_type=jnp.float32
+        ),
+    )
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _finish():
+        o_ref[...] = (acc_ref[...] * s_ref[0, 0]).astype(o_ref.dtype)
+
+
+def _tiled_call(kernel, plan, in_specs, out_dtype, acc_dtype, interpret):
+    _, _, M, N, C, bm, bn, bc, _, _ = plan
+    return pl.pallas_call(
+        kernel,
+        grid=(M // bm, N // bn, C // bc),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, c: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
+        **tuned_call_kwargs(interpret, ("parallel", "parallel", "arbitrary")),
+    )
 
 
 def int8_matmul_fused(
@@ -176,30 +240,37 @@ def int8_matmul_fused(
     trailing axes (per-row groups = rows of the 2D view) and w on its
     leading axes — true for every int8 forward equation. ``None`` when the
     equation/shapes aren't supported (caller falls back)."""
-    plan = _plan(eq, x.shape, wq.shape)
+    plan = _plan(eq, x, wq, x.dtype)
     if plan is None:
         return None
-    oa, ob, M, N, C, bm, bn, out_shape = plan
+    oa, ob, M, N, C, bm, bn, bc, a_rest, b_rest = plan
     if oa != "trail" or ob != "lead":
         return None
     x2, w2 = _views(oa, ob, x, wq, M, N, C)
-    # Contracted axes of w_scale are size 1 (quantizer keepdims): the value
-    # layout is exactly the per-output-channel vector.
-    ws2 = w_scale.astype(jnp.float32).reshape(1, N)
-    a_spec, b_spec = _specs(oa, ob, bm, bn, C)
-    out = pl.pallas_call(
+    # `quantize_act`'s per-row scale, over the whole contraction.
+    amax = jnp.max(jnp.abs(x2.astype(jnp.float32)), axis=1, keepdims=True)
+    sx = jnp.maximum(amax, 1e-12) / 127.0
+    # w_scale has w's rank: contracted axes are size 1 (quantizer keepdims)
+    # and so is any output axis that shares one scale (per-head weights
+    # quantize per head_dim channel) — broadcast to the per-column vector.
+    ws2 = jnp.broadcast_to(
+        w_scale.astype(jnp.float32), (1,) * (wq.ndim - len(b_rest)) + b_rest
+    ).reshape(1, N)
+    a_spec, b_spec = _specs(oa, ob, bm, bn, bc)
+    out = _tiled_call(
         functools.partial(_int8_kernel, dims=_dot_dims(oa, ob)),
-        grid=(M // bm, N // bn),
-        in_specs=[
+        plan,
+        [
             a_spec,
+            pl.BlockSpec((bm, 1), lambda i, j, c: (i, 0)),
             b_spec,
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j, c: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        **tuned_call_kwargs(interpret, ("parallel", "parallel")),
-    )(x2, w2, ws2)
-    return out.reshape(out_shape)
+        x.dtype,
+        jnp.int32,
+        interpret,
+    )(x2, sx, w2, ws2)
+    return out.reshape(a_rest + b_rest)
 
 
 def scaled_matmul(
@@ -215,26 +286,22 @@ def scaled_matmul(
     as one kernel — the fp8 forward/backward contraction without the
     materialized upcast. ``scale`` is the scalar product of the per-tensor
     scales. ``None`` when unsupported."""
-    plan = _plan(eq, qa.shape, qb.shape)
+    plan = _plan(eq, qa, qb, out_dtype)
     if plan is None:
         return None
-    oa, ob, M, N, C, bm, bn, out_shape = plan
+    oa, ob, M, N, C, bm, bn, bc, a_rest, b_rest = plan
     a2, b2 = _views(oa, ob, qa, qb, M, N, C)
     s2 = jnp.asarray(scale, jnp.float32).reshape(1, 1)
-    a_spec, b_spec = _specs(oa, ob, bm, bn, C)
-    out = pl.pallas_call(
+    a_spec, b_spec = _specs(oa, ob, bm, bn, bc)
+    out = _tiled_call(
         functools.partial(_scaled_kernel, dims=_dot_dims(oa, ob)),
-        grid=(M // bm, N // bn),
-        in_specs=[
-            a_spec,
-            b_spec,
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        **tuned_call_kwargs(interpret, ("parallel", "parallel")),
+        plan,
+        [a_spec, b_spec, pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_dtype,
+        jnp.float32,
+        interpret,
     )(a2, b2, s2)
-    return out.reshape(out_shape)
+    return out.reshape(a_rest + b_rest)
 
 
 def maybe_int8_matmul(

@@ -36,10 +36,7 @@ _DEFAULT_CANDIDATES = (512, 256, 128, 64, 32, 16, 8)
 def _chip_name() -> str:
     from ..analysis.roofline import chip_spec_for
 
-    try:
-        return chip_spec_for().name
-    except Exception:
-        return "cpu"
+    return chip_spec_for().name
 
 
 def _env_override(op: str) -> int | None:

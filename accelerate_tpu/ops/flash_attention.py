@@ -58,25 +58,11 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _compiler_params():
-    """Mark (B, H, Q-blocks) parallel, KV-blocks sequential (the scratch
-    carry). Best-effort across pallas versions."""
-    try:
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-        )
-    except Exception:  # pragma: no cover - version dependent
-        return None
-
-
 def _call_kwargs(interpret):
-    kwargs = {"interpret": interpret}
-    params = _compiler_params()
-    if params is not None and not interpret:
-        kwargs["compiler_params"] = params
-    return kwargs
-
-
+    """(B, H, Q-blocks) parallel, KV-blocks sequential (the scratch carry)."""
+    return tuned_call_kwargs(
+        interpret, ("parallel", "parallel", "parallel", "arbitrary")
+    )
 
 
 def _block_live(q_start, block_q, k_start, *, causal, valid, window=None, block_k=None):
@@ -687,99 +673,55 @@ def fold_gqa_groups(dk_h, dv_h, K, k_dtype, v_dtype):
     return dk_h.astype(k_dtype), dv_h.astype(v_dtype)
 
 
-# ------------------------------------------------ SPMD partitioning (GSPMD)
-# pallas_call lowers to an opaque custom-call; without a partitioning rule
-# GSPMD replicates the kernel with UNSHARDED operands on every chip — at
-# pod scale that is a full-global-batch 30+ GiB allocation per device
-# (caught by tests/test_pod_aot.py on a deviceless v5e-256 compile). The
-# kernels are embarrassingly parallel over batch and heads, so declare
-# exactly that via `custom_partitioning`: batch/head partitioning passes
-# through (the head factor must divide BOTH H and the GQA K), sequence and
-# head_dim replicate within each shard.
-from jax.experimental.custom_partitioning import custom_partitioning
-from jax.sharding import NamedSharding, PartitionSpec
+# ------------------------------------------------------- SPMD partitioning
+# pallas_call lowers to an opaque custom-call: left to the partitioner it is
+# replicated, with UNSHARDED operands on every chip — at pod scale a
+# full-global-batch 30+ GiB allocation per device (tests/test_pod_aot.py).
+# The kernels are embarrassingly parallel over batch and heads, so under the
+# ambient mesh (`jax.sharding.set_mesh`; `Accelerator` traces its steps under
+# it) they run per shard in a `shard_map`: batch over the mesh's batch axes,
+# heads over `tensor` when that divides both H and the GQA K, sequence and
+# head_dim whole within each shard. (`custom_partitioning`, which let the
+# partitioner choose, cannot be used: the TPU runtime never registers its
+# callbacks — "Custom emitter for CustomSPMDPartitioning not found" on a
+# real 2x2 v5e, jax 0.9.0 / libtpu 0.0.34.)
+def _shard_spec(B: int, H: int, K: int):
+    """PartitionSpec over the leading (batch, heads) dims of the (B, H|K, S,
+    *) kernel operands under the ambient mesh; None when there is nothing to
+    shard over (no mesh, a one-device mesh, or already inside a shard_map,
+    whose caller has done the partitioning)."""
+    from jax.sharding import PartitionSpec
+
+    from ..parallel.mesh import BATCH_AXES, TENSOR_AXIS, ambient_mesh
+
+    mesh = ambient_mesh()
+    if mesh.empty or mesh.manual_axes:
+        return None
+    sizes = mesh.shape
+    batch = tuple(a for a in BATCH_AXES if sizes.get(a, 1) > 1)
+    if B % math.prod(sizes[a] for a in batch):
+        batch = ()  # e.g. a small eval batch on a large mesh: replicate it
+    n_tensor = sizes.get(TENSOR_AXIS, 1)
+    heads = TENSOR_AXIS if n_tensor > 1 and H % n_tensor == 0 and K % n_tensor == 0 else None
+    if not batch and heads is None:
+        return None
+    return PartitionSpec(batch or None, heads)
 
 
-def _axis_group(mesh, entry) -> int:
-    axes = (entry,) if isinstance(entry, str) else tuple(entry)
-    return int(math.prod(mesh.shape[a] for a in axes))
+def _per_shard(kernel, tensors, n_out: int):
+    """``kernel(*tensors)`` over each batch/head shard of the ambient mesh."""
+    spec = _shard_spec(tensors[0].shape[0], tensors[0].shape[1], tensors[1].shape[1])
+    if spec is None:
+        return kernel(*tensors)
+    return jax.shard_map(
+        kernel,
+        in_specs=(spec,) * len(tensors),
+        out_specs=(spec,) * n_out,
+        check_vma=False,
+    )(*tensors)
 
 
-def _bh_sharding(mesh, sharding, H: int, K: int, ndim: int = 4) -> NamedSharding:
-    """Sanitize to batch/head-only partitioning ((B, H|K, S, h) layout)."""
-    spec = list(sharding.spec) + [None] * (ndim - len(tuple(sharding.spec)))
-    b_ax, h_ax = spec[0], spec[1]
-    if h_ax is not None and (H % _axis_group(mesh, h_ax) or K % _axis_group(mesh, h_ax)):
-        h_ax = None
-    return NamedSharding(mesh, PartitionSpec(b_ax, h_ax, *([None] * (ndim - 2))))
-
-
-def _make_bh_partitioned(inner, n_out: int, sharding_rule: str):
-    """Wrap `inner(*tensors, *statics)` (all tensors (B, H|K, S, *)) so the
-    partitioner shards it over batch/heads and runs the kernel per shard.
-    ``sharding_rule`` is the Shardy propagation rule (einsum-like); the
-    partition callback owns the per-shard lowering and re-sanitizes the
-    shardings (head factor must divide both H and the GQA K) either way."""
-
-    def _hk(arg_shapes):
-        return arg_shapes[0].shape[1], arg_shapes[1].shape[1]
-
-    def infer(*cb_args):
-        *_statics, mesh, arg_shapes, result_shape = cb_args
-        H, K = _hk(arg_shapes)
-        sh = _bh_sharding(mesh, arg_shapes[0].sharding, H, K)
-        if n_out == 1:
-            return sh
-        outs = jax.tree.leaves(result_shape)
-        return tuple(
-            NamedSharding(mesh, sh.spec) for _ in range(len(outs))
-        )
-
-    def partition(*cb_args):
-        *statics, mesh, arg_shapes, result_shape = cb_args
-        H, K = _hk(arg_shapes)
-        base = _bh_sharding(mesh, arg_shapes[0].sharding, H, K)
-        arg_sh = tuple(
-            _bh_sharding(mesh, base, H, K, ndim=len(a.shape)) for a in arg_shapes
-        )
-        outs = jax.tree.leaves(result_shape)
-        out_sh = tuple(
-            _bh_sharding(mesh, base, H, K, ndim=len(o.shape)) for o in outs
-        )
-        if n_out == 1:
-            out_sh = out_sh[0]
-
-        def lower(*tensors):
-            return inner(*tensors, *statics)
-
-        return mesh, lower, out_sh, arg_sh
-
-    wrapped = custom_partitioning(inner, static_argnums=tuple(range(
-        _N_TENSORS[inner], _N_TENSORS[inner] + 6
-    )))
-    try:
-        wrapped.def_partition(
-            partition=partition,
-            infer_sharding_from_operands=infer,
-            sharding_rule=sharding_rule,
-        )
-    except TypeError:
-        # jax < 0.5.x: def_partition has no sharding_rule (the einsum-like
-        # rule string newer shard_map tracing wants); the callbacks alone
-        # carry the same partitioning.
-        wrapped.def_partition(
-            partition=partition,
-            infer_sharding_from_operands=infer,
-        )
-    return wrapped
-
-
-def _fwd_tensors(q, k, v, scale, block, causal, interpret, valid, window):
-    return _fwd(q, k, v, scale=scale, block=block, causal=causal,
-                interpret=interpret, valid=valid, window=window)
-
-
-def _bwd_tensors(q, k, v, o, lse, g, scale, block, causal, interpret, valid, window):
+def _bwd_tensors(q, k, v, o, lse, g, *, scale, block, causal, interpret, valid, window):
     do = g
     if _use_resident(q.shape[2], q.shape[3], k.dtype):
         return _bwd_resident(
@@ -797,54 +739,27 @@ def _bwd_tensors(q, k, v, o, lse, g, scale, block, causal, interpret, valid, win
     return dq, dk, dv
 
 
-_N_TENSORS = {_fwd_tensors: 3, _bwd_tensors: 6}
-# i=batch, j=q-heads, g=kv-heads, s=seq, d=head_dim, e=lse trailing unit.
-_fwd_p = _make_bh_partitioned(
-    _fwd_tensors, n_out=2,
-    sharding_rule="i j s d, i g s d, i g s d -> i j s d, i j s e",
-)
-_bwd_p = _make_bh_partitioned(
-    _bwd_tensors, n_out=3,
-    sharding_rule=(
-        "i j s d, i g s d, i g s d, i j s d, i j s e, i j s d "
-        "-> i j s d, i g s d, i g s d"
-    ),
-)
-
-
-def _call_partitioned(p_fn, inner, args):
-    try:
-        return p_fn(*args)
-    except TypeError:
-        # jax < 0.5: custom_partitioning passes its static_args as a LIST
-        # bind param, which is unhashable under shard_map tracing. A
-        # per-shard call is already partitioned by the enclosing shard_map,
-        # so the raw kernel is equivalent there.
-        return inner(*args)
-
-
 # --------------------------------------------------------------- entry point
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash(q, k, v, scale, block, causal, interpret, valid, window):
-    o, _ = _call_partitioned(
-        _fwd_p, _fwd_tensors, (q, k, v, scale, block, causal, interpret, valid, window)
-    )
-    return o
+    return _flash_fwd(q, k, v, scale, block, causal, interpret, valid, window)[0]
 
 
 def _flash_fwd(q, k, v, scale, block, causal, interpret, valid, window):
-    o, lse = _call_partitioned(
-        _fwd_p, _fwd_tensors, (q, k, v, scale, block, causal, interpret, valid, window)
+    kernel = functools.partial(
+        _fwd, scale=scale, block=block, causal=causal, interpret=interpret,
+        valid=valid, window=window,
     )
+    o, lse = _per_shard(kernel, (q, k, v), n_out=2)
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(scale, block, causal, interpret, valid, window, residuals, g):
-    q, k, v, o, lse = residuals
-    return _call_partitioned(
-        _bwd_p, _bwd_tensors,
-        (q, k, v, o, lse, g, scale, block, causal, interpret, valid, window),
+    kernel = functools.partial(
+        _bwd_tensors, scale=scale, block=block, causal=causal,
+        interpret=interpret, valid=valid, window=window,
     )
+    return _per_shard(kernel, (*residuals, g), n_out=3)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -965,14 +880,11 @@ def pick_block(dim: int, candidates: tuple[int, ...] = (512, 256, 128, 64, 32, 1
 
 
 def tuned_call_kwargs(interpret: bool, semantics: tuple[str, ...]):
-    """`pallas_call` kwargs with per-grid dimension semantics, dropped in
-    interpret mode and on pallas versions without TPUCompilerParams."""
+    """`pallas_call` kwargs with per-grid dimension semantics (compiled mode
+    only: the interpreter takes no compiler params)."""
     kwargs = {"interpret": interpret}
     if not interpret:
-        try:
-            kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-                dimension_semantics=tuple(semantics)
-            )
-        except Exception:  # pragma: no cover - version dependent
-            pass
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=tuple(semantics)
+        )
     return kwargs

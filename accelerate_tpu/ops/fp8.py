@@ -133,14 +133,11 @@ def _scaled_contract(eq, qa, qb, scale, out_dtype):
     """``(dot(qa, qb) * scale).astype(out_dtype)`` — through the `fp8_matmul`
     Pallas kernel when enabled (fp8 operands straight to the MXU, no
     materialized upcast), else the exact reference expression."""
-    try:
-        from ..native.pallas.quant_matmul import maybe_scaled_matmul
-    except Exception:  # pragma: no cover - environment dependent
-        maybe_scaled_matmul = None
-    if maybe_scaled_matmul is not None:
-        out = maybe_scaled_matmul(eq, qa, qb, scale, out_dtype)
-        if out is not None:
-            return out
+    from ..native.pallas.quant_matmul import maybe_scaled_matmul
+
+    out = maybe_scaled_matmul(eq, qa, qb, scale, out_dtype)
+    if out is not None:
+        return out
     return (_contract(eq, qa, qb) * scale).astype(out_dtype)
 
 

@@ -24,10 +24,7 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec
 
-# jax moved shard_map out of experimental in 0.5.x; support both homes.
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 psum = lax.psum
 pmean = lax.pmean
@@ -50,15 +47,9 @@ def shard_map_over(
 ) -> Callable[..., Any]:
     """`shard_map` with the framework mesh; per-shard code sees local blocks
     and may call the collectives above with the mesh axis names."""
-    try:
-        return shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    except TypeError:
-        # jax < 0.6 spells the replication check `check_rep`.
-        return shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-        )
+    return shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
+    )
 
 
 def sequence_parallel_specs(

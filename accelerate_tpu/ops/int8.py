@@ -163,14 +163,11 @@ def int8_einsum(
     the quantize -> dot -> rescale runs as one fused kernel — integer
     accumulation exact, parity within 1 ulp of the activation scale —
     without the intermediate HBM round-trips."""
-    try:
-        from ..native.pallas.quant_matmul import maybe_int8_matmul
-    except Exception:  # pragma: no cover - environment dependent
-        maybe_int8_matmul = None
-    if maybe_int8_matmul is not None:
-        out = maybe_int8_matmul(eq, x, wq, w_scale)
-        if out is not None:
-            return out
+    from ..native.pallas.quant_matmul import maybe_int8_matmul
+
+    out = maybe_int8_matmul(eq, x, wq, w_scale)
+    if out is not None:
+        return out
     qx, sx = quantize_act(x, _x_contracted_axes(eq))
     acc = jnp.einsum(eq, qx, wq, preferred_element_type=jnp.int32)
     scale = _x_scale_to_out(eq, sx) * _w_scale_to_out(eq, w_scale)

@@ -315,7 +315,7 @@ def disk_streamed_update(
 
     do_overlap = overlap_enabled() if overlap is None else bool(overlap)
     engine = get_transfer_engine()
-    # Transfer-overlap spans (docs/observability.md, BENCH_r05 follow-up):
+    # Transfer-overlap spans (docs/observability.md):
     # host clocks only, so the update math stays bit-identical either way.
     trace = _flight.trace_requests_enabled()
     t_update0 = time.perf_counter() if trace else 0.0

@@ -219,16 +219,13 @@ def _adamw_slice(
     if xp is None:
         import jax.numpy as xp  # type: ignore[no-redef]
 
-        try:
-            from ..native.pallas.fused_adamw import maybe_fused_adamw
-        except Exception:  # pragma: no cover - environment dependent
-            maybe_fused_adamw = None
-        if maybe_fused_adamw is not None:
-            fused = maybe_fused_adamw(
-                g, mu, nu, p, count, lr_t, b1, b2, eps, weight_decay, grad_scale
-            )
-            if fused is not None:
-                return fused
+        from ..native.pallas.fused_adamw import maybe_fused_adamw
+
+        fused = maybe_fused_adamw(
+            g, mu, nu, p, count, lr_t, b1, b2, eps, weight_decay, grad_scale
+        )
+        if fused is not None:
+            return fused
 
     g32 = g.astype(mu.dtype)
     if grad_scale is not None:

@@ -241,29 +241,13 @@ def replicated_sharding(mesh: Mesh) -> NamedSharding:
 
 
 def use_mesh(mesh: Mesh):
-    """Ambient-mesh context manager: ``jax.sharding.set_mesh`` where it
-    exists (jax >= 0.5.x), the legacy ``with mesh:`` context on older jax —
-    one call site, both jax generations."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """Ambient-mesh context manager (``jax.sharding.set_mesh``)."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def ambient_mesh():
-    """The active ambient mesh, or None. ``jax.sharding.get_abstract_mesh``
-    on new jax; the thread-resources physical mesh on 0.4.x (private path,
-    so failures degrade to "no ambient mesh" instead of crashing)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    try:
-        from jax._src import mesh as _mesh_lib
-
-        m = _mesh_lib.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+    """The active ambient (abstract) mesh; empty when none is set."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def constrain_batch(x: jax.Array) -> jax.Array:

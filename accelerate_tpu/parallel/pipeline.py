@@ -32,11 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-try:  # jax >= 0.4.35 exposes shard_map at the top level
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - version dependent
-    from jax.experimental.shard_map import shard_map
-
 STAGE_AXIS = "stage"
 
 
@@ -114,14 +109,7 @@ def build_pipeline(
         # the loop body writes stage-dependent values into them, and
         # shard_map's typing rejects an unvarying->varying carry.
         def _varying(x):
-            try:
-                return jax.lax.pcast(x, (STAGE_AXIS,), to="varying")
-            except (AttributeError, TypeError):  # pragma: no cover - jax version
-                pvary = getattr(jax.lax, "pvary", None)
-                # jax < 0.5 has neither pcast nor pvary; its shard_map runs
-                # without replication typing (check_rep=False here), so the
-                # marker is a no-op there.
-                return pvary(x, (STAGE_AXIS,)) if pvary is not None else x
+            return jax.lax.pcast(x, (STAGE_AXIS,), to="varying")
 
         cur0 = _varying(jnp.zeros(mb_all.shape[1:], mb_all.dtype))
         out0 = _varying(jnp.zeros_like(mb_all))
@@ -132,7 +120,7 @@ def build_pipeline(
     from ..ops.in_jit import shard_map_over
 
     # check_vma=False: the stage-varying carries and the final psum are
-    # deliberate; old jax's replication checker has no rule for them anyway.
+    # deliberate.
     sharded = shard_map_over(
         schedule,
         mesh=mesh,

@@ -182,7 +182,7 @@ def _serving_episode(fleet: _Fleet, kind: str, ep_seed: int) -> dict:
                 threads=False,
                 readmit_secs=0.01,
                 probation_completions=2,
-                engine_factory=fleet.mk_engine,
+                engine_factory=lambda _replica: fleet.mk_engine(),
             )
             completions = router.serve(reqs)
             # Drain invariant: preemption flips the router on the next tick
@@ -534,7 +534,9 @@ def _serve_drain_worker() -> int:
 
     fleet = _Fleet()
     _preemption.install_preemption_handler()
-    router = serving.Router(fleet.engines, engine_factory=fleet.mk_engine)
+    router = serving.Router(
+        fleet.engines, engine_factory=lambda _replica: fleet.mk_engine()
+    )
     rng = random.Random(0)
     refs: dict[int, np.ndarray] = {}
 

@@ -3,9 +3,9 @@
 The fixed-batch `generation.Generator` serves OFFLINE workloads well (one
 batch in, one batch out) but wastes the chip under traffic: every request
 pads to the longest prompt in its batch, the batch decodes until its LAST
-row finishes, and new arrivals wait for the whole batch to drain. BENCH_r05
-quantifies the lever: `decode_b1_tokens_per_sec 421.7` vs batch-8
-`decode_tokens_per_sec 3736.5` — keeping the decode batch full is ~8x.
+row finishes, and new arrivals wait for the whole batch to drain. Decode
+is bandwidth-bound on the weights, so a full batch of 8 costs little more
+per step than a batch of 1: keeping the decode batch full is the lever.
 
 This engine applies the Orca iteration-level-scheduling idea in its
 XLA-native form (the vLLM slot/page design reduced to what a TPU actually
@@ -252,16 +252,17 @@ class Engine:
         self.params = params
         cache = init_cache_fn(self.n_slots, self.max_len)
         kv = {k: v for k, v in cache.items() if k != "length"}
-        # Commit the slot pool (and remember its device): every decode /
-        # prefill output inherits this placement, so the jit signatures
-        # (which key on argument committedness) stay IDENTICAL from the
-        # first call on — one compile for decode, one per prefill bucket.
-        try:
-            self._device = sorted(
-                next(iter(jax.tree.leaves(kv))).devices(), key=str
-            )[0]
-        except Exception:
-            self._device = jax.devices()[0]
+        # The engine lives where its weights live: a caller that wants
+        # replica i on chip i commits the params there (`atx serve
+        # --replicas`) and the KV pools follow. Host (numpy) weights, or
+        # weights spread over several devices, leave the default device.
+        leaf = next(iter(jax.tree.leaves(params)), None)
+        placed = leaf.devices() if isinstance(leaf, jax.Array) else ()
+        self._device = next(iter(placed)) if len(placed) == 1 else jax.devices()[0]
+        # Commit the slot pool: every decode / prefill output inherits this
+        # placement, so the jit signatures (which key on argument
+        # committedness) stay IDENTICAL from the first call on — one compile
+        # for decode, one per prefill bucket.
         self._kv = jax.device_put(kv, self._device)
         config_ = self.config
         eos, pad = config_.eos_token_id, config_.pad_token_id
@@ -825,8 +826,11 @@ class Engine:
         # silently compiles twice (committed vs uncommitted int32 (N,)).
         tokens = jax.device_put(tokens, self._device)
         for _ in range(block):
+            # The dispatch gets its own copies of the cursors: the transfer
+            # is asynchronous and can alias numpy memory, so it may still be
+            # reading a host buffer when the lines below advance it in place.
             tokens, self._kv = self._decode(
-                self.params, tokens, lengths, self._kv, seeds, steps
+                self.params, tokens, lengths.copy(), self._kv, seeds, steps.copy()
             )
             fetched.append(tokens)
             lengths[decoding] += 1

@@ -427,7 +427,7 @@ class Router:
         retry_budget: int | None = None,
         retry_refill_per_sec: float | None = None,
         migrate_prefixes: int | None = None,
-        engine_factory: Callable[[], Engine] | None = None,
+        engine_factory: Callable[[int], Engine] | None = None,
     ) -> None:
         engines = list(engines)
         if not engines:
@@ -1286,7 +1286,7 @@ class Router:
             self._schedule_probe(r)
 
     def _rebuild(self, r: _Replica) -> None:
-        r.engine = self.engine_factory()
+        r.engine = self.engine_factory(r.id)
         r.rebuilds += 1
         r.wedged = threading.Event()
 

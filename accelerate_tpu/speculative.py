@@ -31,7 +31,7 @@ batch to the minimum. Rows that hit EOS or their token budget freeze
 stops as soon as every row is frozen — no wasted target forwards after
 early termination.
 
-Acceptance diagnostics: BENCH_r05's ``specdecode_accept_rate 0.0`` with a
+Acceptance diagnostics: a ``specdecode_accept_rate`` of 0.0 with a
 layer-prefix draft was investigated as a suspected logit/position
 misalignment in the accept comparison and CLEARED: at K=1 the engine's
 accept rate equals the teacher-forced draft/target argmax-agreement rate,
@@ -309,8 +309,7 @@ class SpeculativeGenerator:
         # The iteration chain lives on device; the host only needs per-row
         # commit COUNTS (and EOS flags) to know when to stop. A sync per
         # iteration would serialize every step on the host<->device round
-        # trip (fatal over a remote tunnel, where one RTT dwarfs the verify
-        # itself), so dispatch iterations OPTIMISTICALLY in batches of
+        # trip, so dispatch iterations OPTIMISTICALLY in batches of
         # ceil(remaining / (K+1)) — enough to finish the slowest live row if
         # every draft is accepted — then read the whole batch's counts in
         # one sync. Rejections just trigger another (smaller) batch; the

@@ -59,17 +59,20 @@ _block_until_ready = jax.block_until_ready
 
 
 def peak_device_flops(device: Any | None = None) -> float | None:
-    """Peak bf16 FLOP/s of one chip, or None off-TPU (MFU reads 0 there)."""
+    """Peak bf16 FLOP/s of one chip; None on a CPU device (MFU reads 0
+    there). An accelerator whose kind is not in the table raises."""
     if device is None:
-        try:
-            device = jax.devices()[0]
-        except Exception:
-            return None
+        device = jax.devices()[0]
     kind = str(getattr(device, "device_kind", "")).lower()
     for key, peak in _PEAK_FLOPS:
         if key in kind:
             return peak
-    return None
+    if getattr(device, "platform", None) == "cpu":
+        return None
+    raise ValueError(
+        f"no peak FLOP/s for device kind {device.device_kind!r}: add it to "
+        "telemetry.stepstats._PEAK_FLOPS"
+    )
 
 
 def tokens_in_batch(batch: Any) -> int:

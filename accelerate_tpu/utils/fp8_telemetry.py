@@ -2,7 +2,7 @@
 
 fp8 on a chip without fp8 MXU support is a lose-lose: XLA upcasts the
 scaled values, so you pay quantization error for zero speedup (measured
-0.51x on TPU v5e, BENCH_r03 `fp8_matmul_speedup`). The launcher refuses
+0.51x on TPU v5e by `bench.py`'s `fp8_matmul_speedup`). The launcher refuses
 `--mixed_precision fp8` on device kinds with recorded speedup <= 1 unless
 `--force_fp8` is passed (reference analog: the TE/ao fp8 recipes are only
 wired for hardware that benefits, `utils/ao.py:103`).
@@ -19,7 +19,7 @@ import os
 # Measured by bench.py on real hardware (kind -> fp8/bf16 matmul speedup).
 # v5e has no fp8 MXU: the fp8 path lowers to upcast-and-multiply.
 _BUILTIN: dict[str, float] = {
-    "TPU v5 lite": 0.51,  # BENCH_r03 fp8_matmul_speedup
+    "TPU v5 lite": 0.51,  # bench.py fp8_matmul_speedup
 }
 
 

@@ -58,12 +58,9 @@ class ProfileKwargs:
     create_perfetto_trace: bool = False
     on_trace_ready: Callable[[str], None] | None = None
 
-    def build_options(self) -> Any | None:
-        """Map to `jax.profiler.ProfileOptions` when this jax version has it."""
-        options_cls = getattr(jax.profiler, "ProfileOptions", None)
-        if options_cls is None:
-            return None
-        options = options_cls()
+    def build_options(self) -> Any:
+        """Map to `jax.profiler.ProfileOptions`."""
+        options = jax.profiler.ProfileOptions()
         options.host_tracer_level = self.host_tracer_level
         options.python_tracer_level = self.python_tracer_level
         return options
@@ -86,25 +83,11 @@ def profile(
         logging_dir or ".", PROFILE_DIR_DEFAULT
     )
     os.makedirs(trace_dir, exist_ok=True)
-    options = kwargs.build_options()
-    start_kwargs: dict[str, Any] = {}
-    if kwargs.create_perfetto_trace:
-        start_kwargs["create_perfetto_trace"] = True
-    if options is not None:
-        start_kwargs["profiler_options"] = options
-    try:
-        jax.profiler.start_trace(trace_dir, **start_kwargs)
-    except TypeError:
-        # Older jax: no profiler_options / perfetto kwargs.
-        if start_kwargs:
-            import warnings
-
-            warnings.warn(
-                "this jax version's start_trace does not accept "
-                f"{sorted(start_kwargs)}; tracing with defaults instead",
-                stacklevel=3,
-            )
-        jax.profiler.start_trace(trace_dir)
+    jax.profiler.start_trace(
+        trace_dir,
+        create_perfetto_trace=kwargs.create_perfetto_trace,
+        profiler_options=kwargs.build_options(),
+    )
     global _ACTIVE_TRACES
     _ACTIVE_TRACES += 1
     try:

@@ -15,8 +15,15 @@ from accelerate_tpu.state import ProcessState
 marker = sys.argv[1]
 ps = ProcessState()
 if ps.process_index == 1 and not os.path.exists(marker):
-    with open(marker, "w") as f:
-        f.write("crashed")
+    try:
+        with open(marker, "w") as f:
+            f.write("crashed")
+    except OSError:
+        # An uncreatable marker means "crash every time" — and crash HARD
+        # either way: an exception would leave through the interpreter's
+        # exit hooks, where the distributed shutdown barrier waits half a
+        # minute for the peer this rank just abandoned.
+        pass
     print(f"[proc {ps.process_index}] CRASHING ONCE", flush=True)
     os._exit(17)
 

@@ -251,7 +251,7 @@ def check_gather_for_metrics(
 
 
 def run_sharded_mode(ps: ProcessState, kind: str, ckpt_dir: str) -> None:
-    """The pod regime (VERDICT r3 weak #2): FSDP / TP training where every
+    """The pod regime: FSDP / TP training where every
     param is a *global non-addressable* array spanning process boundaries,
     with per-host shard I/O in save_state/load_state and loss parity against
     a single-device reference run of the same math."""
@@ -348,7 +348,7 @@ def run_sharded_mode(ps: ProcessState, kind: str, ckpt_dir: str) -> None:
 
 def run_longcontext_mode(ps: ProcessState, kind: str) -> None:
     """Sequence/expert parallelism with the axis SPANNING the process
-    boundary (VERDICT r4 #7): 2 processes × 4 devices with sequence=8 (the
+    boundary: 2 processes × 4 devices with sequence=8 (the
     KV ring's ppermute hops cross hosts) or expert=8 (the MoE dispatch
     all-to-all crosses hosts), trained for 5 steps with loss parity against
     a single-device oracle of the same math — not just a finite-loss check."""

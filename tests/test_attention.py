@@ -320,8 +320,8 @@ def test_llama_ulysses_matches_dot():
 
 def test_flash_partitions_under_jit():
     """The pallas kernel must partition over batch/heads under plain jit
-    (custom_partitioning) instead of being replicated as an opaque
-    custom-call — the pod-scale failure tests/test_pod_aot.py documents.
+    (a shard_map over the ambient mesh) instead of being replicated as an
+    opaque custom-call — the pod-scale failure tests/test_pod_aot.py documents.
     Numerics must match the oracle and the output must keep the batch
     sharding."""
     import numpy as np

@@ -287,7 +287,7 @@ class TestShardedGenerate:
 
 
 class TestDiskOffload:
-    """Disk-offloaded inference (VERDICT r3 #4): offloaded leaves live on
+    """Disk-offloaded inference: offloaded leaves live on
     disk as memmaps (reference disk_offload / OffloadedWeightsLoader,
     `big_modeling.py:260`, `utils/offload.py:127`), streamed per layer —
     host RAM never holds the model."""

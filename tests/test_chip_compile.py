@@ -1,0 +1,245 @@
+"""What can be known about the chip without one.
+
+- The TPU compiler is installed here: `jax.experimental.topologies` describes
+  a v5e that is not attached, and `jit(...).lower(shapes).compile()` raises
+  what the chip's compiler would raise. The main-path Pallas kernels are
+  compiled at `chip_smoke.py`'s widths (Llama-3-8B: 32/8 heads of 128,
+  d_model 4096, d_ff 14336) — interpret mode accepts block shapes and VMEM
+  footprints that Mosaic refuses, so the interpret tests in test_kernels
+  cannot stand in for these.
+- `chip_smoke.py`'s phase functions are rehearsed at tiny sizes on the CPU
+  mesh (interpret kernels), and the script itself must refuse a host with no
+  TPU.
+
+A compile that passes is not a chip run: nothing here produces a time.
+"""
+
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+from accelerate_tpu.models import llama  # noqa: E402
+from accelerate_tpu.native.pallas import decode_attention, fused_adamw, quant_matmul  # noqa: E402
+from accelerate_tpu.native.pallas.dispatch import force_kernels  # noqa: E402
+from accelerate_tpu.ops.flash_attention import flash_attention  # noqa: E402
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+# chip_smoke widths: train at (B, S) = (1, 4096); serve with 4 slots of 256.
+H, K, HD, D, FF = 32, 8, 128, 4096, 14336
+SLOTS, SLOT_LEN = chip_smoke.SERVE_ENGINE["slots"], chip_smoke.SERVE_ENGINE["max_len"]
+SEQ = chip_smoke.TRAIN_CUTS["seq_len"]
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four chips of a described (not attached) v5e 2x2. The persistent
+    compile cache stays off around these compiles: it would write entries
+    that no process without a chip can read back."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this environment
+        pytest.skip(f"TPU topology cannot be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _flash(q, k, v):
+    return flash_attention(q, k, v, causal=True, interpret=False)
+
+
+def _flash_grad(q, k, v):
+    return jax.grad(lambda *a: _flash(*a).astype(F32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+def _decode(q, k, v, lengths):
+    return decode_attention.flash_decode(q, k, v, lengths, interpret=False)
+
+
+def _decode_int8(q, k, v, lengths, ks, vs):
+    return decode_attention.flash_decode(
+        q, k, v, lengths, k_scale=ks, v_scale=vs, interpret=False
+    )
+
+
+def _int8_matmul(x, w, s):
+    return quant_matmul.int8_matmul_fused("mc,cn->mn", x, w, s, interpret=False)
+
+
+def _adamw(g, mu, nu, p, count, lr):
+    return fused_adamw.fused_adamw_update(
+        g, mu, nu, p, count, lr, 0.9, 0.999, 1e-8, 0.01, interpret=False
+    )
+
+
+_QKV = [((1, SEQ, H, HD), BF16), ((1, SEQ, K, HD), BF16), ((1, SEQ, K, HD), BF16)]
+_SLOT_Q = ((SLOTS, 1, H, HD), BF16)
+_LEAF = ((D, FF), F32)
+# name -> (function, [(shape, dtype)...], tpu_custom_calls expected)
+KERNELS = {
+    "flash_fwd": (_flash, _QKV, 1),
+    "flash_fwd_bwd": (_flash_grad, _QKV, 3),
+    "flash_decode_bf16": (
+        _decode,
+        [_SLOT_Q, ((SLOTS, SLOT_LEN, K, HD), BF16), ((SLOTS, SLOT_LEN, K, HD), BF16), ((SLOTS,), I32)],
+        1,
+    ),
+    "flash_decode_int8_kv": (
+        _decode_int8,
+        [_SLOT_Q, ((SLOTS, SLOT_LEN, K, HD), I8), ((SLOTS, SLOT_LEN, K, HD), I8), ((SLOTS,), I32),
+         ((SLOTS, SLOT_LEN, K), BF16), ((SLOTS, SLOT_LEN, K), BF16)],
+        1,
+    ),
+    # The down projection of a 2048-token prefill: the whole contraction
+    # (14336) staged per block was 43 MB of VMEM against a 16 MB limit.
+    "int8_matmul_prefill": (_int8_matmul, [((2048, FF), BF16), ((FF, D), I8), ((1, D), F32)], 1),
+    "int8_matmul_decode": (_int8_matmul, [((SLOTS, D), BF16), ((D, FF), I8), ((1, FF), F32)], 1),
+    "fused_adamw_leaf": (_adamw, [_LEAF, _LEAF, _LEAF, _LEAF, ((), I32), ((), F32)], 1),
+}
+
+
+@pytest.mark.parametrize("name", list(KERNELS))
+def test_kernel_compiles_for_v5e(v5e, name):
+    fn, operands, expected_calls = KERNELS[name]
+    one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in operands]
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert text.count("tpu_custom_call") == expected_calls
+
+
+def test_flash_partitions_over_a_described_mesh(v5e):
+    """Flash attention (fwd + bwd) under fsdp=2 x tensor=2 on four described
+    chips: batch and heads are sharded by `shard_map`, each chip runs the
+    kernels on its shard. (`custom_partitioning` got as far as the TPU
+    backend and no further: "Custom emitter for CustomSPMDPartitioning not
+    found", on described and on real chips alike.)"""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(np.array(v5e).reshape(2, 2), ("fsdp", "tensor"))
+    sharding = NamedSharding(mesh, PartitionSpec("fsdp", None, "tensor", None))
+    shapes = [
+        jax.ShapeDtypeStruct((2, 2048, heads, HD), BF16, sharding=sharding)
+        for heads in (H, K, K)
+    ]
+    with jax.sharding.set_mesh(mesh):
+        text = jax.jit(_flash_grad).lower(*shapes).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    # Each chip's kernel sees its own shard: one batch row, half the heads.
+    assert f"bf16[1,{H // 2},2048,{HD}]" in text
+
+
+def test_plan_refuses_what_cannot_be_staged():
+    # A contraction with no 128-multiple divisor must be staged whole; at
+    # this width that is past the VMEM budget, so the plan says no (the
+    # caller falls back) instead of lowering a kernel the compiler refuses.
+    x = jax.ShapeDtypeStruct((2048, 14337), BF16)
+    w = jax.ShapeDtypeStruct((14337, 4096), I8)
+    assert quant_matmul._plan("mc,cn->mn", x, w, BF16) is None
+    assert fused_adamw._plan(8 * 129) is None  # no lane-aligned view
+
+
+# ------------------------------------------------- chip_smoke, rehearsed on CPU
+_TINY_TRAIN = dict(
+    head_dim=16, max_seq_len=64, remat=True, remat_policy="attn_and_outputs",
+    attention_impl="flash", loss_chunk_size=32,
+)
+
+
+def _rehearse_kernel_parity():
+    errors = chip_smoke.kernel_parity_phase(seq_len=64, cache_len=64, head_dim=16, seed=0)
+    chip_smoke.check_parity(errors)
+    assert set(errors) == {"flash_fwd_bwd", "flash_decode", "flash_decode_int8_kv", "int8_matmul"}
+
+
+def _rehearse_serve():
+    requests = ((16, 16), (5, 6), (23, 8))
+    out = chip_smoke.serve_phase(
+        llama.LlamaConfig.tiny(max_seq_len=32),
+        requests=requests,
+        engine_kwargs={"slots": 2, "buckets": (8, 16), "max_len": 32},
+        seed=0,
+    )
+    assert out["generated_tokens"] == 30 and out["equal_to_generator"] == "16 of 16"
+    assert out["reference_argmax_or_tie"] == "16 of 16"
+    assert out["decode_compiles"] == 1 and out["prefill_compiles"] == 2
+
+
+def _rehearse_sharded():
+    # Runs `train_phase` too: it is the one-device side of the comparison.
+    out = chip_smoke.sharded_phase(
+        llama.LlamaConfig.tiny(**_TINY_TRAIN), batch_size=2, seq_len=64, steps=2,
+        seed=0, devices=jax.devices()[:4],
+    )
+    assert len(out["w_gate_shard_bytes"]) == 4 and "all-reduce" in out["collectives"]
+    assert out["one_device_losses"][-1] < out["one_device_losses"][0]
+
+
+@pytest.mark.parametrize(
+    "rehearse", [_rehearse_kernel_parity, _rehearse_serve, _rehearse_sharded]
+)
+def test_chip_smoke_phase_on_cpu(rehearse):
+    with force_kernels("interpret"):
+        rehearse()
+
+
+def test_chip_smoke_refuses_a_host_with_no_tpu():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert proc.stdout == "" and "no TPU" in proc.stderr
+
+
+# ------------------------------------------------------------ no hidden device
+def _bench_without_a_chip():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location("bench_no_chip", os.path.join(REPO, "bench.py"))
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    assert bench.main() != 0 and not bench._RESULT  # refused before any phase
+    tpu = type("D", (), {"device_kind": "TPU v9 ultra", "platform": "tpu"})()
+    with pytest.raises(ValueError, match="TPU v9 ultra"):
+        bench._peak_flops(tpu)
+
+
+def _mfu_peak_of_an_unknown_chip():
+    from accelerate_tpu.telemetry import peak_device_flops
+
+    assert peak_device_flops() is None  # a CPU device has no MFU
+    tpu = type("D", (), {"device_kind": "TPU v9 ultra", "platform": "tpu"})()
+    with pytest.raises(ValueError, match="TPU v9 ultra"):
+        peak_device_flops(tpu)
+
+
+def _dryrun_on_more_devices_than_exist():
+    from __graft_entry__ import dryrun_multichip
+
+    with pytest.raises(RuntimeError, match="needs 64 devices, found 8"):
+        dryrun_multichip(64)
+
+
+@pytest.mark.parametrize(
+    "case",
+    [_bench_without_a_chip, _mfu_peak_of_an_unknown_chip, _dryrun_on_more_devices_than_exist],
+)
+def test_no_path_stands_in_for_the_device(case):
+    case()

@@ -42,8 +42,13 @@ class TestEarlyExit:
         ap, ic = _pair()
         budget = 48
         prompt = jnp.asarray(np.tile(np.arange(5, dtype=np.int32)[None] % 61, (2, 1)))
-        free = _free_run(params, prompt, budget)
-        eos = int(free[0, 5 + 2])  # identical rows -> both hit it at step 3
+        free = np.asarray(_free_run(params, prompt, budget))[0, 5:]
+        # The first token (from index 2 on) the stream has not emitted before:
+        # a random model may repeat itself, and an earlier occurrence of the
+        # chosen token would be the EOS instead. Identical rows -> both hit it.
+        k = next(i for i in range(2, budget) if free[i] not in free[:i])
+        assert k < 20  # early enough for the loop to exit well under budget
+        eos = int(free[k])
         config = GenerationConfig(max_new_tokens=budget, eos_token_id=eos, pad_token_id=0)
         early = Generator(ap, ic, config, eos_check_every=4)
         full = Generator(ap, ic, config, eos_check_every=10_000)

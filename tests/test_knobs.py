@@ -1,4 +1,4 @@
-"""Every public config field must be consumed (the VERDICT honesty
+"""Every public config field must be consumed (the honesty
 contract): activation_checkpointing changes the compiled program but not the
 math; state_dict_type drives the save_model layout; removed knobs are gone."""
 

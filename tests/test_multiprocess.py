@@ -95,7 +95,7 @@ def test_failed_worker_tears_down_job(tmp_path):
 
 @pytest.mark.multiprocess
 def test_two_process_fsdp_training_and_sharded_checkpoint(tmp_path):
-    """The pod regime (VERDICT r3 weak #2): 2 processes x 4 local devices,
+    """The pod regime: 2 processes x 4 local devices,
     params sharded over fsdp as non-addressable global arrays, sharded
     save/load across process boundaries, loss parity vs single device."""
     proc = launch(
@@ -124,8 +124,8 @@ def test_two_process_tensor_parallel_training(tmp_path):
 
 @pytest.mark.multiprocess
 def test_two_process_ring_attention_training():
-    """Sequence parallelism with the ring axis SPANNING the process boundary
-    (VERDICT r4 #7): KV ppermute hops cross hosts; loss parity vs a
+    """Sequence parallelism with the ring axis SPANNING the process boundary:
+    KV ppermute hops cross hosts; loss parity vs a
     single-device dot-attention oracle (ring attention is exact)."""
     proc = launch(
         DRIVER,

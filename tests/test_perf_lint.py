@@ -9,6 +9,7 @@ persists/overrides correctly. Runs on the 8-device CPU simulation
 import importlib.util
 import json
 import os
+import types
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +70,22 @@ class TestChipSpecs:
         assert roofline.chip_spec_for("TPU v4").name == "v4"
         # container auto-detect: no TPU attached -> cpu stand-in
         assert roofline.chip_spec_for().name == "cpu"
+
+    @pytest.mark.parametrize(
+        "chip",
+        ["TPU v9 ultra", types.SimpleNamespace(device_kind="TPU v9 ultra", platform="tpu")],
+        ids=["kind-string", "device"],
+    )
+    def test_unknown_accelerator_raises(self, chip):
+        # Never the cpu stand-in's numbers under an accelerator's name.
+        with pytest.raises(ValueError, match="TPU v9 ultra"):
+            roofline.chip_spec_for(chip)
+
+    def test_cpu_stand_in_only_for_cpu(self):
+        cpu = types.SimpleNamespace(device_kind="cpu", platform="cpu")
+        assert roofline.chip_spec_for(cpu).name == "cpu"
+        assert roofline.chip_spec_for("cpu").name == "cpu"
+        assert roofline.chip_spec_for(jax.devices()[0]).name == "cpu"
 
     def test_dtype_packing(self):
         assert V5E.native_sublane("f32") == 8

@@ -1,4 +1,4 @@
-"""AOT validation of the pod-scale story (VERDICT r3 #6 / weak #5).
+"""AOT validation of the pod-scale story.
 
 The 45%-MFU north star is defined on a v5e-256; no 256-chip hardware is
 reachable from CI, but XLA's TPU compiler is — `jax.experimental.topologies`
@@ -51,11 +51,11 @@ def _aot_train_step(mesh: Mesh, rules=()):
     """Lower + AOT-compile one full 8B train step (bf16 compute, fp32
     master params, sharded adamw) against the topology mesh; returns the
     compiled executable."""
-    # dot (not flash) attention: the deviceless AOT compiler cannot emit
-    # custom_partitioning callbacks ("Custom emitter for
-    # CustomSPMDPartitioning not found"), and the unfused path upper-bounds
-    # the fused kernel's memory anyway. The flash partitioning itself is
-    # runtime-verified on the simulated mesh (test_flash_partitions_under_jit).
+    # dot (not flash) attention: the unfused path upper-bounds the fused
+    # kernel's memory, and these bounds were pinned with it. The flash
+    # partitioning (a shard_map over batch/heads) is compiled for a described
+    # mesh in test_chip_compile and run on the simulated mesh
+    # (test_flash_partitions_under_jit).
     config = llama.LlamaConfig.llama3_8b(
         remat=True,
         remat_policy="attn_and_outputs",

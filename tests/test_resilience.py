@@ -655,23 +655,24 @@ class TestCoordInitBackoff:
             )
         assert len(calls) == 3  # 1 try + 2 retries
 
-    def test_timeout_kwarg_dropped_on_older_jax(self, monkeypatch):
+    def test_timeout_forwarded_as_initialization_timeout(self, monkeypatch):
         import accelerate_tpu.state as smod
 
         calls = []
-
-        def old_jax_init(**kwargs):
-            calls.append(dict(kwargs))
-            if "initialization_timeout" in kwargs:
-                raise TypeError("unexpected keyword argument")
-
-        monkeypatch.setattr(smod.jax.distributed, "initialize", old_jax_init)
+        monkeypatch.setattr(
+            smod.jax.distributed, "initialize", lambda **kwargs: calls.append(kwargs)
+        )
         monkeypatch.setenv("ATX_COORD_TIMEOUT_SECS", "5")
         smod._initialize_distributed_with_retries(
             coordinator_address="127.0.0.1:1", num_processes=2
         )
-        assert len(calls) == 2
-        assert "initialization_timeout" not in calls[1]
+        assert calls == [
+            {
+                "coordinator_address": "127.0.0.1:1",
+                "num_processes": 2,
+                "initialization_timeout": 5,
+            }
+        ]
 
 
 # ============================================================== subprocesses

@@ -745,6 +745,31 @@ class TestSelfHealing:
 
 
 class TestServeCLIFlags:
+    def test_replicas_spread_over_local_devices(self, params, monkeypatch):
+        """`atx serve --replicas 4` on a four-device host: replica i's
+        weights, slot KV pool and prefix pool all live on device i — not
+        four engines stacked on device 0."""
+        from accelerate_tpu.commands import serve as serve_cmd
+
+        four = jax.local_devices()[:4]
+        monkeypatch.setattr(jax, "local_devices", lambda: four)
+        engines = [
+            _engine(serve_cmd.replica_params(params, i), prefix_cache=True)
+            for i in range(5)
+        ]
+        placed = [
+            {d for pool in (e._kv, e._pool, e.params)
+             for leaf in jax.tree.leaves(pool) for d in leaf.devices()}
+            for e in engines
+        ]
+        assert placed[:4] == [{d} for d in four]
+        assert placed[4] == {four[0]}  # i mod n
+        # ...and an engine still serves from where it was put.
+        engines[2].submit(np.arange(5, dtype=np.int32), 3)
+        (c,) = engines[2].run_until_idle()
+        assert c.n_new == 3
+        assert next(iter(engines[2]._kv.values())).devices() == {four[2]}
+
     def test_parser_accepts_router_flags(self):
         import argparse
 

@@ -58,6 +58,15 @@ def _solo(params, prompt, max_new, config=None):
     return out[0, len(prompt):]
 
 
+def _first_fresh(stream, start, width=1):
+    """Smallest ``i >= start`` whose ``width``-token window of the greedy
+    ``stream`` has not occurred at an earlier position. A random-weight
+    model may repeat itself, so a fixed index can pick a token that already
+    occurred — and an EOS / stop match would then fire early."""
+    windows = [tuple(int(t) for t in stream[i : i + width]) for i in range(len(stream) - width + 1)]
+    return next(i for i in range(start, len(windows)) if windows[i] not in windows[:i])
+
+
 def _mixed_requests(n, *, seed=0, max_prompt=40, budgets=(4, 12)):
     rng = np.random.RandomState(seed)
     return [
@@ -101,7 +110,8 @@ class TestBitIdentity:
         and its slot is reused by a queued request."""
         prompt = np.arange(5, dtype=np.int32) % 61
         free_run = _solo(params, prompt, 16)
-        eos = int(free_run[3])
+        k = _first_fresh(free_run, 3)
+        eos = int(free_run[k])
         config = GenerationConfig(max_new_tokens=16, eos_token_id=eos, pad_token_id=0)
         eng = _engine(params, config, slots=1)
         for i in range(3):  # one slot, three requests: forced reuse
@@ -110,7 +120,7 @@ class TestBitIdentity:
         assert len(outs) == 3 and eng.stats["admitted"] == 3
         want = _solo(params, prompt, 16, config)
         for c in outs:
-            assert c.n_new == 4  # 3 tokens + the eos
+            assert c.n_new == k + 1 < 16  # k tokens + the eos
             np.testing.assert_array_equal(c.tokens, want)
 
     def test_sampled_stream_independent_of_batchmates(self, params):
@@ -514,16 +524,17 @@ class TestStopAndBudget:
         including the stop match, with finish_reason 'stop'."""
         prompt = (np.arange(9, dtype=np.int32) * 11) % 61
         free = _solo(params, prompt, 12)
-        stop = tuple(int(t) for t in free[4:6])
+        k = _first_fresh(free, 4, width=2)
+        stop = tuple(int(t) for t in free[k : k + 2])
         eng = _engine(params)
         eng.submit(prompt, 12, stop_sequences=[stop])
         (c,) = eng.run_until_idle()
         assert c.finish_reason == "stop"
-        assert c.n_new == 6
+        assert c.n_new == k + 2 < 12
         # tokens keeps the (max_new_tokens,) padded layout; the generated
         # region up to the stop match equals the solo stream.
-        np.testing.assert_array_equal(c.tokens[:6], free[:6])
-        assert not c.tokens[6:].any()  # pad after the stop
+        np.testing.assert_array_equal(c.tokens[: k + 2], free[: k + 2])
+        assert not c.tokens[k + 2 :].any()  # pad after the stop
 
     def test_stop_sequence_not_hit_runs_to_budget(self, params):
         prompt = (np.arange(9, dtype=np.int32) * 11) % 61
@@ -533,14 +544,15 @@ class TestStopAndBudget:
         assert c.finish_reason == "length" and c.n_new == 7
 
     def test_eos_reports_eos_reason(self, params):
-        prompt = np.arange(5, dtype=np.int32) % 61
+        prompt = np.arange(9, dtype=np.int32) % 61
         free = _solo(params, prompt, 8)
-        eos = int(free[2])
+        k = _first_fresh(free, 2)
+        eos = int(free[k])
         config = GenerationConfig(max_new_tokens=8, eos_token_id=eos, pad_token_id=0)
         eng = _engine(params, config)
         eng.submit(prompt, 8)
         (c,) = eng.run_until_idle()
-        assert c.finish_reason == "eos" and c.n_new == 3
+        assert c.finish_reason == "eos" and c.n_new == k + 1 < 8
 
     def test_per_request_budget_override(self, params):
         """submit() without max_new_tokens falls back to the engine

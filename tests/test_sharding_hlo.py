@@ -1,4 +1,4 @@
-"""Compiled-HLO verification of the sharding strategies (VERDICT r2 #4).
+"""Compiled-HLO verification of the sharding strategies.
 
 The strategy claims (`utils/dataclasses.py:54-59`, `parallel/sharding.py`)
 are that GSPMD lowers each strategy's train step to the right collectives —
@@ -187,7 +187,7 @@ class TestCompileStability:
 class TestSpmdWarningClean:
     """The dryrun's phases must compile without involuntary SPMD resharding.
 
-    Round-4 verdict: `MULTICHIP_r04.json` passed with repeated "[SPMD]
+    A multi-chip dry run once passed with repeated "[SPMD]
     Involuntary full rematerialization" warnings — the embed table's D dim
     was sharded over fsdp, colliding with the batch-over-(data,fsdp)
     activation constraint (fixed in `parallel/tp.py`; the plans now shard

@@ -88,7 +88,7 @@ class TestGreedyExactness:
 
 class TestPerRowCommit:
     def test_batched_iterations_track_slowest_row_not_min_commit(self, models):
-        """Per-row cache lengths (VERDICT r4 #4): each row commits its own
+        """Per-row cache lengths: each row commits its own
         accepted count, so a batched call needs no more verify iterations
         than its slowest row would alone. Under the old shared-scalar
         length, every iteration committed the MINIMUM across rows and the
@@ -138,8 +138,8 @@ class TestPerRowCommit:
 
 
 class TestAcceptRateRegression:
-    """BENCH_r05 reported `specdecode_accept_rate 0.0` with a real draft
-    model; the suspected accept-comparison misalignment was diagnosed and
+    """A chip run once reported `specdecode_accept_rate 0.0` with a real
+    draft model; the suspected accept-comparison misalignment was diagnosed and
     CLEARED (speculative.py module docstring). These tests pin the two
     facts that diagnosis rests on, so a future positional regression in
     the draft or verify path cannot hide behind 'the draft is just bad'."""

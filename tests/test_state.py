@@ -171,3 +171,29 @@ def test_require_decorator_on_plain_pytest_class():
 
     marks = getattr(Probe, "pytestmark", [])
     assert any(m.name == "skipif" and m.args == (True,) for m in marks)
+
+
+@pytest.mark.parametrize("placed", [None, "/some/dir"])
+def test_configure_compile_cache(monkeypatch, placed):
+    """JAX_COMPILATION_CACHE_DIR set: it is honoured and nothing is set in
+    code. Unset: one fixed, git-ignored directory inside the checkout."""
+    import os
+
+    from accelerate_tpu import state
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if placed is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            assert state.configure_compile_cache() == state.COMPILE_CACHE_DIR
+            assert jax.config.jax_compilation_cache_dir == state.COMPILE_CACHE_DIR
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(state.__file__)))
+            assert state.COMPILE_CACHE_DIR == os.path.join(repo, ".jax_compile_cache")
+            with open(os.path.join(repo, ".gitignore")) as f:
+                assert ".jax_compile_cache/" in f.read().split()
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+            assert state.configure_compile_cache() == placed
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
