@@ -1341,13 +1341,10 @@ class Accelerator:
                 return new_state, metrics
             # Trace (and run) under the ambient mesh so the model's
             # activation constraints (parallel.mesh.constrain_batch) bind
-            # to this Accelerator's axes. While an XPlane capture is live the
-            # step also enters StepTraceAnnotation so traces show numbered
-            # steps (utils/profiler.maybe_step_annotation — a no-op context
-            # otherwise).
-            with use_mesh(self.mesh), _profiler.maybe_step_annotation(
-                _stats_cell["calls"]
-            ):
+            # to this Accelerator's axes. `step_span` numbers the step in
+            # any profiler capture of the process (a flag check when there
+            # is none).
+            with use_mesh(self.mesh), _telemetry.step_span(_stats_cell["calls"]):
                 new_state, metrics = jitted(state, batch)
             if self._elastic_timer is not None:
                 # First step after an in-place resize: block on its output

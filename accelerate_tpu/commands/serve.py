@@ -374,9 +374,9 @@ def run(args: argparse.Namespace) -> int:
             ),
         }
         if router is None:
-            # Single-engine runs report the registry histograms' estimates —
-            # the SAME series `/metrics` exports, so a scrape and the JSON
-            # line always agree (docs/observability.md).
+            # Single-engine runs report the engine's own exact percentiles
+            # (its `request` records): the samples `/metrics` exports in
+            # buckets (docs/observability.md).
             lat = engine.latency_summary()
             for out_key, reg_key in (
                 ("serve_p50_ms", "p50_ms"),

@@ -216,7 +216,9 @@ def flash_decode(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, group, h), q.dtype),
-        **tuned_call_kwargs(interpret, ("parallel", "parallel", "arbitrary")),
+        **tuned_call_kwargs(
+            "flash_decode", interpret, ("parallel", "parallel", "arbitrary")
+        ),
     )(lengths, *operands)
     return out.reshape(B, 1, H, h)
 

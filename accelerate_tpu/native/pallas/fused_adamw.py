@@ -155,7 +155,7 @@ def fused_adamw_update(
         ],
         # Moments update in place; the scalars/g/p operands stay read-only.
         input_output_aliases={2: 1, 3: 2},
-        **tuned_call_kwargs(interpret, ("arbitrary",)),
+        **tuned_call_kwargs("fused_adamw", interpret, ("arbitrary",)),
     )(scalars, view(g), view(mu), view(nu), view(p))
     return u.reshape(mu.shape), new_mu.reshape(mu.shape), new_nu.reshape(mu.shape)
 

@@ -214,7 +214,7 @@ def _scaled_kernel(a_ref, b_ref, s_ref, o_ref, acc_ref, *, dims):
         o_ref[...] = (acc_ref[...] * s_ref[0, 0]).astype(o_ref.dtype)
 
 
-def _tiled_call(kernel, plan, in_specs, out_dtype, acc_dtype, interpret):
+def _tiled_call(name, kernel, plan, in_specs, out_dtype, acc_dtype, interpret):
     _, _, M, N, C, bm, bn, bc, _, _ = plan
     return pl.pallas_call(
         kernel,
@@ -223,7 +223,7 @@ def _tiled_call(kernel, plan, in_specs, out_dtype, acc_dtype, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, c: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        **tuned_call_kwargs(interpret, ("parallel", "parallel", "arbitrary")),
+        **tuned_call_kwargs(name, interpret, ("parallel", "parallel", "arbitrary")),
     )
 
 
@@ -258,6 +258,7 @@ def int8_matmul_fused(
     ).reshape(1, N)
     a_spec, b_spec = _specs(oa, ob, bm, bn, bc)
     out = _tiled_call(
+        "int8_matmul",
         functools.partial(_int8_kernel, dims=_dot_dims(oa, ob)),
         plan,
         [
@@ -294,6 +295,7 @@ def scaled_matmul(
     s2 = jnp.asarray(scale, jnp.float32).reshape(1, 1)
     a_spec, b_spec = _specs(oa, ob, bm, bn, bc)
     out = _tiled_call(
+        "scaled_matmul",
         functools.partial(_scaled_kernel, dims=_dot_dims(oa, ob)),
         plan,
         [a_spec, b_spec, pl.BlockSpec(memory_space=pltpu.SMEM)],

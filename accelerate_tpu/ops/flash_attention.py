@@ -58,10 +58,10 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _call_kwargs(interpret):
+def _call_kwargs(name, interpret):
     """(B, H, Q-blocks) parallel, KV-blocks sequential (the scratch carry)."""
     return tuned_call_kwargs(
-        interpret, ("parallel", "parallel", "parallel", "arbitrary")
+        name, interpret, ("parallel", "parallel", "parallel", "arbitrary")
     )
 
 
@@ -178,7 +178,7 @@ def _fwd_resident(q, k, v, *, scale, block, causal, interpret, valid, window=Non
             jax.ShapeDtypeStruct((B, H, S, h), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
         ],
-        interpret=interpret,
+        **tuned_call_kwargs("flash_fwd_resident", interpret),
     )(q, k, v)
     return o, lse
 
@@ -299,7 +299,7 @@ def _bwd_resident(scale, block, causal, interpret, valid, residuals, g, window=N
         ],
         out_specs=pl.BlockSpec((1, 1, block, h), lambda b, hh, qi: (b, hh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, h), q.dtype),
-        interpret=interpret,
+        **tuned_call_kwargs("flash_bwd_dq_resident", interpret),
     )(q, k, v, do, lse, delta)
 
     grid_kv = (B, H, S // block)
@@ -322,7 +322,7 @@ def _bwd_resident(scale, block, causal, interpret, valid, residuals, g, window=N
             jax.ShapeDtypeStruct((B, H, S, h), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, h), q.dtype),
         ],
-        interpret=interpret,
+        **tuned_call_kwargs("flash_bwd_dkv_resident", interpret),
     )(q, k, v, do, lse, delta)
 
     if group > 1:
@@ -472,7 +472,7 @@ def _fwd(q, k, v, *, scale, block, causal, interpret, valid, window=None):
             pltpu.VMEM((block, 1), jnp.float32),   # l
             pltpu.VMEM((block, h), jnp.float32),   # acc
         ],
-        **_call_kwargs(interpret),
+        **_call_kwargs("flash_fwd", interpret),
     )(q, k, v)
     return o, lse
 
@@ -617,7 +617,7 @@ def dq_call(q, k, v, do, lse, delta, *, scale, block, causal, interpret, valid, 
         out_specs=pl.BlockSpec((1, 1, block, h), lambda b, hh, qi, ki: (b, hh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, h), q.dtype),
         scratch_shapes=[pltpu.VMEM((block, h), jnp.float32)],
-        **_call_kwargs(interpret),
+        **_call_kwargs("flash_bwd_dq", interpret),
     )(q, k, v, do, lse, delta)
 
 
@@ -658,7 +658,7 @@ def dkv_call(q, k, v, do, lse, delta, *, scale, block, causal, interpret, valid,
             pltpu.VMEM((block, h), jnp.float32),
             pltpu.VMEM((block, h), jnp.float32),
         ],
-        **_call_kwargs(interpret),
+        **_call_kwargs("flash_bwd_dkv", interpret),
     )(q, k, v, do, lse, delta)
 
 
@@ -879,11 +879,17 @@ def pick_block(dim: int, candidates: tuple[int, ...] = (512, 256, 128, 64, 32, 1
     return None
 
 
-def tuned_call_kwargs(interpret: bool, semantics: tuple[str, ...]):
-    """`pallas_call` kwargs with per-grid dimension semantics (compiled mode
-    only: the interpreter takes no compiler params)."""
-    kwargs = {"interpret": interpret}
-    if not interpret:
+def tuned_call_kwargs(
+    name: str, interpret: bool, semantics: tuple[str, ...] | None = None
+):
+    """`pallas_call` kwargs every kernel of the package shares: its ``name``
+    (``metadata`` is what reaches a device trace: a v5e names an operation by
+    its HLO text, and JAX writes the metadata into the custom call's
+    ``frontend_attributes={kernel_metadata={...}}``) and the per-grid
+    dimension semantics (compiled mode only: the interpreter takes no
+    compiler params)."""
+    kwargs = {"name": name, "metadata": {"kernel": name}, "interpret": interpret}
+    if semantics is not None and not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=tuple(semantics)
         )

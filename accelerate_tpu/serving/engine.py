@@ -125,7 +125,11 @@ class Completion:
     """A finished request. ``tokens`` is (max_new_tokens,) int32 padded with
     ``pad_token_id`` after EOS — the exact layout solo `generate()` emits
     for the generated region, so bit-identity checks are a slice compare.
-    Timestamps are absolute `time.perf_counter()` values. ``finish_reason``
+    Timestamps are absolute `time.perf_counter()` values, in the order a
+    request lives them: ``submitted_at`` <= ``admitted_at`` (a slot) <=
+    ``prefill_started_at`` (its first prefill chunk: the wait in between is
+    the prefill queue) <= ``first_token_at`` <= ``finished_at``; 0.0 for a
+    stage a cancelled request never reached. ``finish_reason``
     is ``"eos"`` / ``"stop"`` (a stop sequence matched; its tokens stay in
     ``tokens``) / ``"length"`` (budget exhausted) / ``"cancelled"``
     (`Engine.cancel` — deadline expiry or caller cancellation; ``tokens``
@@ -143,13 +147,15 @@ class Completion:
     first_token_at: float
     finished_at: float
     finish_reason: str = "length"
+    admitted_at: float = 0.0
+    prefill_started_at: float = 0.0
 
 
 class _Slot:
     __slots__ = (
         "req", "chunks", "cursor", "n_new", "last_token", "out",
         "first_token_at", "decoding", "pending_copy",
-        "t_prefill0", "occ_sum", "occ_n",
+        "t_admit", "t_prefill0", "occ_sum", "occ_n",
     )
 
     def __init__(
@@ -169,10 +175,12 @@ class _Slot:
         self.out = np.full((req.max_new_tokens,), pad, np.int32)
         self.first_token_at = 0.0
         self.decoding = False
-        # Tracing residuals (ATX_TRACE_REQUESTS=1): first prefill-chunk
-        # dispatch time, plus decode-residency accumulators (sum of batch
-        # occupancy over resident iterations) — plain float/int adds in the
-        # decode loop, emitted as ONE span at completion.
+        # Admission and first prefill-chunk dispatch, always stamped (the
+        # `request` record). Tracing residuals (ATX_TRACE_REQUESTS=1):
+        # decode-residency accumulators (sum of batch occupancy over
+        # resident iterations) — plain float/int adds in the decode loop,
+        # emitted as ONE span at completion.
+        self.t_admit = time.perf_counter()
         self.t_prefill0 = 0.0
         self.occ_sum = 0
         self.occ_n = 0
@@ -396,7 +404,9 @@ class Engine:
         _labels = ("engine",)
         self._tel_labels = self.stats.labels
         self._h_queue_wait = _telemetry.histogram(
-            "serve_queue_wait_ms", "submit -> slot admission", labels=_labels
+            "serve_queue_wait_ms",
+            "submit -> first prefill chunk (a slot and then the prefill queue)",
+            labels=_labels,
         )
         self._h_prefill_ms = _telemetry.histogram(
             "serve_prefill_step_ms", "wall per prefill scheduler step",
@@ -511,7 +521,6 @@ class Engine:
                     req,
                     np.full((req.max_new_tokens,), self.config.pad_token_id, np.int32),
                     0,
-                    0.0,
                 )
         for slot_id, slot in enumerate(self._slots):
             if slot is not None and slot.req.rid == rid:
@@ -529,23 +538,46 @@ class Engine:
                 self._free.append(slot_id)
                 self.stats["cancelled"] += 1
                 return self._cancelled_completion(
-                    slot.req, slot.out, slot.n_new, slot.first_token_at
+                    slot.req, slot.out, slot.n_new, slot
                 )
         return None
 
     def _cancelled_completion(
-        self, req: Request, tokens: np.ndarray, n_new: int, first_token_at: float
+        self, req: Request, tokens: np.ndarray, n_new: int, slot: _Slot | None = None
     ) -> Completion:
-        return Completion(
+        completion = Completion(
             rid=req.rid,
             prompt=req.prompt,
             tokens=tokens,
             n_new=n_new,
             text=self.detokenize(tokens[:n_new].tolist()) if self.detokenize else None,
             submitted_at=getattr(req, "submitted_at", 0.0),
-            first_token_at=first_token_at,
+            first_token_at=slot.first_token_at if slot else 0.0,
             finished_at=time.perf_counter(),
             finish_reason="cancelled",
+            admitted_at=slot.t_admit if slot else 0.0,
+            prefill_started_at=slot.t_prefill0 if slot else 0.0,
+        )
+        self._record_request(completion)
+        return completion
+
+    def _record_request(self, c: Completion) -> None:
+        """One `request` record per completion in the process's flight
+        recorder, tracing on or off: the black box holds the last requests
+        when a process dies, and `latency_summary` reads its exact samples
+        from them."""
+        _flight.record_span(
+            "request",
+            rid=c.rid,
+            t0=c.submitted_at,
+            t1=c.finished_at,
+            engine=self.stats.instance,
+            admitted_at=c.admitted_at,
+            prefill_started_at=c.prefill_started_at,
+            first_token_at=c.first_token_at,
+            finish_reason=c.finish_reason,
+            prompt_tokens=len(c.prompt),
+            new_tokens=c.n_new,
         )
 
     def abort_inflight(self) -> list[Completion]:
@@ -630,11 +662,6 @@ class Engine:
             )
             self._prefill_order.append(slot_id)
             self.stats["admitted"] += 1
-            submitted = getattr(req, "submitted_at", 0.0)
-            if submitted:
-                self._h_queue_wait.observe(
-                    (time.perf_counter() - submitted) * 1e3, **self._tel_labels
-                )
             self.stats["prompt_tokens"] += len(req.prompt)
             if matched:
                 self.stats["prefix_hits"] += 1
@@ -657,13 +684,19 @@ class Engine:
         # Engine-level chaos injection point (test_utils/faults.py): a
         # cheap env-membership check when no fault is armed.
         resilience.fault_point("engine.step")
-        self._admit()
+        # Which kind of step this is, known before admission so that the
+        # step's span covers it: a request admitted now is never decoding
+        # yet and always has a prefill chunk pending (a prefix match stops
+        # one token short of the prompt).
         decoding = [i for i, s in enumerate(self._slots) if s is not None and s.decoding]
-        if self._prefill_order and (not decoding or self._decode_credit <= 0):
+        will_prefill = self._prefill_order or (self._queue and self._free)
+        if will_prefill and (not decoding or self._decode_credit <= 0):
             self._decode_credit = self.prefill_interleave
             self.actions.append("prefill")
             t0 = time.perf_counter()
             with _telemetry.span("serve_prefill"):
+                with _telemetry.span("serve_admit"):
+                    self._admit()
                 out = self._prefill_step()
             self._h_prefill_ms.observe(
                 (time.perf_counter() - t0) * 1e3, **self._tel_labels
@@ -674,6 +707,8 @@ class Engine:
             self.actions.append("decode")
             t0 = time.perf_counter()
             with _telemetry.span("serve_decode"):
+                with _telemetry.span("serve_admit"):
+                    self._admit()
                 out = self._decode_step(decoding)
             self._h_decode_ms.observe(
                 (time.perf_counter() - t0) * 1e3, **self._tel_labels
@@ -718,89 +753,88 @@ class Engine:
     def _prefill_step(self) -> list[Completion]:
         slot_id = self._prefill_order[0]
         slot = self._slots[slot_id]
-        if slot.pending_copy is not None:
-            # Prefix-cache hit: copy the matched KV span out of the pool
-            # into this slot's row, chunked at bucket lengths (static per
-            # chunk — the jit cache stays bounded by the bucket set). The
-            # copies are dispatched BEFORE this slot's first prefill chunk,
-            # so in device order the chunk's attention over [0, cursor)
-            # reads committed prefix KV, never the pool row's future state.
-            node, matched = slot.pending_copy
-            t_copy0 = time.perf_counter() if self._trace else 0.0
-            off = 0
-            n_copy = 0
-            for ln in self.prefix_cache.chunks(matched):
-                self._kv = self._copy(
-                    self._kv, self._pool,
-                    np.int32(slot_id), np.int32(node.row), np.int32(off), ln,
-                )
-                self.copy_signatures.append(ln)
-                self.stats["prefix_copy_chunks"] += 1
-                off += ln
-                n_copy += 1
-            self.prefix_cache.release(node)
-            slot.pending_copy = None
-            if self._trace:
-                # Dispatch time only — the copies are async on device.
-                _flight.record_span(
-                    "prefix_copy",
-                    rid=slot.req.rid,
-                    t0=t_copy0,
-                    tokens=int(matched),
-                    chunks=n_copy,
-                )
         buf, real = slot.chunks.pop(0)
-        t_chunk0 = 0.0
-        compiles_before = 0
-        if self._trace:
-            if slot.t_prefill0 == 0.0:
-                slot.t_prefill0 = time.perf_counter()
-            t_chunk0 = time.perf_counter()
-            compiles_before = self._prefill._cache_size()
-        tok, self._kv = self._prefill(
-            self.params,
-            buf,
-            self._kv,
-            np.int32(slot_id),
-            np.int32(slot.cursor),
-            np.int32(real - 1),
-            np.uint32(slot.req.seed),
-        )
-        slot.cursor += real
-        self.stats["prefill_chunks"] += 1
-        self.prefill_signatures.append(buf.shape[1])
-        if self._trace:
-            _flight.record_span(
-                "prefill_chunk",
-                rid=slot.req.rid,
-                t0=t_chunk0,
-                bucket=int(buf.shape[1]),
-                tokens=int(real),
-                compile_miss=self._prefill._cache_size() > compiles_before,
+        if slot.t_prefill0 == 0.0:
+            slot.t_prefill0 = time.perf_counter()
+            submitted = getattr(slot.req, "submitted_at", 0.0)
+            if submitted:
+                self._h_queue_wait.observe(
+                    (slot.t_prefill0 - submitted) * 1e3, **self._tel_labels
+                )
+        with _telemetry.span(
+            "serve_dispatch", bucket=int(buf.shape[1]), slot=slot_id, rid=slot.req.rid
+        ):
+            if slot.pending_copy is not None:
+                self._copy_prefix(slot_id, slot)
+            t_chunk0 = 0.0
+            compiles_before = 0
+            if self._trace:
+                t_chunk0 = time.perf_counter()
+                compiles_before = self._prefill._cache_size()
+            tok, self._kv = self._prefill(
+                self.params,
+                buf,
+                self._kv,
+                np.int32(slot_id),
+                np.int32(slot.cursor),
+                np.int32(real - 1),
+                np.uint32(slot.req.seed),
             )
+            slot.cursor += real
+            self.stats["prefill_chunks"] += 1
+            self.prefill_signatures.append(buf.shape[1])
+            if self._trace:
+                _flight.record_span(
+                    "prefill_chunk",
+                    rid=slot.req.rid,
+                    t0=t_chunk0,
+                    bucket=int(buf.shape[1]),
+                    tokens=int(real),
+                    compile_miss=self._prefill._cache_size() > compiles_before,
+                )
         if slot.chunks:
             return []  # more prompt to go; tok was a throwaway
-        self._prefill_order.popleft()
-        slot.first_token_at = time.perf_counter()
-        slot.decoding = True
-        return self._emit(slot_id, int(tok))
+        with _telemetry.span("serve_fetch"):
+            first = int(tok)  # the host waits for the device here
+        with _telemetry.span("serve_emit"):
+            self._prefill_order.popleft()
+            slot.first_token_at = time.perf_counter()
+            slot.decoding = True
+            return self._emit(slot_id, first)
+
+    def _copy_prefix(self, slot_id: int, slot: _Slot) -> None:
+        """Prefix-cache hit: copy the matched KV span out of the pool into
+        this slot's row, chunked at bucket lengths (static per chunk — the
+        jit cache stays bounded by the bucket set). The copies are
+        dispatched BEFORE this slot's first prefill chunk, so in device
+        order the chunk's attention over [0, cursor) reads committed prefix
+        KV, never the pool row's future state."""
+        node, matched = slot.pending_copy
+        t_copy0 = time.perf_counter() if self._trace else 0.0
+        off = 0
+        n_copy = 0
+        for ln in self.prefix_cache.chunks(matched):
+            self._kv = self._copy(
+                self._kv, self._pool,
+                np.int32(slot_id), np.int32(node.row), np.int32(off), ln,
+            )
+            self.copy_signatures.append(ln)
+            self.stats["prefix_copy_chunks"] += 1
+            off += ln
+            n_copy += 1
+        self.prefix_cache.release(node)
+        slot.pending_copy = None
+        if self._trace:
+            # Dispatch time only — the copies are async on device.
+            _flight.record_span(
+                "prefix_copy",
+                rid=slot.req.rid,
+                t0=t_copy0,
+                tokens=int(matched),
+                chunks=n_copy,
+            )
 
     def _decode_step(self, decoding: list[int]) -> list[Completion]:
-        lengths = np.zeros((self.n_slots,), np.int32)
-        seeds = np.zeros((self.n_slots,), np.uint32)
-        steps = np.zeros((self.n_slots,), np.int32)
-        tokens: Any = np.zeros((self.n_slots,), np.int32)
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue  # free slot: garbage write at 0, overwritten by the
-                # next admission's first prefill chunk
-            # Mid-prefill slots ride along too: their cursor points at the
-            # next chunk's start, so the row's garbage write lands exactly
-            # where that chunk will overwrite it — never on committed KV.
-            tokens[i] = s.last_token
-            lengths[i] = s.cursor
-            seeds[i] = s.req.seed
-            steps[i] = s.n_new
         # Block dispatch: chain up to decode_block steps on device, bounded
         # by the smallest remaining budget (so no step past a known budget
         # eviction), then fetch all their tokens in ONE sync. Interleave
@@ -811,41 +845,62 @@ class Engine:
         ))
         if self._prefill_order:
             block = 1
-        if self._trace:
-            # Residency accounting: two attribute adds per resident slot —
-            # no per-iteration span, no allocation, nothing device-side.
-            occ = len(decoding)
-            for i in decoding:
-                s = self._slots[i]
-                s.occ_sum += occ * block
-                s.occ_n += block
-        fetched = []
-        # Commit the seed tokens to the cache's device so the chained calls
-        # (whose token input is the previous step's committed OUTPUT) share
-        # one jit signature with the first — otherwise the decode step
-        # silently compiles twice (committed vs uncommitted int32 (N,)).
-        tokens = jax.device_put(tokens, self._device)
-        for _ in range(block):
-            # The dispatch gets its own copies of the cursors: the transfer
-            # is asynchronous and can alias numpy memory, so it may still be
-            # reading a host buffer when the lines below advance it in place.
-            tokens, self._kv = self._decode(
-                self.params, tokens, lengths.copy(), self._kv, seeds, steps.copy()
-            )
-            fetched.append(tokens)
-            lengths[decoding] += 1
-            steps[decoding] += 1
-        host_tokens = [np.asarray(t) for t in jax.device_get(fetched)]
-        self.stats["decode_steps"] += block
-        self.stats["decode_slot_steps"] += block * len(decoding)
+        with _telemetry.span("serve_dispatch", resident=len(decoding), block=block):
+            lengths = np.zeros((self.n_slots,), np.int32)
+            seeds = np.zeros((self.n_slots,), np.uint32)
+            steps = np.zeros((self.n_slots,), np.int32)
+            tokens: Any = np.zeros((self.n_slots,), np.int32)
+            for i, s in enumerate(self._slots):
+                if s is None:
+                    continue  # free slot: garbage write at 0, overwritten by
+                    # the next admission's first prefill chunk
+                # Mid-prefill slots ride along too: their cursor points at
+                # the next chunk's start, so the row's garbage write lands
+                # exactly where that chunk will overwrite it — never on
+                # committed KV.
+                tokens[i] = s.last_token
+                lengths[i] = s.cursor
+                seeds[i] = s.req.seed
+                steps[i] = s.n_new
+            if self._trace:
+                # Residency accounting: two attribute adds per resident slot
+                # — no per-iteration span, no allocation, nothing device-side.
+                occ = len(decoding)
+                for i in decoding:
+                    s = self._slots[i]
+                    s.occ_sum += occ * block
+                    s.occ_n += block
+            fetched = []
+            # Commit the seed tokens to the cache's device so the chained
+            # calls (whose token input is the previous step's committed
+            # OUTPUT) share one jit signature with the first — otherwise the
+            # decode step silently compiles twice (committed vs uncommitted
+            # int32 (N,)).
+            tokens = jax.device_put(tokens, self._device)
+            for _ in range(block):
+                # The dispatch gets its own copies of the cursors: the
+                # transfer is asynchronous and can alias numpy memory, so it
+                # may still be reading a host buffer when the lines below
+                # advance it in place.
+                tokens, self._kv = self._decode(
+                    self.params, tokens, lengths.copy(), self._kv, seeds, steps.copy()
+                )
+                fetched.append(tokens)
+                lengths[decoding] += 1
+                steps[decoding] += 1
+        with _telemetry.span("serve_fetch"):
+            host_tokens = [np.asarray(t) for t in jax.device_get(fetched)]
         out: list[Completion] = []
-        for nxt in host_tokens:
-            for i in decoding:
-                slot = self._slots[i]
-                if slot is None or not slot.decoding:
-                    continue  # finished mid-block: later tokens are zombies
-                slot.cursor += 1
-                out.extend(self._emit(i, int(nxt[i])))
+        with _telemetry.span("serve_emit"):
+            self.stats["decode_steps"] += block
+            self.stats["decode_slot_steps"] += block * len(decoding)
+            for nxt in host_tokens:
+                for i in decoding:
+                    slot = self._slots[i]
+                    if slot is None or not slot.decoding:
+                        continue  # finished mid-block: later tokens are zombies
+                    slot.cursor += 1
+                    out.extend(self._emit(i, int(nxt[i])))
         return out
 
     def _emit(self, slot_id: int, tok: int) -> list[Completion]:
@@ -884,7 +939,10 @@ class Engine:
             first_token_at=slot.first_token_at,
             finished_at=time.perf_counter(),
             finish_reason="eos" if eos_hit else ("stop" if stop_hit else "length"),
+            admitted_at=slot.t_admit,
+            prefill_started_at=slot.t_prefill0,
         )
+        self._record_request(completion)
         if self._trace:
             # Contiguous phase spans — queue / prefill / decode / emit tile
             # [submitted_at, finished_at] exactly, so the `atx trace`
@@ -971,17 +1029,31 @@ class Engine:
 
     # ------------------------------------------------------------ metrics
     def latency_summary(self) -> dict:
-        """Registry-backed request-latency percentiles (ms, None until the
-        first completion) — the numbers behind `atx serve`'s ``serve_p50_ms``
-        / ``serve_ttft_p50_ms`` fields, estimated from the same histogram
-        series the `/metrics` endpoint exports."""
-        labels = self._tel_labels
+        """Exact request-latency percentiles (ms, None until the first
+        completion) over the `request` records this engine has in the flight
+        recorder's ring: submit -> completion and submit -> first token of
+        the requests that ran to their end (a caller whose requests were due
+        earlier adds its own lateness). The numbers behind `atx serve`'s
+        ``serve_p50_ms`` / ``serve_ttft_p50_ms``; `/metrics` exports the
+        same samples as fixed-bucket histograms."""
+        mine = [
+            r for r in _flight.recorder().last()
+            if r["name"] == "request"
+            and r["attrs"]["engine"] == self.stats.instance
+            and r["attrs"]["finish_reason"] != "cancelled"
+        ]
+        if not mine:
+            return dict.fromkeys(
+                ("p50_ms", "p99_ms", "ttft_p50_ms", "ttft_p99_ms", "mean_ms")
+            )
+        e2e = np.array([r["t1"] - r["t0"] for r in mine]) * 1e3
+        ttft = np.array([r["attrs"]["first_token_at"] - r["t0"] for r in mine]) * 1e3
         return {
-            "p50_ms": self._h_e2e.quantile(0.50, **labels),
-            "p99_ms": self._h_e2e.quantile(0.99, **labels),
-            "ttft_p50_ms": self._h_ttft.quantile(0.50, **labels),
-            "ttft_p99_ms": self._h_ttft.quantile(0.99, **labels),
-            "mean_ms": self._h_e2e.mean(**labels),
+            "p50_ms": float(np.percentile(e2e, 50)),
+            "p99_ms": float(np.percentile(e2e, 99)),
+            "ttft_p50_ms": float(np.percentile(ttft, 50)),
+            "ttft_p99_ms": float(np.percentile(ttft, 99)),
+            "mean_ms": float(e2e.mean()),
         }
 
     def prefix_metrics(self) -> dict:

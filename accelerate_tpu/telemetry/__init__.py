@@ -5,11 +5,11 @@ Dependency-free, hot-path-safe metrics + tracing for training and serving:
 - `telemetry.registry` — counters / gauges / fixed-bucket histograms with
   label sets, Prometheus text rendering, and cross-host aggregation via
   per-process JSON snapshots merged by proc 0 (no collectives).
-- `telemetry.spans` — wall-clock host spans as Chrome-trace JSONL, bridged
-  into XPlane via ``jax.profiler.TraceAnnotation`` when a
-  `utils/profiler.profile()` capture is running.
-- `telemetry.stepstats` — per-step dispatch-gap vs device-compute split,
-  EMA tokens/sec + achieved MFU, and a recompile counter, wired into the
+- `telemetry.spans` — wall-clock host spans as Chrome-trace JSONL; every
+  span is also a ``jax.profiler.TraceAnnotation``, so it lands in any
+  XPlane capture of the process, whoever started it.
+- `telemetry.stepstats` — per-step dispatch vs device-compute split,
+  EMA tokens/sec + hardware-FLOPs utilisation, and a recompile counter, wired into the
   `Accelerator` step helper behind ``ATX_METRICS`` (default on; zero device
   syncs unless ``ATX_METRICS_SAMPLE_EVERY`` turns the sampler on).
 - `telemetry.export` — stdlib-only Prometheus ``/metrics`` HTTP endpoint
@@ -65,7 +65,7 @@ from .registry import (
     snapshot,
     write_snapshot,
 )
-from .spans import chrome_trace, span, spans_enabled, start_trace_log, step_span, stop_trace_log
+from .spans import chrome_trace, span, start_trace_log, step_span, stop_trace_log
 from .stepstats import StepStats, peak_device_flops, tokens_in_batch
 from .views import StatsView
 
@@ -99,7 +99,6 @@ __all__ = [
     "render_snapshot_prometheus",
     "snapshot",
     "span",
-    "spans_enabled",
     "start_trace_log",
     "step_span",
     "stop_trace_log",

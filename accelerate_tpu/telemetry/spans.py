@@ -9,13 +9,16 @@ chrome://tracing expect). Nesting is tracked with a ``contextvars`` stack so
 events carry their parent span and spans in worker threads don't corrupt
 each other.
 
-When a `utils/profiler.py` XPlane capture is active, every span also enters
-a ``jax.profiler.TraceAnnotation`` so the same names line up against the
-device timeline in TensorBoard; ``step_span`` uses ``StepTraceAnnotation``
-so step-time views group ops by step number.
+Every span also enters a ``jax.profiler.TraceAnnotation`` (its keyword
+attributes become the event's stats), so the same names line up against the
+device timeline of any capture of this process, whoever started it: the
+program's `profile()`, a bare ``jax.profiler.start_trace``, a profiler
+server's capture button. ``step_span`` adds a ``StepTraceAnnotation`` so
+step-time views group ops by step number.
 
-Hot-path safety: with no span log open and no profiler trace running,
-``span()`` yields immediately — one contextvar read, no timestamps, no I/O.
+Hot-path safety: with no span log open ``span()`` *is* the annotation, and an
+annotation with no capture live is a check of one flag — no timestamps, no
+I/O.
 """
 
 from __future__ import annotations
@@ -29,7 +32,7 @@ import threading
 import time
 from typing import Any, Iterator
 
-from ..utils import profiler as _profiler
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 __all__ = [
     "span",
@@ -37,7 +40,6 @@ __all__ = [
     "start_trace_log",
     "stop_trace_log",
     "trace_log_path",
-    "spans_enabled",
     "chrome_trace",
 ]
 
@@ -145,60 +147,50 @@ def _maybe_open_from_env() -> "_JsonlWriter | None":
     return _writer
 
 
-def spans_enabled() -> bool:
-    """True when spans do real work (log open or XPlane capture running)."""
+def span(name: str, **attrs: Any):
+    """Context manager timing a host-side block. With no span log open it is
+    the bare `TraceAnnotation`: one flag check while nobody captures."""
     writer = _writer if _env_checked else _maybe_open_from_env()
-    return writer is not None or _profiler.trace_active()
+    if writer is None:
+        return TraceAnnotation(name, **attrs)
+    return _logged_span(writer, name, attrs)
 
 
 @contextlib.contextmanager
-def span(name: str, **attrs: Any) -> Iterator[None]:
-    """Time a host-side block; near-zero cost while tracing is off."""
-    writer = _writer if _env_checked else _maybe_open_from_env()
-    xplane = _profiler.trace_active()
-    if writer is None and not xplane:
-        yield
-        return
+def _logged_span(writer: _JsonlWriter, name: str, attrs: dict[str, Any]) -> Iterator[None]:
     stack = _SPAN_STACK.get()
     token = _SPAN_STACK.set(stack + (name,))
-    cm = _profiler.annotate(name) if xplane else contextlib.nullcontext()
     start = time.perf_counter()
     wall_us = time.time() * 1e6
     try:
-        with cm:
+        with TraceAnnotation(name, **attrs):
             yield
     finally:
         dur_us = (time.perf_counter() - start) * 1e6
         _SPAN_STACK.reset(token)
-        if writer is not None:
-            event: dict[str, Any] = {
-                "name": name,
-                "ph": "X",
-                "ts": wall_us,
-                "dur": dur_us,
-                "pid": _process_index(),
-                "tid": threading.get_ident() & 0xFFFFFFFF,
-            }
-            args = dict(attrs)
-            if stack:
-                args["parent"] = stack[-1]
-            if args:
-                event["args"] = args
-            writer.write(event)
+        event: dict[str, Any] = {
+            "name": name,
+            "ph": "X",
+            "ts": wall_us,
+            "dur": dur_us,
+            "pid": _process_index(),
+            "tid": threading.get_ident() & 0xFFFFFFFF,
+        }
+        args = dict(attrs)
+        if stack:
+            args["parent"] = stack[-1]
+        if args:
+            event["args"] = args
+        writer.write(event)
 
 
 @contextlib.contextmanager
 def step_span(step: int, name: str = "train") -> Iterator[None]:
-    """Span for one training step, bridged to ``StepTraceAnnotation`` when an
-    XPlane capture is running so TensorBoard numbers the steps."""
-    with _profiler.maybe_step_annotation(step, name=name):
+    """Span for one training step, under a ``StepTraceAnnotation`` so a
+    capture's step-time views number the steps."""
+    with StepTraceAnnotation(name, step_num=int(step)):
         with span(f"{name}_step", step=int(step)):
             yield
-
-
-def current_span() -> str | None:
-    stack = _SPAN_STACK.get()
-    return stack[-1] if stack else None
 
 
 def mirror_flight_event(

@@ -1,26 +1,26 @@
 """Step-time breakdown for the training loop.
 
 JAX dispatch is asynchronous: a jitted step call returns as soon as the work
-is enqueued, so host-side wall clocks around the call measure the *dispatch
-gap* (host Python + enqueue cost), not device compute. :class:`StepStats`
+is enqueued, so host-side wall clocks around the call measure the *dispatch*
+(host Python + enqueue cost), not device compute. :class:`StepStats`
 splits the two from host timestamps alone:
 
 - ``train_step_ms``: EMA of the interval between consecutive step entries —
   the true sustained step time once the pipeline is saturated (the device
   backpressures dispatch through the stream).
-- ``train_dispatch_gap_ms``: EMA of the jitted-call wall time — host time
-  the step spends NOT overlapping device work. When this approaches
-  ``train_step_ms`` the loop is host-bound.
+- ``train_dispatch_ms``: EMA of the jitted call's host wall time (not a gap
+  on the device: the device can be busy with the previous step meanwhile).
+  When this approaches ``train_step_ms`` the loop is host-bound.
 - ``train_device_ms``: on sampled steps only (``ATX_METRICS_SAMPLE_EVERY``,
   default 0 = never), a ``block_until_ready`` on the step outputs measures
   dispatch-begin -> outputs-ready — an upper bound on device compute
   including queued prior work. With sampling off there are ZERO device
   syncs: every other field is pure ``time.perf_counter`` + shape math.
-- ``train_tokens_per_sec`` / ``train_mfu``: EMA'd throughput from the batch
-  leaf shapes and achieved model-FLOPs utilisation via
+- ``train_tokens_per_sec`` / ``train_hfu``: EMA'd throughput from the batch
+  leaf shapes and *hardware* FLOPs utilisation via
   `utils/profiler.estimate_step_flops` (XLA's own cost analysis of the
-  compiled step) against the chip's peak — the ROADMAP's "where does the
-  step wall clock go" axis.
+  compiled step, so operations recomputed under remat count: it reads above
+  a model-FLOPs utilisation) against the chip's peak.
 - ``train_compiles``: jit cache-size deltas — recompiles on the hot path
   (the runtime twin of the ATX302 shape-drift lint).
 
@@ -123,15 +123,17 @@ class StepStats:
 
         self._g_step = reg.gauge(
             "train_step_ms", "EMA interval between step entries (ms)")
-        self._g_gap = reg.gauge(
-            "train_dispatch_gap_ms", "EMA wall time of the jitted dispatch (ms)")
+        self._g_dispatch = reg.gauge(
+            "train_dispatch_ms", "EMA host wall time of the jitted call (ms)")
         self._g_device = reg.gauge(
             "train_device_ms",
             "Sampled dispatch-begin to outputs-ready wall (ms)")
         self._g_tps = reg.gauge(
             "train_tokens_per_sec", "EMA training throughput", aggregate="sum")
-        self._g_mfu = reg.gauge(
-            "train_mfu", "Achieved model-FLOPs utilisation (0 when peak unknown)")
+        self._g_hfu = reg.gauge(
+            "train_hfu",
+            "Hardware FLOPs utilisation: XLA's count of the compiled step, "
+            "recomputed operations included (0 when peak unknown)")
         self._c_steps = reg.counter("train_steps", "Steps dispatched")
         self._c_compiles = reg.counter(
             "train_compiles", "Jit cache growth events (ATX302 runtime twin)")
@@ -159,7 +161,7 @@ class StepStats:
                 if tokens_per_step:
                     tps = self._ema("tps", tokens_per_step / interval_s)
                     self._g_tps.set(tps)
-                self._update_mfu(interval_s)
+                self._update_hfu(interval_s)
         self._last_entry = now
         self._t_entry = now
 
@@ -169,8 +171,9 @@ class StepStats:
         self._steps += 1
         self._c_steps.inc()
         if self._t_entry is not None:
-            gap_ms = self._ema("gap_ms", (now - self._t_entry) * 1e3)
-            self._g_gap.set(gap_ms)
+            self._g_dispatch.set(
+                self._ema("dispatch_ms", (now - self._t_entry) * 1e3)
+            )
         if cache_size is not None and cache_size > self._last_cache_size:
             self._compiles += cache_size - self._last_cache_size
             self._c_compiles.inc(cache_size - self._last_cache_size)
@@ -193,12 +196,12 @@ class StepStats:
         self._emas[key] = out
         return out
 
-    def _update_mfu(self, interval_s: float) -> None:
+    def _update_hfu(self, interval_s: float) -> None:
         if not self.peak_flops_total:
             # Unknown chip peak (e.g. CPU runs): report 0 and never call
             # flops_fn — resolving it may cost an AOT compile.
-            self._g_mfu.set(0.0)
-            self._emas.setdefault("mfu", 0.0)
+            self._g_hfu.set(0.0)
+            self._emas.setdefault("hfu", 0.0)
             return
         if not self._flops_resolved:
             self._flops_resolved = True
@@ -207,11 +210,11 @@ class StepStats:
             except Exception:
                 self._flops_per_step = None
         if self._flops_per_step:
-            mfu = self._flops_per_step / (interval_s * self.peak_flops_total)
-            self._g_mfu.set(self._ema("mfu", mfu))
+            hfu = self._flops_per_step / (interval_s * self.peak_flops_total)
+            self._g_hfu.set(self._ema("hfu", hfu))
         else:
-            self._g_mfu.set(0.0)
-            self._emas.setdefault("mfu", 0.0)
+            self._g_hfu.set(0.0)
+            self._emas.setdefault("hfu", 0.0)
 
     # -- read side ---------------------------------------------------------
 
@@ -230,9 +233,9 @@ class StepStats:
         bench lines — same field names as the registry gauges."""
         out = {
             "train_step_ms": self._emas.get("step_ms", 0.0),
-            "train_dispatch_gap_ms": self._emas.get("gap_ms", 0.0),
+            "train_dispatch_ms": self._emas.get("dispatch_ms", 0.0),
             "train_tokens_per_sec": self._emas.get("tps", 0.0),
-            "train_mfu": self._emas.get("mfu", 0.0),
+            "train_hfu": self._emas.get("hfu", 0.0),
             "train_compiles": float(self._compiles),
         }
         if self._sampled_device_ms is not None:

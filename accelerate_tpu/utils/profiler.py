@@ -27,16 +27,6 @@ import jax
 
 PROFILE_DIR_DEFAULT = "atx_profile"
 
-# Live XPlane captures started through profile(). telemetry/spans.py keys its
-# TraceAnnotation bridging off this, and the Accelerator step helper only
-# enters StepTraceAnnotation while a capture is running (docs/observability.md).
-_ACTIVE_TRACES = 0
-
-
-def trace_active() -> bool:
-    """True while a `profile()` XPlane capture is running in this process."""
-    return _ACTIVE_TRACES > 0
-
 
 @dataclass
 class ProfileKwargs:
@@ -88,12 +78,9 @@ def profile(
         create_perfetto_trace=kwargs.create_perfetto_trace,
         profiler_options=kwargs.build_options(),
     )
-    global _ACTIVE_TRACES
-    _ACTIVE_TRACES += 1
     try:
         yield kwargs
     finally:
-        _ACTIVE_TRACES -= 1
         jax.profiler.stop_trace()
         if kwargs.on_trace_ready is not None:
             kwargs.on_trace_ready(trace_dir)
@@ -108,16 +95,6 @@ def annotate(name: str, **kwargs: Any):
 def step_annotation(step: int, name: str = "train"):
     """Mark one training step so TensorBoard's step-time views group ops."""
     return jax.profiler.StepTraceAnnotation(name, step_num=step)
-
-
-def maybe_step_annotation(step: int, name: str = "train"):
-    """Step boundary for the Accelerator step helper: a
-    ``StepTraceAnnotation`` while a `profile()` capture is running (so XPlane
-    traces show numbered steps), a no-op context otherwise — keeping the
-    training hot path annotation-free when nobody is tracing."""
-    if trace_active():
-        return step_annotation(step, name=name)
-    return contextlib.nullcontext()
 
 
 def save_memory_profile(path: str) -> str:

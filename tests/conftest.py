@@ -49,6 +49,47 @@ def _reset_singletons():
     ProcessState._reset_state()
 
 
+@pytest.fixture
+def host_capture(tmp_path):
+    """``host_capture(body)`` runs ``body`` inside a profiler capture started
+    with the bare `jax.profiler.start_trace` — the way a benchmark or an
+    operator's capture button starts one, with nothing told to the program —
+    and returns the host plane's events in start order: name, start, end
+    (nanoseconds on the capture's clock) and stats."""
+    import glob
+    import tempfile
+    import warnings
+
+    from jax.profiler import ProfileData
+
+    def capture(body):
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        log_dir = tempfile.mkdtemp(dir=tmp_path)
+        jax.profiler.start_trace(log_dir, profiler_options=options)
+        try:
+            body()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb"))
+        events = []
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", DeprecationWarning)
+            for plane in ProfileData.from_file(path).planes:
+                if not plane.name.startswith("/host:"):
+                    continue
+                for line in plane.lines:
+                    for e in line.events:
+                        events.append({
+                            "name": e.name, "start": e.start_ns, "end": e.start_ns + e.duration_ns,
+                            "stats": {k: v for k, v in e.stats if not k.startswith("_")},
+                        })
+        return sorted(events, key=lambda e: (e["start"], -e["end"]))
+
+    return capture
+
+
 def pytest_addoption(parser):
     parser.addoption(
         "--heavy",

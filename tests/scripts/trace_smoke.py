@@ -101,7 +101,10 @@ def main() -> int:
 
     with patch_environment(ATX_TRACE_REQUESTS="0"):
         baseline = _serve_once(params, cfg)
-    assert flight.recorder().total == 0, "tracing off must record nothing"
+    off = [r["name"] for r in flight.recorder().last()]
+    assert off == ["request"] * REQUESTS, (
+        f"tracing off must record one `request` a completion and nothing finer: {off}"
+    )
 
     with tempfile.TemporaryDirectory() as td:
         trace_dir = os.path.join(td, "trace")

@@ -15,6 +15,7 @@ A compile that passes is not a chip run: nothing here produces a time.
 """
 
 import os
+import re
 import subprocess
 import sys
 
@@ -81,6 +82,10 @@ def _int8_matmul(x, w, s):
     return quant_matmul.int8_matmul_fused("mc,cn->mn", x, w, s, interpret=False)
 
 
+def _fp8_matmul(a, b, s):
+    return quant_matmul.scaled_matmul("mc,cn->mn", a, b, s, BF16, interpret=False)
+
+
 def _adamw(g, mu, nu, p, count, lr):
     return fused_adamw.fused_adamw_update(
         g, mu, nu, p, count, lr, 0.9, 0.999, 1e-8, 0.01, interpret=False
@@ -90,36 +95,80 @@ def _adamw(g, mu, nu, p, count, lr):
 _QKV = [((1, SEQ, H, HD), BF16), ((1, SEQ, K, HD), BF16), ((1, SEQ, K, HD), BF16)]
 _SLOT_Q = ((SLOTS, 1, H, HD), BF16)
 _LEAF = ((D, FF), F32)
-# name -> (function, [(shape, dtype)...], tpu_custom_calls expected)
+_QKV_SHORT = [((1, 2048, H, HD), BF16), ((1, 2048, K, HD), BF16), ((1, 2048, K, HD), BF16)]
+F8 = jnp.float8_e4m3fn
+_FLASH_BWD = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+# name -> (function, [(shape, dtype)...], the kernels expected, by the name each carries)
 KERNELS = {
-    "flash_fwd": (_flash, _QKV, 1),
-    "flash_fwd_bwd": (_flash_grad, _QKV, 3),
+    "flash_fwd": (_flash, _QKV, ["flash_fwd"]),
+    "flash_fwd_bwd": (_flash_grad, _QKV, _FLASH_BWD),
+    # Under 4096 tokens the whole K and V of a head stay in VMEM.
+    "flash_resident_fwd_bwd": (_flash_grad, _QKV_SHORT, [k + "_resident" for k in _FLASH_BWD]),
     "flash_decode_bf16": (
         _decode,
         [_SLOT_Q, ((SLOTS, SLOT_LEN, K, HD), BF16), ((SLOTS, SLOT_LEN, K, HD), BF16), ((SLOTS,), I32)],
-        1,
+        ["flash_decode"],
     ),
     "flash_decode_int8_kv": (
         _decode_int8,
         [_SLOT_Q, ((SLOTS, SLOT_LEN, K, HD), I8), ((SLOTS, SLOT_LEN, K, HD), I8), ((SLOTS,), I32),
          ((SLOTS, SLOT_LEN, K), BF16), ((SLOTS, SLOT_LEN, K), BF16)],
-        1,
+        ["flash_decode"],
     ),
     # The down projection of a 2048-token prefill: the whole contraction
     # (14336) staged per block was 43 MB of VMEM against a 16 MB limit.
-    "int8_matmul_prefill": (_int8_matmul, [((2048, FF), BF16), ((FF, D), I8), ((1, D), F32)], 1),
-    "int8_matmul_decode": (_int8_matmul, [((SLOTS, D), BF16), ((D, FF), I8), ((1, FF), F32)], 1),
-    "fused_adamw_leaf": (_adamw, [_LEAF, _LEAF, _LEAF, _LEAF, ((), I32), ((), F32)], 1),
+    "int8_matmul_prefill": (_int8_matmul, [((2048, FF), BF16), ((FF, D), I8), ((1, D), F32)], ["int8_matmul"]),
+    "int8_matmul_decode": (_int8_matmul, [((SLOTS, D), BF16), ((D, FF), I8), ((1, FF), F32)], ["int8_matmul"]),
+    "fp8_scaled_matmul": (_fp8_matmul, [((2048, D), F8), ((D, FF), F8), ((), F32)], ["scaled_matmul"]),
+    "fused_adamw_leaf": (_adamw, [_LEAF, _LEAF, _LEAF, _LEAF, ((), I32), ((), F32)], ["fused_adamw"]),
 }
 
 
 @pytest.mark.parametrize("name", list(KERNELS))
 def test_kernel_compiles_for_v5e(v5e, name):
-    fn, operands, expected_calls = KERNELS[name]
+    """The chip's compiler takes the kernel, and the compiled text carries
+    the kernel's name where a v5e trace shows it: an operation is named by
+    its HLO text there, and the name rides in the custom call's
+    ``frontend_attributes={kernel_metadata={...}}``."""
+    fn, operands, kernels = KERNELS[name]
     one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
     shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in operands]
     text = jax.jit(fn).lower(*shapes).compile().as_text()
-    assert text.count("tpu_custom_call") == expected_calls
+    assert text.count("tpu_custom_call") == len(kernels)
+    # (a kernel's get-tuple-elements repeat its attributes: a set, not a count)
+    named = re.findall(r'kernel_metadata=\{\s*"kernel":"(\w+)"', text)
+    assert set(named) == set(kernels)
+
+
+def test_every_pallas_call_in_the_package_is_named():
+    """Each `pl.pallas_call(` site takes its keywords from
+    `tuned_call_kwargs`, which always gives it a name and the metadata that
+    reaches the trace: a kernel added without one fails here, not in a
+    trace somebody reads months later."""
+    import ast
+
+    from accelerate_tpu.ops.flash_attention import tuned_call_kwargs
+
+    kwargs = tuned_call_kwargs("some_kernel", False, ("parallel",))
+    assert kwargs["name"] == "some_kernel" and kwargs["metadata"] == {"kernel": "some_kernel"}
+    assert tuned_call_kwargs("k", True)["interpret"] is True
+    sites, helpers = [], ("tuned_call_kwargs", "_call_kwargs")
+    package = os.path.join(REPO, "accelerate_tpu")
+    for base, _, files in os.walk(package):
+        for f in files:
+            if not f.endswith(".py"):
+                continue
+            path = os.path.join(base, f)
+            for node in ast.walk(ast.parse(open(path).read())):
+                if isinstance(node, ast.Call) and getattr(node.func, "attr", None) == "pallas_call":
+                    spread = [k.value for k in node.keywords if k.arg is None]
+                    ok = any(
+                        isinstance(v, ast.Call) and getattr(v.func, "id", None) in helpers
+                        for v in spread
+                    )
+                    sites.append((os.path.relpath(path, REPO), node.lineno, ok))
+    assert len(sites) == 9, sites
+    assert [s for s in sites if not s[2]] == []
 
 
 def test_flash_partitions_over_a_described_mesh(v5e):

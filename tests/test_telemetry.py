@@ -346,7 +346,7 @@ class TestStepStats:
         assert stats.compiles == 3
         assert stats.latest()["train_compiles"] == 3.0
 
-    def test_mfu_never_resolves_flops_when_peak_unknown(self):
+    def test_hfu_never_resolves_flops_when_peak_unknown(self):
         resolved = []
         stats = StepStats(
             registry=Registry(),
@@ -358,9 +358,9 @@ class TestStepStats:
             stats.on_entry(tokens_per_step=8)
             stats.on_dispatched()
         assert resolved == []
-        assert stats.latest()["train_mfu"] == 0.0
+        assert stats.latest()["train_hfu"] == 0.0
 
-    def test_mfu_with_known_peak(self):
+    def test_hfu_with_known_peak(self):
         import time
 
         stats = StepStats(
@@ -376,9 +376,9 @@ class TestStepStats:
             time.sleep(0.005)
         latest = stats.latest()
         assert latest["train_step_ms"] > 0
-        # ema_alpha=1: mfu == flops / (last_interval * peak), ~2e-4 for a
+        # ema_alpha=1: hfu == flops / (last_interval * peak), ~2e-4 for a
         # ~5 ms loop — the point is it resolved flops_fn and is sane.
-        assert 0 < latest["train_mfu"] < 1.0
+        assert 0 < latest["train_hfu"] < 1.0
 
     def test_tokens_in_batch_prefers_integer_leaves(self):
         batch = {
@@ -408,12 +408,61 @@ class TestSpans:
         trace = spans_mod.chrome_trace(path)
         assert {e["ph"] for e in trace["traceEvents"]} == {"X"}
 
-    def test_span_is_noop_without_writer(self):
-        # No writer, no profiler trace: the context manager must not write
-        # anywhere or raise — the hot-path fast path.
-        assert not spans_mod.spans_enabled()
-        with spans_mod.span("nothing"):
+    def test_span_without_writer_writes_nowhere(self, tmp_path, monkeypatch):
+        # No log open: the span is the bare profiler annotation (one flag
+        # check while nobody captures) and must not write anywhere or raise.
+        monkeypatch.chdir(tmp_path)
+        assert spans_mod.trace_log_path() is None
+        with spans_mod.span("nothing", bucket=8):
             pass
+        with spans_mod.step_span(3):
+            pass
+        assert os.listdir(tmp_path) == []
+
+    @pytest.mark.parametrize("log_open", [False, True], ids=["no-log", "log-open"])
+    def test_span_reaches_a_capture_nobody_told_the_program_about(
+        self, tmp_path, host_capture, log_open
+    ):
+        """A capture started by bare `jax.profiler.start_trace` (a benchmark,
+        a profiler server's button) holds the program's spans on its host
+        plane, attributes included: there is no gate to raise first."""
+        if log_open:
+            spans_mod.start_trace_log(str(tmp_path / "spans.jsonl"))
+        try:
+            events = {e["name"]: e for e in host_capture(_spans_under_test)}
+        finally:
+            spans_mod.stop_trace_log()
+        assert events["unit_outer"]["stats"] == {"bucket": 64, "rid": 7}
+        assert events["train_step"]["stats"]["step"] == 5
+        assert events["train"]["stats"]["step_num"] == 5  # the StepTraceAnnotation
+        o, i = events["unit_outer"], events["unit_inner"]
+        assert o["start"] <= i["start"] and i["end"] <= o["end"]
+        if log_open:
+            logged = [json.loads(l) for l in open(tmp_path / "spans.jsonl")]
+            assert [e["name"] for e in logged] == ["unit_inner", "unit_outer", "train_step"]
+
+    def test_no_capture_gate_is_left_in_the_package(self):
+        import accelerate_tpu
+        from accelerate_tpu.utils import profiler
+
+        for gone in ("trace_active", "maybe_step_annotation", "_ACTIVE_TRACES"):
+            assert not hasattr(profiler, gone)
+        assert not hasattr(spans_mod, "current_span")
+        assert not hasattr(telemetry, "spans_enabled")
+        root = os.path.dirname(accelerate_tpu.__file__)
+        for base, _, files in os.walk(root):
+            for f in files:
+                if f.endswith(".py"):
+                    text = open(os.path.join(base, f)).read()
+                    assert "trace_active" not in text and "maybe_step_annotation" not in text, f
+
+
+def _spans_under_test():
+    with spans_mod.span("unit_outer", bucket=64, rid=7):
+        with spans_mod.span("unit_inner"):
+            pass
+    with spans_mod.step_span(5):
+        pass
 
 
 # ------------------------------------------------- training integration
@@ -457,8 +506,16 @@ class TestTrainingIntegration:
         assert stats.compiles == 1  # one shape -> one jit entry
         latest = stats.latest()
         assert latest["train_step_ms"] > 0
-        assert latest["train_mfu"] == 0.0  # CPU: peak unknown
+        assert latest["train_hfu"] == 0.0  # CPU: peak unknown
         assert "train_device_ms" not in latest  # sampler off -> no syncs
+
+    def test_train_steps_are_numbered_in_a_bare_capture(self, host_capture):
+        """`Accelerator`'s step helper enters `step_span` around the jitted
+        call: a capture it knows nothing of shows numbered steps."""
+        events = host_capture(lambda: _train_losses(3))
+        steps = [e["stats"]["step_num"] for e in events if e["name"] == "train"]
+        assert steps == [1, 2, 3]
+        assert [e["stats"]["step"] for e in events if e["name"] == "train_step"] == [1, 2, 3]
 
     def test_zero_syncs_through_real_train_loop(self, monkeypatch):
         calls = []

@@ -12,7 +12,11 @@ The ISSUE-15 acceptance matrix:
   a bundle is refused when the spans key is missing;
 - **bit-identity**: greedy outputs through a 2-replica Router are
   BIT-IDENTICAL with ``ATX_TRACE_REQUESTS=1`` vs ``0`` — tracing must
-  never perturb the numerics;
+  never perturb the numerics; with it off the ring holds exactly one
+  `request` record per completion, its five timestamps in order;
+- **engine step phases**: in a capture the engine knows nothing of, the
+  `serve_admit` / `serve_dispatch` / `serve_fetch` / `serve_emit` spans
+  tile each `serve_prefill` / `serve_decode` parent;
 - **exactly-once semantics through failover**: a replica killed
   mid-decode leaves BOTH dispatch spans in the trace (attempt 1 and the
   retry), while stream spans still count each delivered token once;
@@ -182,16 +186,89 @@ class TestTracedServing:
         reqs = _requests(8)
         with patch_environment(ATX_TRACE_REQUESTS="0"):
             off = self._serve(params, reqs)
-        assert flight.recorder().total == 0  # off really is zero records
+        # Off is the always-on black box and nothing finer: one `request`
+        # record per completion.
+        records = flight.recorder().last()
+        assert [r["name"] for r in records] == ["request"] * 8
+        assert {r["rid"] for r in records} == set(off)
+        for r in records:
+            c, a = off[r["rid"]], r["attrs"]
+            stamps = [r["t0"], a["admitted_at"], a["prefill_started_at"],
+                      a["first_token_at"], r["t1"]]
+            assert stamps == sorted(stamps) and stamps[0] > 0
+            assert stamps[1:] == [c.admitted_at, c.prefill_started_at,
+                                  c.first_token_at, c.finished_at]
+            assert c.submitted_at <= r["t0"]  # the router's admission came first
+            assert a["prompt_tokens"] == len(c.prompt) and a["new_tokens"] == c.n_new
+            assert a["finish_reason"] == c.finish_reason and a["engine"]
         with patch_environment(ATX_TRACE_REQUESTS="1"):
             on = self._serve(params, _requests(8))
-        assert flight.recorder().total > 0
+        assert len(_spans_by_name("request")) == 16  # still one a completion
+        assert flight.recorder().total > 16
         assert set(on) == set(off)
         for rid in off:
             np.testing.assert_array_equal(
                 off[rid].tokens, on[rid].tokens,
                 err_msg=f"rid {rid}: tracing perturbed the output",
             )
+
+    def test_cancelled_request_is_recorded_and_left_out_of_the_summary(self, params):
+        engine = _engine(params)
+        done = engine.submit(np.arange(1, 9, dtype=np.int32), max_new_tokens=4)
+        completions = engine.run_until_idle()
+        queued = engine.submit(np.arange(1, 9, dtype=np.int32), max_new_tokens=4)
+        assert engine.cancel(queued).finish_reason == "cancelled"
+        by_rid = {r["rid"]: r for r in _spans_by_name("request")}
+        assert set(by_rid) == {done, queued}
+        assert by_rid[queued]["attrs"]["finish_reason"] == "cancelled"
+        assert by_rid[queued]["attrs"]["admitted_at"] == 0.0  # never reached a slot
+        # exact samples, not bucket estimates: one finished request is its own median
+        (c,) = completions
+        summary = engine.latency_summary()
+        assert summary["p50_ms"] == pytest.approx((c.finished_at - c.submitted_at) * 1e3)
+        assert summary["ttft_p50_ms"] == pytest.approx((c.first_token_at - c.submitted_at) * 1e3)
+        assert _engine(params).latency_summary()["p50_ms"] is None  # another engine's ring share
+
+    @pytest.mark.parametrize("decode_block", [1, 3])
+    def test_step_phase_spans_tile_their_parent(self, params, host_capture, decode_block):
+        """Started by the bare profiler API, with no `profile()` context and
+        no span log: every engine step is one `serve_prefill` or
+        `serve_decode` span whose children, in order and without overlap,
+        are admit, dispatch, fetch (when the host waits for a token) and
+        emit, and cover it."""
+        engine = _engine(params, decode_block=decode_block)
+        for r in _requests(5, seed=2):
+            engine.submit_request(r)
+        engine.step()  # compile outside the capture
+        events = host_capture(engine.run_until_idle)
+        parents = [e for e in events if e["name"] in ("serve_prefill", "serve_decode")]
+        assert len(parents) == len(engine.actions) - 1
+        assert [p["name"].removeprefix("serve_") for p in parents] == engine.actions[1:]
+        covered = total = 0
+        for parent in parents:
+            children = [
+                e for e in events
+                if e["name"] in ("serve_admit", "serve_dispatch", "serve_fetch", "serve_emit")
+                and parent["start"] <= e["start"] and e["end"] <= parent["end"]
+            ]
+            names = [c["name"] for c in children]
+            if parent["name"] == "serve_decode":
+                assert names == ["serve_admit", "serve_dispatch", "serve_fetch", "serve_emit"]
+                assert children[1]["stats"]["resident"] >= 1
+                assert 1 <= children[1]["stats"]["block"] <= decode_block
+            else:  # fetch and emit only on a prompt's last chunk
+                assert names in (["serve_admit", "serve_dispatch"],
+                                 ["serve_admit", "serve_dispatch", "serve_fetch", "serve_emit"])
+                assert children[1]["stats"]["bucket"] == 8
+                assert {"slot", "rid"} <= set(children[1]["stats"])
+            for a, b in zip(children, children[1:]):
+                assert a["end"] <= b["start"]
+            covered += sum(c["end"] - c["start"] for c in children)
+            total += parent["end"] - parent["start"]
+        assert covered / total > 0.9  # the rest is the Python between two spans
+        blocks = [e["stats"]["block"] for e in events
+                  if e["name"] == "serve_dispatch" and "block" in e["stats"]]
+        assert max(blocks) == decode_block
 
     def test_request_lifecycle_spans_present(self, params):
         with patch_environment(ATX_TRACE_REQUESTS="1"):
