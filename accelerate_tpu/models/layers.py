@@ -17,6 +17,8 @@ Conventions: ``B`` batch, ``S`` sequence, ``D`` model dim, ``H`` heads,
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 from functools import partial
 from typing import Any
@@ -82,13 +84,6 @@ def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array, eps: float = 1e-
     return (x * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dtype)
 
 
-# Crossover where forward_with_cache switches the KV cache from scan xs/ys
-# (restacked every step — cheap while the cache is small) to an in-place scan
-# carry (no per-step restack; measured 1.3x decode at 16k ctx on one v5e).
-# Shared by every family's cache path so the layouts can't silently diverge.
-CARRY_CACHE_MIN_LEN = 4096
-
-
 # ------------------------------------------------------------------ kv cache
 def cache_positions(start: jax.Array, t_new: int, batch: int) -> jax.Array:
     """(B, T_new) logical positions for tokens appended at ``start``.
@@ -124,26 +119,59 @@ def cache_write(buf: jax.Array, new: jax.Array, start: jax.Array) -> jax.Array:
 
 def cache_write_stacked(
     all_buf: jax.Array, i: jax.Array, rows: jax.Array, start: jax.Array
-) -> tuple[jax.Array, jax.Array]:
+) -> jax.Array:
     """Write ``rows`` (B, T, ...) into layer ``i`` of a layer-stacked cache
-    buffer (L, B, S, ...) at offset ``start`` (scalar or (B,) — see
-    `cache_write`). Returns (updated stacked buffer, updated (B, S, ...)
-    layer) so carry-layout scan bodies can attend against the fresh layer
-    without re-slicing. Shared by every family's carry cache path."""
+    buffer (L, B, S, ...) at offset ``start`` and return the updated stack.
+    Only the new rows move, whatever the cursor's rank: a scalar ``start`` is
+    one ``dynamic_update_slice`` at ``(i, 0, start)``; a (B,) ``start`` (the
+    engine's per-slot cursors, speculative decoding's per-row commits) is one
+    scatter of B x T rows into ``[i, b, start_b + t]``. A scattered row that
+    would land past the end of the buffer is dropped. Shared by every
+    family's cache path, which carries the stack through its layer scan."""
     start = jnp.asarray(start, jnp.int32)
-    lead = (0,) * (all_buf.ndim - 1)
-    full = (1,) + all_buf.shape[1:]
-    if start.ndim == 1:
-        layer = jax.lax.dynamic_slice(all_buf, (i,) + lead, full)[0]
-        layer = cache_write(layer, rows, start)
-        all_buf = jax.lax.dynamic_update_slice(all_buf, layer[None], (i,) + lead)
-        return all_buf, layer
-    idx = (i, 0, start) + (0,) * (all_buf.ndim - 3)
-    all_buf = jax.lax.dynamic_update_slice(
-        all_buf, rows.astype(all_buf.dtype)[None], idx
+    rows = rows.astype(all_buf.dtype)
+    if start.ndim == 0:
+        idx = (i, 0, start) + (0,) * (all_buf.ndim - 3)
+        return jax.lax.dynamic_update_slice(all_buf, rows[None], idx)
+    B, T = rows.shape[:2]
+    pos = start[:, None] + jnp.arange(T, dtype=jnp.int32)
+    return all_buf.at[i, jnp.arange(B)[:, None], pos].set(
+        rows, mode="drop", unique_indices=True, indices_are_sorted=True
     )
-    layer = jax.lax.dynamic_slice(all_buf, (i,) + lead, full)[0]
-    return all_buf, layer
+
+
+def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(B, T, K, h) -> int8 values + per-(token, head) scales."""
+    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1)
+    scale = jnp.maximum(amax / 127.0, 1e-8)
+    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale[..., None]), -127, 127)
+    return q.astype(jnp.int8), scale.astype(jnp.bfloat16)
+
+
+def dequant_kv(vals: jax.Array, scales: jax.Array, dtype) -> jax.Array:
+    """Inverse of `quantize_kv` — the ONE place the dequant arithmetic lives
+    outside the flash-decode kernel."""
+    return vals.astype(dtype) * scales[..., None].astype(dtype)
+
+
+def cache_append(
+    kv: dict[str, jax.Array], i: jax.Array, k: jax.Array, v: jax.Array, start: jax.Array
+) -> dict[str, jax.Array]:
+    """Write one layer's new keys and values (B, T, K, h) into the stacked
+    cache leaves ``kv`` (a family cache without its ``length`` cursor: ``k``
+    / ``v`` of shape (L, B, S, K*h) and, for an int8 cache, ``k_scale`` /
+    ``v_scale`` of shape (L, B, S, K)) at layer ``i``, offset ``start``."""
+    B, T = k.shape[:2]
+    if "k_scale" in kv:
+        k, k_scale = quantize_kv(k)
+        v, v_scale = quantize_kv(v)
+        new = {"k_scale": k_scale, "v_scale": v_scale}
+    else:
+        new = {}
+    new["k"], new["v"] = k.reshape(B, T, -1), v.reshape(B, T, -1)
+    return {
+        name: cache_write_stacked(buf, i, new[name], start) for name, buf in kv.items()
+    }
 
 
 def cache_slot_view(kv: Any, slot: jax.Array) -> Any:
@@ -353,33 +381,71 @@ def dot_product_attention(
     return out.reshape(B, S, H, h)
 
 
-def cached_decode_attention(
+_ATTENTION_PATHS: contextvars.ContextVar[list[str] | None] = contextvars.ContextVar(
+    "atx_cached_attention_paths", default=None
+)
+
+
+@contextlib.contextmanager
+def record_attention_paths():
+    """Collect which lowering every `cached_attention` call traced inside the
+    block took: ``"in_place"`` (the flash-decode kernel reads the stacked
+    cache where it lies) or ``"sliced"`` (one layer sliced out of the stack
+    for `dot_product_attention`). Trace-time bookkeeping only — the serving
+    engine wraps its decode program's trace in it, so a silent fall to the
+    sliced lowering shows in ``Engine.stats['decode_in_place']``."""
+    paths: list[str] = []
+    token = _ATTENTION_PATHS.set(paths)
+    try:
+        yield paths
+    finally:
+        _ATTENTION_PATHS.reset(token)
+
+
+def cached_attention(
     q: jax.Array,
-    k_full: jax.Array,
-    v_full: jax.Array,
+    kv: dict[str, jax.Array],
+    i: jax.Array,
     *,
     mask: jax.Array | None = None,
     lengths: jax.Array | None = None,
-    kv_raw=None,
     window: int | None = None,
 ) -> jax.Array:
-    """Decode-step attention over a slot KV cache.
+    """Attention of ``q`` (B, T_new, H, h) over layer ``i`` of the stacked
+    cache leaves ``kv`` (see `cache_append`).
 
-    Routes through the `native/pallas` flash-decode kernel when the
-    `decode_attn` kernel is enabled and the shapes are supported (single
-    query token, no sliding window, cursor-masked by ``lengths``); otherwise
-    the reference `dot_product_attention` with the full cache ``mask`` — the
-    exact current lowering, so with kernels off this function is
-    byte-identical to calling the reference directly. ``kv_raw`` optionally
-    carries the raw int8 cache + scales so the kernel fuses the dequant.
-    """
-    if lengths is not None and window is None and q.shape[1] == 1:
+    One cache layout, two lowerings, chosen by what the shapes show. A decode
+    step (one query token, cursor-masked by ``lengths``, no sliding window)
+    whose shapes the `native/pallas` flash-decode kernel supports, with the
+    `decode_attn` kernel enabled, reads the stack in place: the kernel takes
+    the whole buffers and the layer index, int8 dequant included. Everything
+    else (prefill, a window, kernels off, the CPU) slices layer ``i`` out of
+    the stack and runs the reference `dot_product_attention` with the full
+    cache ``mask``."""
+    out = None
+    if lengths is not None and window is None:
         from ..native.pallas.decode_attention import maybe_flash_decode
 
-        out = maybe_flash_decode(q, k_full, v_full, lengths, kv_raw=kv_raw)
-        if out is not None:
-            return out
-    return dot_product_attention(q, k_full, v_full, mask=mask)
+        out = maybe_flash_decode(
+            q, kv["k"], kv["v"], lengths, i,
+            k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"),
+        )
+    paths = _ATTENTION_PATHS.get()
+    if paths is not None:
+        paths.append("sliced" if out is None else "in_place")
+    if out is not None:
+        return out
+    layer = {
+        name: jax.lax.dynamic_index_in_dim(buf, i, 0, keepdims=False)
+        for name, buf in kv.items()
+    }
+    heads = layer["k"].shape[:2] + (-1, q.shape[-1])  # (B, S, K*h) -> (B, S, K, h)
+    k, v = layer["k"].reshape(heads), layer["v"].reshape(heads)
+    if "k_scale" in layer:
+        # Dequant stays elementwise on the sliced layer: HBM reads int8.
+        k = dequant_kv(k, layer["k_scale"], q.dtype)
+        v = dequant_kv(v, layer["v_scale"], q.dtype)
+    return dot_product_attention(q, k.astype(q.dtype), v.astype(q.dtype), mask=mask)
 
 
 # ------------------------------------------------------------------ attention block

@@ -30,15 +30,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from .layers import (
-    CARRY_CACHE_MIN_LEN,
     AttentionSpec,
     apply_rope,
     attention_out,
     attention_qkv,
+    cache_append,
     cache_positions,
     cache_write,
-    cache_write_stacked,
-    cached_decode_attention,
+    cached_attention,
     cross_entropy_loss,
     dot_product_attention,
     init_attention,
@@ -427,9 +426,13 @@ def init_cache(
     the context is long (at 32k the cache outweighs a 443M model's weights
     ~2:1). Dequantization fuses into the attention matmuls; accuracy is the
     standard per-token-scale int8 KV trade (logit drift ~1e-2, tested)."""
-    shape = (config.n_layers, batch_size, max_len, config.num_kv_heads, config.resolved_head_dim)
+    kv_heads, head_dim = config.num_kv_heads, config.resolved_head_dim
+    # Heads are flattened into the last axis: (L, B, T, K*h) is the shape the
+    # flash-decode kernel's blocks index, so a decode step reads the stack in
+    # place (`layers.cached_attention`); head kk is lanes [kk*h, (kk+1)*h).
+    shape = (config.n_layers, batch_size, max_len, kv_heads * head_dim)
     if dtype == jnp.int8:
-        scale_shape = shape[:-1]
+        scale_shape = shape[:-1] + (kv_heads,)
         return {
             "k": jnp.zeros(shape, jnp.int8),
             "v": jnp.zeros(shape, jnp.int8),
@@ -442,20 +445,6 @@ def init_cache(
         "v": jnp.zeros(shape, dtype),
         "length": jnp.zeros((), jnp.int32),
     }
-
-
-def _quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """(B, T, H, h) -> int8 values + per-(token, head) scales."""
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1)
-    scale = jnp.maximum(amax / 127.0, 1e-8)
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale[..., None]), -127, 127)
-    return q.astype(jnp.int8), scale.astype(jnp.bfloat16)
-
-
-def _dequant_kv(vals: jax.Array, scales: jax.Array, dtype) -> jax.Array:
-    """Inverse of `_quantize_kv` — the ONE place the dequant arithmetic
-    lives, whatever the cache layout indexes look like."""
-    return vals.astype(dtype) * scales[..., None].astype(dtype)
 
 
 def forward_with_cache(
@@ -492,134 +481,37 @@ def forward_with_cache(
         )
 
     x = params["embed"][tokens]
-    int8_kv = cache["k"].dtype == jnp.int8
-    # Long contexts keep the stacked cache in the scan CARRY: as xs/ys the
-    # scan RESTACKS the whole cache every step (read+write), which becomes
-    # the decode roofline once the per-row context is long — measured on
-    # v5e at 16k ctx / 443M / B=1: 77.5 -> 100.7 tok/s bf16, 111.4 with
-    # int8. Short contexts keep the xs/ys layout (the restack is cheap
-    # there and the carry's dynamic-slice read measured ~7% slower at
-    # 2k/B=8). The threshold is static — the choice costs nothing at trace
-    # time and both paths are numerically identical (tested).
-    carry_cache = max_len >= CARRY_CACHE_MIN_LEN
-
     # Decode steps (T_new == 1) may take the Pallas flash-decode kernel:
     # valid prefix per row after the write is positions[:, 0] + 1 (works for
     # the scalar cursor and the per-row speculative cursors alike). Prefill
     # and sliding-window configs always run the masked reference attention.
     decode_lengths = positions[:, 0] + 1 if T_new == 1 else None
 
-    def attend(block, x, q, k_full, v_full, kv_raw=None):
-        attn = cached_decode_attention(
-            q, k_full, v_full, mask=mask, lengths=decode_lengths,
-            kv_raw=kv_raw, window=config.sliding_window,
-        )
-        x = x + attention_out(block["attn"], attn)
-        h = rms_norm(x, block["mlp_norm"], config.norm_eps)
-        ffn_out, _ = _ffn(block, h, config)  # aux unused at inference
-        return x + ffn_out
-
-    def project(block, x):
+    # The layer-stacked cache rides the scan CARRY at every length: a step
+    # writes its new rows into the donated buffers and attends against them
+    # there (`layers.cache_append`, `layers.cached_attention`). As xs/ys the
+    # scan would restack the whole cache every step.
+    def scan_body(carry, block):
+        x, kv, i = carry
+        block = _maybe_dequantize(block, x.dtype)
         h = rms_norm(x, block["attn_norm"], config.norm_eps)
         q, k, v = attention_qkv(block["attn"], h)
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
-        return q, k, v
+        kv = cache_append(kv, i, k, v, start)
+        attn = cached_attention(
+            q, kv, i, mask=mask, lengths=decode_lengths, window=config.sliding_window
+        )
+        x = x + attention_out(block["attn"], attn)
+        h = rms_norm(x, block["mlp_norm"], config.norm_eps)
+        ffn_out, _ = _ffn(block, h, config)  # aux unused at inference
+        return (x + ffn_out, kv, i + 1), None
 
-    if carry_cache:
-        def _update_layer(all_buf, i, rows):
-            return cache_write_stacked(all_buf, i, rows, start)
-
-        def scan_body(carry, block):
-            if int8_kv:
-                x, k_all, v_all, ks_all, vs_all, i = carry
-            else:
-                x, k_all, v_all, i = carry
-            block = _maybe_dequantize(block, x.dtype)
-            q, k, v = project(block, x)
-            q_dtype = x.dtype
-            if int8_kv:
-                kq, ks = _quantize_kv(k)
-                vq, vs = _quantize_kv(v)
-                k_all, k_layer = _update_layer(k_all, i, kq)
-                v_all, v_layer = _update_layer(v_all, i, vq)
-                ks_all, ks_layer = _update_layer(ks_all, i, ks)
-                vs_all, vs_layer = _update_layer(vs_all, i, vs)
-                # Dequant stays elementwise on the sliced layer: HBM reads int8.
-                k_full = _dequant_kv(k_layer, ks_layer, q_dtype)
-                v_full = _dequant_kv(v_layer, vs_layer, q_dtype)
-                # Raw cache for the flash-decode kernel: when it runs, the
-                # dequantized copies above are dead and XLA drops them.
-                kv_raw = (k_layer, ks_layer, v_layer, vs_layer)
-            else:
-                k_all, k_layer = _update_layer(k_all, i, k)
-                v_all, v_layer = _update_layer(v_all, i, v)
-                k_full = k_layer.astype(q_dtype)
-                v_full = v_layer.astype(q_dtype)
-                kv_raw = None
-            x = attend(block, x, q, k_full, v_full, kv_raw)
-            if int8_kv:
-                return (x, k_all, v_all, ks_all, vs_all, i + 1), None
-            return (x, k_all, v_all, i + 1), None
-
-        layer0 = jnp.zeros((), jnp.int32)
-        if int8_kv:
-            carry = (x, cache["k"], cache["v"], cache["k_scale"], cache["v_scale"], layer0)
-            (x, new_k, new_v, new_ks, new_vs, _), _ = jax.lax.scan(
-                scan_body, carry, params["blocks"]
-            )
-            new_cache = {
-                "k": new_k, "v": new_v, "k_scale": new_ks, "v_scale": new_vs,
-                "length": start + T_new,
-            }
-        else:
-            (x, new_k, new_v, _), _ = jax.lax.scan(
-                scan_body, (x, cache["k"], cache["v"], layer0), params["blocks"]
-            )
-            new_cache = {"k": new_k, "v": new_v, "length": start + T_new}
-    else:
-        def scan_body(carry, xs):
-            x = carry
-            if int8_kv:
-                block, k_cache, v_cache, k_sc, v_sc = xs
-            else:
-                block, k_cache, v_cache = xs
-            block = _maybe_dequantize(block, x.dtype)
-            q, k, v = project(block, x)
-            q_dtype = x.dtype
-            if int8_kv:
-                kq, ks = _quantize_kv(k)
-                vq, vs = _quantize_kv(v)
-                k_cache = cache_write(k_cache, kq, start)
-                v_cache = cache_write(v_cache, vq, start)
-                k_sc = cache_write(k_sc, ks, start)
-                v_sc = cache_write(v_sc, vs, start)
-                k_full = _dequant_kv(k_cache, k_sc, q_dtype)
-                v_full = _dequant_kv(v_cache, v_sc, q_dtype)
-                kv_raw = (k_cache, k_sc, v_cache, v_sc)
-            else:
-                k_cache = cache_write(k_cache, k, start)
-                v_cache = cache_write(v_cache, v, start)
-                k_full = k_cache.astype(q_dtype)
-                v_full = v_cache.astype(q_dtype)
-                kv_raw = None
-            x = attend(block, x, q, k_full, v_full, kv_raw)
-            if int8_kv:
-                return x, (k_cache, v_cache, k_sc, v_sc)
-            return x, (k_cache, v_cache)
-
-        if int8_kv:
-            xs = (params["blocks"], cache["k"], cache["v"], cache["k_scale"], cache["v_scale"])
-            x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(scan_body, x, xs)
-            new_cache = {
-                "k": new_k, "v": new_v, "k_scale": new_ks, "v_scale": new_vs,
-                "length": start + T_new,
-            }
-        else:
-            x, (new_k, new_v) = jax.lax.scan(
-                scan_body, x, (params["blocks"], cache["k"], cache["v"])
-            )
-            new_cache = {"k": new_k, "v": new_v, "length": start + T_new}
+    kv = {name: buf for name, buf in cache.items() if name != "length"}
+    (x, kv, _), _ = jax.lax.scan(
+        scan_body, (x, kv, jnp.zeros((), jnp.int32)), params["blocks"]
+    )
+    new_cache = dict(kv, length=start + T_new)
     x = rms_norm(x, params["final_norm"], config.norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, _lm_head(params, config).astype(x.dtype))
     return logits, new_cache
@@ -761,10 +653,15 @@ def _offloaded_cache_step(config: LlamaConfig):
         q, k, v = attention_qkv(block["attn"], h)
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
-        k_cache = cache_write(k_cache, k, start)
-        v_cache = cache_write(v_cache, v, start)
+        # One layer (B, S, K*h) of the stacked cache: heads flattened.
+        k_cache = cache_write(k_cache, k.reshape(k.shape[:2] + (-1,)), start)
+        v_cache = cache_write(v_cache, v.reshape(v.shape[:2] + (-1,)), start)
+        heads = k_cache.shape[:2] + k.shape[2:]
         attn = dot_product_attention(
-            q, k_cache.astype(q.dtype), v_cache.astype(q.dtype), mask=mask
+            q,
+            k_cache.reshape(heads).astype(q.dtype),
+            v_cache.reshape(heads).astype(q.dtype),
+            mask=mask,
         )
         x = x + attention_out(block["attn"], attn)
         h = rms_norm(x, block["mlp_norm"], config.norm_eps)

@@ -8,12 +8,16 @@ blocks, carrying running max / normalizer / accumulator in VMEM scratch, and
 masks by the host-shipped length cursor so the padded slot tail never enters
 the softmax.
 
+The kernel reads the cache where the model keeps it: the whole layer-stacked
+(L, B, T, K*h) buffer is the operand, the layer index rides scalar prefetch
+beside the cursors, and the block index map picks (layer, row, block, head).
+No layer is sliced out of the stack and nothing is reshaped on the way in,
+so a decode step moves only the rows it attends to.
+
 The int8-KV variant dequantizes inside the kernel (``k * scale`` per cache
 block) — that is the bandwidth win the kv16k bench measures: the fallback
 lowering materializes the full bf16 dequant copy of a 16k-token cache before
-a single attention flop, this kernel reads the int8 bytes once. When the
-kernel takes the quantized operands the call site's dequantized copies are
-dead and XLA drops them.
+a single attention flop, this kernel reads the int8 bytes once.
 
 Parity vs `models.layers.dot_product_attention` is to tolerance, not bitwise:
 the oracle computes one full-row softmax, this kernel merges per-block
@@ -55,6 +59,7 @@ else:  # pragma: no cover - environment dependent
 
 def _decode_kernel(
     len_ref,
+    layer_ref,
     q_ref,
     k_ref,
     ks_ref,
@@ -78,6 +83,7 @@ def _decode_kernel(
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
+    del layer_ref  # only the block index maps read it
     length = len_ref[pl.program_id(0)]
 
     # Blocks entirely past the cursor contribute nothing — skip the flops
@@ -85,7 +91,7 @@ def _decode_kernel(
     @pl.when(t * blk < length)
     def _block():
         q = q_ref[0, 0].astype(jnp.float32)  # (group, h)
-        k = k_ref[0].astype(jnp.float32)  # (blk, h)
+        k = k_ref[...].astype(jnp.float32)  # (blk, h)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (group, blk)
@@ -108,7 +114,7 @@ def _decode_kernel(
             pv = p * vs_ref[0, 0].astype(jnp.float32)
         acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
             pv,
-            v_ref[0].astype(jnp.float32),  # (blk, h)
+            v_ref[...].astype(jnp.float32),  # (blk, h)
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -120,16 +126,17 @@ def _decode_kernel(
 
 
 def supported(q: jax.Array, k: jax.Array, *, compiled: bool = False, quantized: bool = False) -> bool:
-    """Shape support: one query token per row, GQA-divisible heads, and a
-    cache length some tile divides exactly (the kernel never pads).
+    """Shape support: one query token per row, a layer-stacked (L, B, T, K*h)
+    cache whose last axis holds whole GQA-divisible heads, and a cache length
+    some tile divides exactly (the kernel never pads).
     ``compiled`` adds what Mosaic's (8, 128) tiling asks of the blocks: the
     per-head slice of the flattened (K*h) axis is a whole number of lane
     tiles, and the int8 scale rows (``quantized``) are lane-aligned too."""
     if q.ndim != 4 or k.ndim != 4 or q.shape[1] != 1:
         return False
     B, _, H, h = q.shape
-    T, K = k.shape[1], k.shape[2]
-    if k.shape[0] != B or k.shape[3] != h or H % K != 0:
+    T, K = k.shape[2], k.shape[3] // h
+    if k.shape[1] != B or k.shape[3] != K * h or K == 0 or H % K != 0:
         return False
     blk = pick_block(T)
     if blk is None:
@@ -149,19 +156,21 @@ def flash_decode(
     k: jax.Array,
     v: jax.Array,
     lengths: jax.Array,
+    layer: jax.Array | int = 0,
     *,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     scale: float | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """q: (B, 1, H, h); k/v: (B, T, K, h) cache buffers (bf16/f32, or int8
-    with per-(token, head) ``*_scale`` of shape (B, T, K)); lengths: () or
-    (B,) valid-prefix cursors. Returns (B, 1, H, h) in q's dtype."""
+    """q: (B, 1, H, h); k/v: the layer-stacked (L, B, T, K*h) cache buffers
+    (bf16/f32, or int8 with per-(token, head) ``*_scale`` of shape
+    (L, B, T, K)), read in place at layer ``layer``; lengths: () or (B,)
+    valid-prefix cursors. Returns (B, 1, H, h) in q's dtype."""
     B, S, H, h = q.shape
     if S != 1:
         raise ValueError(f"flash_decode is single-query only, got T_new={S}")
-    T, K = k.shape[1], k.shape[2]
+    T, K = k.shape[2], k.shape[3] // h
     group = H // K
     blk = pick_block(T)
     if blk is None:
@@ -170,28 +179,33 @@ def flash_decode(
     scale = scale if scale is not None else float(1.0 / (h**0.5))
 
     qt = q.reshape(B, K, group, h)  # head = kk * group + g, the oracle's layout
-    # The cache is read where it lies: (B, T, K*h) is a free view, and head
-    # kk is lane-block kk of its last axis — no transposed copy per step.
-    kt = k.reshape(B, T, K * h)
-    vt = v.reshape(B, T, K * h)
-    # The cursors ride scalar prefetch (SMEM), read per program by batch row.
+    # The cursors and the layer index ride scalar prefetch (SMEM): the cursors
+    # are read per program by batch row, the layer by every block index map.
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1), (B,))
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    q_spec = pl.BlockSpec((1, 1, group, h), lambda b, kk, t, lens: (b, kk, 0, 0))
-    kv_spec = pl.BlockSpec((1, blk, h), lambda b, kk, t, lens: (b, t, kk))
-    # Scales as lane-dense rows: (B, T, K) -> (B, K, 1, T), block (1, blk).
-    # The transpose moves 1/h of the cache bytes.
-    scale_spec = pl.BlockSpec((1, 1, 1, blk), lambda b, kk, t, lens: (b, kk, 0, t))
+    q_spec = pl.BlockSpec((1, 1, group, h), lambda b, kk, t, lens, layer: (b, kk, 0, 0))
+    # Head kk is lane-block kk of the stack's last axis.
+    kv_spec = pl.BlockSpec(
+        (None, None, blk, h), lambda b, kk, t, lens, layer: (layer[0], b, t, kk)
+    )
+    # Scales as lane-dense rows: layer (B, T, K) -> (B, K, 1, T), block
+    # (1, blk). Slice and transpose move 1/h of one layer's cache bytes.
+    scale_spec = pl.BlockSpec((1, 1, 1, blk), lambda b, kk, t, lens, layer: (b, kk, 0, t))
 
-    operands = [qt, kt]
+    def scale_rows(stacked):
+        one = jax.lax.dynamic_index_in_dim(stacked, layer[0], 0, keepdims=False)
+        return one.transpose(0, 2, 1)[:, :, None, :]
+
+    operands = [qt, k]
     in_specs = [q_spec, kv_spec]
     if k_scale is not None:
-        operands.append(k_scale.transpose(0, 2, 1)[:, :, None, :])
+        operands.append(scale_rows(k_scale))
         in_specs.append(scale_spec)
-    operands.append(vt)
+    operands.append(v)
     in_specs.append(kv_spec)
     if v_scale is not None:
-        operands.append(v_scale.transpose(0, 2, 1)[:, :, None, :])
+        operands.append(scale_rows(v_scale))
         in_specs.append(scale_spec)
 
     kernel = functools.partial(
@@ -205,7 +219,7 @@ def flash_decode(
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(B, K, n_blocks),
             in_specs=in_specs,
             out_specs=q_spec,
@@ -219,18 +233,20 @@ def flash_decode(
         **tuned_call_kwargs(
             "flash_decode", interpret, ("parallel", "parallel", "arbitrary")
         ),
-    )(lengths, *operands)
+    )(lengths, layer, *operands)
     return out.reshape(B, 1, H, h)
 
 
-def _kernel_with_optionals(len_ref, q_ref, k_ref, *rest, has_ks, has_vs, **kw):
+def _kernel_with_optionals(len_ref, layer_ref, q_ref, k_ref, *rest, has_ks, has_vs, **kw):
     """Unpack the optional scale operands into the fixed-arity kernel."""
     rest = list(rest)
     ks_ref = rest.pop(0) if has_ks else None
     v_ref = rest.pop(0)
     vs_ref = rest.pop(0) if has_vs else None
     o_ref, m_s, l_s, acc_s = rest
-    _decode_kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, m_s, l_s, acc_s, **kw)
+    _decode_kernel(
+        len_ref, layer_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, m_s, l_s, acc_s, **kw
+    )
 
 
 def maybe_flash_decode(
@@ -238,23 +254,23 @@ def maybe_flash_decode(
     k: jax.Array,
     v: jax.Array,
     lengths: jax.Array,
+    layer: jax.Array | int = 0,
     *,
-    kv_raw=None,
+    k_scale: jax.Array | None = None,
+    v_scale: jax.Array | None = None,
     scale: float | None = None,
 ) -> jax.Array | None:
     """Dispatch entry: the kernel output when `decode_attn` is enabled and
-    the shapes are supported, else ``None`` (caller runs the exact reference
-    lowering). ``kv_raw = (k_q, k_scale, v_q, v_scale)`` hands over the raw
-    int8 cache so dequant fuses into the kernel."""
+    the shapes are supported, else ``None`` (caller slices layer ``layer``
+    out of the stack and runs the exact reference lowering). ``k_scale`` /
+    ``v_scale`` are the stacked scales of an int8 cache, whose dequant then
+    fuses into the kernel."""
     mode = kernel_mode("decode_attn")
     if mode is None or not supported(
-        q, k, compiled=mode == "compiled", quantized=kv_raw is not None
+        q, k, compiled=mode == "compiled", quantized=k_scale is not None
     ):
         return None
-    interpret = mode == "interpret"
-    if kv_raw is not None:
-        kq, ks, vq, vs = kv_raw
-        return flash_decode(
-            q, kq, vq, lengths, k_scale=ks, v_scale=vs, scale=scale, interpret=interpret
-        )
-    return flash_decode(q, k, v, lengths, scale=scale, interpret=interpret)
+    return flash_decode(
+        q, k, v, lengths, layer, k_scale=k_scale, v_scale=v_scale, scale=scale,
+        interpret=mode == "interpret",
+    )

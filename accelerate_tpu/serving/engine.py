@@ -53,7 +53,12 @@ from .. import resilience
 from .. import telemetry as _telemetry
 from ..telemetry import flight as _flight
 from ..generation import GenerationConfig, warp_logits
-from ..models.layers import cache_slot_copy, cache_slot_view, cache_slot_write
+from ..models.layers import (
+    cache_slot_copy,
+    cache_slot_view,
+    cache_slot_write,
+    record_attention_paths,
+)
 from ..utils.environment import (
     get_int_from_env,
     get_str_from_env,
@@ -297,7 +302,12 @@ class Engine:
             when enabled (``ATX_KERNELS`` / ``ATX_KERNEL_DECODE_ATTN``,
             read at trace time): split-K over the slot KV cache, masked by
             each row's length cursor, with int8 KV dequantized in-kernel."""
-            logits, new = apply_fn(params, tokens[:, None], dict(kv, length=lengths))
+            with record_attention_paths() as paths:
+                logits, new = apply_fn(params, tokens[:, None], dict(kv, length=lengths))
+            # Trace time: which attention lowering this program compiled to.
+            self.stats["decode_in_place"] = int(
+                bool(paths) and all(p == "in_place" for p in paths)
+            )
             nxt = jax.vmap(_sample)(logits[:, -1, :], seeds, steps)
             return nxt, {k: new[k] for k in kv}
 
@@ -383,7 +393,9 @@ class Engine:
         # snapshot working while `/metrics` reads the same series — one
         # source of truth. Keys: decode_slot_steps sums active rows over
         # decode steps; prefill_tokens_saved counts prompt tokens served by
-        # KV copy instead of prefill compute.
+        # KV copy instead of prefill compute; decode_in_place is 1 when the
+        # traced decode program's attention reads the stacked cache in place
+        # (the flash-decode kernel) and 0 when it slices a layer out.
         self.stats = _telemetry.StatsView(
             "serve",
             (
@@ -398,8 +410,10 @@ class Engine:
                 "prefix_copy_chunks",
                 "prefix_promotions",
                 "cancelled",
+                "decode_in_place",
             ),
             label="engine",
+            gauges=("decode_in_place",),
         )
         _labels = ("engine",)
         self._tel_labels = self.stats.labels

@@ -215,8 +215,7 @@ def kernel_parity_phase(*, seq_len: int, cache_len: int, head_dim: int, seed: in
     import jax.numpy as jnp
     import numpy as np
 
-    from accelerate_tpu.models.layers import cached_decode_attention, dot_product_attention
-    from accelerate_tpu.models.llama import _dequant_kv, _quantize_kv
+    from accelerate_tpu.models.layers import cache_append, cached_attention, dot_product_attention
     from accelerate_tpu.native.pallas.dispatch import force_kernels
     from accelerate_tpu.ops.flash_attention import flash_attention
     from accelerate_tpu.ops.int8 import int8_einsum, quantize_act
@@ -252,23 +251,26 @@ def kernel_parity_phase(*, seq_len: int, cache_len: int, head_dim: int, seed: in
     dot = attention_grads(lambda q, k, v: dot_product_attention(q, k, v, causal=True))
     out = {"flash_fwd_bwd": err(flash, dot)}
 
-    slots = 4
-    dq, dk, dv = normal(slots, 1, 8, head_dim), normal(slots, cache_len, 2, head_dim), normal(slots, cache_len, 2, head_dim)
+    # Decode: new rows appended to layer 1 of a two-layer stacked cache at
+    # one cursor a slot, then attended in place (kernel) or sliced (off).
+    slots, kv_heads = 4, 2
+    dq, dk, dv = normal(slots, 1, 8, head_dim), normal(slots, cache_len, kv_heads, head_dim), normal(slots, cache_len, kv_heads, head_dim)
     lengths = jnp.asarray(np.linspace(1, cache_len, slots).astype(np.int32))
     mask = (jnp.arange(cache_len)[None, :] < lengths[:, None])[:, None, :]
-    out["flash_decode"] = both(
-        lambda q, k, v: cached_decode_attention(q, k, v, mask=mask, lengths=lengths), dq, dk, dv
-    )
+    zeros = jnp.zeros((slots,), jnp.int32)
 
-    def int8_kv(q, k, v):
-        kq, ks = _quantize_kv(k)
-        vq, vs = _quantize_kv(v)
-        return cached_decode_attention(
-            q, _dequant_kv(kq, ks, q.dtype), _dequant_kv(vq, vs, q.dtype),
-            mask=mask, lengths=lengths, kv_raw=(kq, ks, vq, vs),
-        )
+    def decode(q, k, v, kv):
+        kv = cache_append(kv, 1, k, v, zeros)
+        return cached_attention(q, kv, 1, mask=mask, lengths=lengths)
 
-    out["flash_decode_int8_kv"] = both(int8_kv, dq, dk, dv)
+    stack = lambda *tail, dtype=bf16: jnp.zeros((2, slots, cache_len) + tail, dtype)
+    flat = kv_heads * head_dim
+    out["flash_decode"] = both(decode, dq, dk, dv, {"k": stack(flat), "v": stack(flat)})
+    int8_cache = {
+        "k": stack(flat, dtype=jnp.int8), "v": stack(flat, dtype=jnp.int8),
+        "k_scale": stack(kv_heads), "v_scale": stack(kv_heads),
+    }
+    out["flash_decode_int8_kv"] = both(decode, dq, dk, dv, int8_cache)
 
     x = normal(2, 8, 4 * head_dim)
     wq, w_scale = quantize_act(normal(4 * head_dim, 2, head_dim), (0,))

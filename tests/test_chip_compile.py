@@ -21,6 +21,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -68,13 +69,13 @@ def _flash_grad(q, k, v):
     return jax.grad(lambda *a: _flash(*a).astype(F32).sum(), argnums=(0, 1, 2))(q, k, v)
 
 
-def _decode(q, k, v, lengths):
-    return decode_attention.flash_decode(q, k, v, lengths, interpret=False)
+def _decode(q, k, v, lengths, layer):
+    return decode_attention.flash_decode(q, k, v, lengths, layer, interpret=False)
 
 
-def _decode_int8(q, k, v, lengths, ks, vs):
+def _decode_int8(q, k, v, lengths, layer, ks, vs):
     return decode_attention.flash_decode(
-        q, k, v, lengths, k_scale=ks, v_scale=vs, interpret=False
+        q, k, v, lengths, layer, k_scale=ks, v_scale=vs, interpret=False
     )
 
 
@@ -94,6 +95,7 @@ def _adamw(g, mu, nu, p, count, lr):
 
 _QKV = [((1, SEQ, H, HD), BF16), ((1, SEQ, K, HD), BF16), ((1, SEQ, K, HD), BF16)]
 _SLOT_Q = ((SLOTS, 1, H, HD), BF16)
+_STACK = (2, SLOTS, SLOT_LEN, K * HD)  # a two-layer stacked cache, heads flattened
 _LEAF = ((D, FF), F32)
 _QKV_SHORT = [((1, 2048, H, HD), BF16), ((1, 2048, K, HD), BF16), ((1, 2048, K, HD), BF16)]
 F8 = jnp.float8_e4m3fn
@@ -106,13 +108,13 @@ KERNELS = {
     "flash_resident_fwd_bwd": (_flash_grad, _QKV_SHORT, [k + "_resident" for k in _FLASH_BWD]),
     "flash_decode_bf16": (
         _decode,
-        [_SLOT_Q, ((SLOTS, SLOT_LEN, K, HD), BF16), ((SLOTS, SLOT_LEN, K, HD), BF16), ((SLOTS,), I32)],
+        [_SLOT_Q, (_STACK, BF16), (_STACK, BF16), ((SLOTS,), I32), ((), I32)],
         ["flash_decode"],
     ),
     "flash_decode_int8_kv": (
         _decode_int8,
-        [_SLOT_Q, ((SLOTS, SLOT_LEN, K, HD), I8), ((SLOTS, SLOT_LEN, K, HD), I8), ((SLOTS,), I32),
-         ((SLOTS, SLOT_LEN, K), BF16), ((SLOTS, SLOT_LEN, K), BF16)],
+        [_SLOT_Q, (_STACK, I8), (_STACK, I8), ((SLOTS,), I32), ((), I32),
+         (_STACK[:3] + (K,), BF16), (_STACK[:3] + (K,), BF16)],
         ["flash_decode"],
     ),
     # The down projection of a 2048-token prefill: the whole contraction
@@ -138,6 +140,77 @@ def test_kernel_compiles_for_v5e(v5e, name):
     # (a kernel's get-tuple-elements repeat its attributes: a set, not a count)
     named = re.findall(r'kernel_metadata=\{\s*"kernel":"(\w+)"', text)
     assert set(named) == set(kernels)
+
+
+# The chat cell's cache: 32 slots x 1024 rows, 8 kv heads x 128, two layers.
+_CHAT_SLOTS, _CHAT_LEN = 32, 1024
+_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice", "reshape", "transpose", "slice")
+
+
+def _cache_sized_moves(text, layer_elements):
+    """Instructions of a compiled module (fused computations included) that
+    copy, slice, reshape or update-slice an array of one layer's cache or
+    more: (name, opcode) pairs, named as a v5e trace would show them."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][a-z\-]*)\(", line)
+        if m is None:
+            continue
+        name, result, opcode = m.groups()
+        sizes = [
+            int(np.prod([int(d) for d in dims.split(",")]))
+            for dims in re.findall(r"[a-z]\w*\[([0-9,]+)\]", result)
+        ]
+        moved = opcode.removesuffix("-start").removesuffix("-done") in _MOVES or any(
+            word in name for word in _MOVES
+        )
+        if moved and max(sizes, default=0) >= layer_elements:
+            found.append((name, opcode))
+    return found
+
+
+@pytest.mark.parametrize("kernels, in_place", [("on", 1), ("off", 0)])
+def test_engine_decode_touches_the_cache_in_place_on_v5e(v5e, monkeypatch, kernels, in_place):
+    """The engine's decode program at the chat cell's cache widths, compiled
+    for the described chip: between the donated, loop-carried cache and the
+    kernel stands no copy, slice, reshape or update of a layer or more (the
+    row write is a scatter, aliased onto the carry), and the program's
+    temporaries are far under one cache buffer. With the kernel off the same
+    check finds the sliced lowering's whole-layer reads: it has teeth."""
+    from accelerate_tpu import serving
+    from accelerate_tpu.generation import GenerationConfig
+    from accelerate_tpu.native.pallas import dispatch
+
+    monkeypatch.setattr(dispatch, "_on_tpu", lambda: True)  # jax.default_backend() is the CPU
+    cfg = llama.LlamaConfig(
+        vocab_size=512, d_model=512, n_layers=2, num_heads=H, num_kv_heads=K,
+        head_dim=HD, d_ff=1024, max_seq_len=_CHAT_LEN,
+    )
+    params = jax.tree.map(lambda x: x.astype(BF16), llama.init(jax.random.PRNGKey(0), cfg))
+    engine = serving.Engine(
+        lambda p, t, c: llama.forward_with_cache(p, t, c, cfg),
+        lambda b, m: llama.init_cache(cfg, b, m),
+        params, GenerationConfig(), slots=_CHAT_SLOTS, max_len=_CHAT_LEN, prefix_cache=False,
+    )
+    one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
+    shapes = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        engine.abstract_decode_args(),
+    )
+    with force_kernels(kernels):
+        compiled = jax.jit(engine._decode_fn, donate_argnums=(3,)).lower(*shapes).compile()
+    assert engine.stats["decode_in_place"] == in_place
+    layer_elements = _CHAT_SLOTS * _CHAT_LEN * K * HD
+    buffer_bytes = cfg.n_layers * layer_elements * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * buffer_bytes  # k and v stay where they were donated
+    moves = _cache_sized_moves(compiled.as_text(), layer_elements)
+    if in_place:
+        assert moves == []
+        assert "tpu_custom_call" in compiled.as_text()
+        assert memory.temp_size_in_bytes < buffer_bytes // 8
+    else:
+        assert any("slice" in name or "slice" in opcode for name, opcode in moves), moves
 
 
 def test_every_pallas_call_in_the_package_is_named():

@@ -103,58 +103,107 @@ def _decode_operands(dtype, B=2, T=64, K=2, group=2, h=16, seed=0):
     return q, k, v
 
 
+def _stacked(x, layer=0, n_layers=1):
+    """One layer's (B, T, K, h) keys or values (or (B, T, K) scales) as the
+    kernel's operand: layer ``layer`` of an (L, B, T, K*h) stack whose other
+    layers hold noise, so a wrong layer index cannot pass."""
+    flat = x.reshape(x.shape[:2] + (-1,))
+    noise = 3.0 * jax.random.normal(
+        jax.random.PRNGKey(7), (n_layers,) + flat.shape, jnp.float32
+    )
+    return noise.astype(x.dtype).at[layer].set(flat)
+
+
 class TestFlashDecode:
     def test_f32_parity_scalar_length(self):
         q, k, v = _decode_operands(jnp.float32)
-        out = decode_attention.flash_decode(q, k, v, 48, interpret=True)
+        out = decode_attention.flash_decode(q, _stacked(k), _stacked(v), 48, interpret=True)
         ref = _ref_decode(q, k, v, 48)
         np.testing.assert_allclose(out, ref, rtol=2e-6, atol=2e-6)
 
     def test_ragged_lengths_gqa(self):
         q, k, v = _decode_operands(jnp.float32, B=4, T=64, K=2, group=4)
         lengths = jnp.asarray([3, 17, 64, 40], jnp.int32)
-        out = decode_attention.flash_decode(q, k, v, lengths, interpret=True)
+        out = decode_attention.flash_decode(
+            q, _stacked(k), _stacked(v), lengths, interpret=True
+        )
         ref = _ref_decode(q, k, v, lengths)
         np.testing.assert_allclose(out, ref, rtol=2e-6, atol=2e-6)
 
     def test_bf16_parity(self):
         q, k, v = _decode_operands(jnp.bfloat16)
-        out = decode_attention.flash_decode(q, k, v, 40, interpret=True)
+        out = decode_attention.flash_decode(q, _stacked(k), _stacked(v), 40, interpret=True)
         ref = _ref_decode(q, k, v, 40)
         np.testing.assert_allclose(
             out.astype(np.float32), ref.astype(np.float32), rtol=2e-2, atol=2e-2
         )
 
     def test_int8_kv_dequant_in_kernel(self):
-        from accelerate_tpu.models.llama import _dequant_kv, _quantize_kv
+        from accelerate_tpu.models.layers import dequant_kv, quantize_kv
 
         q, k, v = _decode_operands(jnp.bfloat16, B=2, T=32)
-        kq, ksc = _quantize_kv(k)
-        vq, vsc = _quantize_kv(v)
+        kq, ksc = quantize_kv(k)
+        vq, vsc = quantize_kv(v)
         out = decode_attention.flash_decode(
             q,
-            kq,
-            vq,
+            _stacked(kq),
+            _stacked(vq),
             20,
-            k_scale=ksc,
-            v_scale=vsc,
+            k_scale=_stacked(ksc),
+            v_scale=_stacked(vsc),
             interpret=True,
         )
         ref = _ref_decode(
-            q, _dequant_kv(kq, ksc, q.dtype), _dequant_kv(vq, vsc, q.dtype), 20
+            q, dequant_kv(kq, ksc, q.dtype), dequant_kv(vq, vsc, q.dtype), 20
         )
+        np.testing.assert_allclose(
+            out.astype(np.float32), ref.astype(np.float32), rtol=3e-2, atol=3e-2
+        )
+
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    @pytest.mark.parametrize("layer", [1, 2])
+    def test_reads_layer_of_the_stack_with_per_row_cursors(self, kv_dtype, layer):
+        """The kernel indexes the whole (L, B, T, K*h) stack by a traced
+        layer index and masks by one cursor a row; the oracle is
+        `dot_product_attention` over that layer alone."""
+        from accelerate_tpu.models.layers import (
+            dequant_kv, dot_product_attention, quantize_kv,
+        )
+
+        q, k, v = _decode_operands(jnp.bfloat16, B=4, T=64, K=2, group=4)
+        lengths = jnp.asarray([1, 23, 64, 40], jnp.int32)
+        scales = {}
+        if kv_dtype == "int8":
+            (k_in, ksc), (v_in, vsc) = quantize_kv(k), quantize_kv(v)
+            scales = {"k_scale": _stacked(ksc, layer, 3), "v_scale": _stacked(vsc, layer, 3)}
+            k, v = dequant_kv(k_in, ksc, q.dtype), dequant_kv(v_in, vsc, q.dtype)
+        else:
+            k_in, v_in = k, v
+        out = jax.jit(
+            lambda i: decode_attention.flash_decode(
+                q, _stacked(k_in, layer, 3), _stacked(v_in, layer, 3), lengths, i,
+                interpret=True, **scales,
+            )
+        )(jnp.int32(layer))
+        mask = (jnp.arange(64)[None, :] < lengths[:, None])[:, None, :]
+        ref = dot_product_attention(q, k, v, mask=mask)
         np.testing.assert_allclose(
             out.astype(np.float32), ref.astype(np.float32), rtol=3e-2, atol=3e-2
         )
 
     def test_unsupported_shapes_fall_back(self):
         q, k, v = _decode_operands(jnp.float32, T=12)  # 12 has no block divisor
+        k, v = _stacked(k), _stacked(v)
         assert not decode_attention.supported(q, k)
         with force_kernels("interpret"):
             assert decode_attention.maybe_flash_decode(q, k, v, 8) is None
         # T_new > 1 (prefill) is never this kernel's shape.
         q2 = jnp.zeros((2, 3, 4, 16), jnp.float32)
-        assert not decode_attention.supported(q2, jnp.zeros((2, 64, 2, 16)))
+        assert not decode_attention.supported(q2, jnp.zeros((1, 2, 64, 2 * 16)))
+        # A last axis that is not whole heads of q's width is not a cache.
+        q3 = jnp.zeros((2, 1, 4, 16), jnp.float32)
+        assert decode_attention.supported(q3, jnp.zeros((1, 2, 64, 2 * 16)))
+        assert not decode_attention.supported(q3, jnp.zeros((1, 2, 64, 2 * 16 + 8)))
 
     def test_forward_with_cache_off_is_byte_identical_to_default(self):
         # ATX_KERNELS=0 acceptance: on this backend the default resolves to
@@ -209,6 +258,36 @@ class TestFlashDecode:
             with force_kernels("interpret"):
                 out = run(cache_dtype)
             np.testing.assert_allclose(out, ref, rtol=5e-5, atol=5e-5)
+
+
+    @pytest.mark.parametrize("kernels", ["off", "interpret"])
+    @pytest.mark.parametrize("cache_len", [64, 4096])  # the lengths the old layout switch separated
+    def test_per_row_cursor_decode_matches_forward(self, cache_len, kernels):
+        """The engine's decode contract on the one cache layout: after a
+        prefill, rows at DIFFERENT cursors each append one token a step (a
+        row scatter into the carried stack) and must see the cache-free
+        forward's logits at their own positions - through the sliced
+        lowering and through the in-place flash-decode kernel alike."""
+        from accelerate_tpu.models import llama
+
+        config = llama.LlamaConfig.tiny(vocab_size=97, max_seq_len=8192)
+        params = llama.init(jax.random.PRNGKey(0), config)
+        tok = jnp.asarray(np.arange(24, dtype=np.int32).reshape(2, 12) % 97)
+        want = np.asarray(llama.forward(params, tok, config))
+        step = jax.jit(lambda p, t, c: llama.forward_with_cache(p, t, c, config))
+        cursors = np.asarray([7, 4])  # row 1 rewinds: it re-appends tokens 4.. at 4..
+        with force_kernels(kernels):
+            cache = llama.init_cache(config, 2, cache_len, dtype=jnp.float32)
+            _, cache = step(params, tok[:, :7], cache)
+            cache = dict(cache, length=jnp.asarray(cursors, jnp.int32))
+            for t in range(5):
+                new = tok[np.arange(2), cursors + t][:, None]
+                got, cache = step(params, new, cache)
+                np.testing.assert_allclose(
+                    np.asarray(got[:, 0]), want[np.arange(2), cursors + t],
+                    atol=2e-5, rtol=2e-5,
+                )
+        np.testing.assert_array_equal(np.asarray(cache["length"]), cursors + 5)
 
 
 # ========================================================== quantized matmul

@@ -542,10 +542,10 @@ def test_gpt_chunked_loss_with_mask_matches():
 
 
 class TestInt8KvCache:
-    """int8 KV cache (llama `init_cache(dtype=jnp.int8)`) and the dual
-    scan layout (xs/ys restack for short caches, in-place carry for long —
-    `forward_with_cache`): both must be numerically identical per dtype,
-    and int8 must stay within the per-token-scale quantization envelope."""
+    """int8 KV cache (llama `init_cache(dtype=jnp.int8)`) on the one cache
+    layout `forward_with_cache` has (the stacked cache in the scan carry),
+    at a short and a long cache: numerically identical per dtype, and int8
+    within the per-token-scale quantization envelope."""
 
     CFG = llama.LlamaConfig.tiny(vocab_size=97, max_seq_len=8192)
 
@@ -553,7 +553,7 @@ class TestInt8KvCache:
     def params(self):
         return llama.init(jax.random.PRNGKey(0), self.CFG)
 
-    @pytest.mark.parametrize("cache_len", [64, 4096])  # xs/ys vs carry path
+    @pytest.mark.parametrize("cache_len", [64, 4096])  # one path, two lengths
     def test_fp32_cache_matches_forward_exactly(self, params, cache_len):
         tok = jnp.asarray(np.arange(20, dtype=np.int32).reshape(2, 10) % 97)
         want = np.asarray(llama.forward(params, tok, self.CFG))
@@ -611,9 +611,9 @@ class TestInt8KvCache:
             cache_dtype(GenerationConfig(kv_cache_dtype="fp8"))
 
 
-@pytest.mark.parametrize("cache_len", [32, 4096])  # xs/ys vs carry layout
+@pytest.mark.parametrize("cache_len", [32, 4096])  # one path, two lengths
 def test_gpt_cache_layouts_match_forward(cache_len):
-    """The gpt family's dual cache layout (same design as llama's) must be
+    """The gpt family's cached forward (same cache layout as llama's) must be
     numerically identical to the uncached forward on every block variant."""
     cfg = gpt.GPTConfig.tiny(
         max_seq_len=8192, positional="rotary", rotary_dim=8,

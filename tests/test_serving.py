@@ -252,6 +252,33 @@ class TestCompileDiscipline:
         assert not report.has_errors, [str(f) for f in report.findings]
 
 
+    @pytest.mark.parametrize("mode, in_place", [("interpret", 1), ("off", 0)])
+    def test_stats_say_which_attention_path_decode_compiled(self, params, mode, in_place):
+        """A silent fall to the sliced lowering would bring the old speed
+        back under the new code: the decode program records at trace time
+        whether its attention reads the stacked cache in place (the
+        flash-decode kernel) or slices a layer out, and tokens agree."""
+        from accelerate_tpu.native.pallas.dispatch import force_kernels
+        from accelerate_tpu import telemetry
+
+        prompt = np.arange(1, 12, dtype=np.int32)
+        eng = _engine(params, max_len=64)
+        assert eng.stats["decode_in_place"] == 0  # nothing traced yet
+        with force_kernels(mode):
+            (done,) = eng.serve([serving.Request(prompt=prompt, max_new_tokens=5)])
+        assert eng.stats["decode_in_place"] == in_place
+        np.testing.assert_array_equal(done.tokens, _solo(params, prompt, 5))
+        # ...and the registry exports it like the other counts.
+        (metric,) = [
+            m for m in telemetry.snapshot()["metrics"] if m["name"] == "serve_decode_in_place"
+        ]
+        (value,) = [
+            series["value"] for series in metric["series"]
+            if series["labels"] == eng.stats.labels
+        ]
+        assert value == in_place
+
+
 class TestPoissonSmoke:
     def test_poisson_16_requests_all_complete_and_match_solo(self, params):
         """The `make smoke-serve` contract: a 16-request Poisson trace of

@@ -303,6 +303,26 @@ class TestCachePythonIntStart:
 
         all_buf = jnp.zeros((3, 2, 8, 4), jnp.float32)
         rows = jnp.ones((2, 2, 4), jnp.float32)
-        stacked, layer = cache_write_stacked(all_buf, jnp.int32(1), rows, 2)
-        np.testing.assert_array_equal(np.asarray(stacked[1]), np.asarray(layer))
+        stacked = cache_write_stacked(all_buf, jnp.int32(1), rows, 2)
+        np.testing.assert_array_equal(
+            np.asarray(stacked[1, :, 2:4]), np.ones((2, 2, 4), np.float32)
+        )
         assert float(jnp.sum(stacked)) == pytest.approx(16.0)
+
+    def test_cache_write_stacked_scatters_rows_at_per_row_cursors(self):
+        """A (B,) cursor writes row b's T new rows at its own offset, into
+        the named layer only; a row that would land past the end of the
+        buffer is dropped, not clamped onto a committed position."""
+        from accelerate_tpu.models.layers import cache_write_stacked
+
+        all_buf = jnp.zeros((3, 3, 8, 4), jnp.float32)
+        rows = jnp.arange(1, 7, dtype=jnp.float32).reshape(3, 2, 1) * jnp.ones((3, 2, 4))
+        out = np.asarray(
+            jax.jit(cache_write_stacked)(all_buf, jnp.int32(2), rows, jnp.asarray([0, 5, 7]))
+        )
+        want = np.zeros((3, 8, 4), np.float32)
+        want[0, 0], want[0, 1] = 1.0, 2.0
+        want[1, 5], want[1, 6] = 3.0, 4.0
+        want[2, 7] = 5.0  # its second row (6.0) falls off the end
+        np.testing.assert_array_equal(out[2], want)
+        assert not out[:2].any()
