@@ -22,7 +22,8 @@ a leaf reads only the matching byte ranges of the source tensors, so a 70B
 repo never materializes a full tensor on any host (the streaming contract of
 `load_checkpoint_and_dispatch`).
 
-Supported ``model_type``s: llama, mistral, mixtral, qwen2 (the llama
+Supported ``model_type``s: smallthinker (its config only: `from_hf_config`),
+llama, mistral, mixtral, qwen2 (the llama
 family — mixtral routes through the MoE blocks, qwen2 adds q/k/v biases),
 gpt2, gpt_neox, gptj, opt (the gpt family — variant knobs select rotary
 style, parallel residual, activation, and bias layout; these are the
@@ -1053,10 +1054,52 @@ def from_hf_config(config: Any) -> tuple[str, Any]:
             norm_eps=config.get("layer_norm_epsilon", 1e-6),
             tie_embeddings=config.get("tie_word_embeddings", True),
         )
+    if mt == "smallthinker":
+        from .smallthinker import SmallThinkerConfig
+
+        # The config maps; a checkpoint's tensor names are not known here,
+        # so `load_pretrained` has no key specs for this family.
+        if not config.get("moe_primary_router_apply_softmax", True):
+            raise ValueError(
+                "This smallthinker config routes without a softmax "
+                "(moe_primary_router_apply_softmax=false); only the softmax "
+                "router is implemented."
+            )
+        if config.get("rope_scaling"):
+            raise ValueError("rope_scaling is not implemented for the smallthinker family")
+        n_layers = config["num_hidden_layers"]
+
+        def layout(key: str, default: int) -> tuple[int, ...]:
+            # A depth-cut config keeps the published per-layer layout whole:
+            # its first num_hidden_layers entries apply.
+            entries = tuple(config.get(key) or (default,) * n_layers)
+            if len(entries) < n_layers:
+                raise ValueError(f"{key} has {len(entries)} entries for {n_layers} layers")
+            return entries[:n_layers]
+
+        return "smallthinker", SmallThinkerConfig(
+            vocab_size=config["vocab_size"],
+            d_model=config["hidden_size"],
+            n_layers=n_layers,
+            num_heads=config["num_attention_heads"],
+            num_kv_heads=config["num_key_value_heads"],
+            head_dim=config.get("head_dim") or config["hidden_size"] // config["num_attention_heads"],
+            n_experts=config["moe_num_primary_experts"],
+            moe_top_k=config["moe_num_active_primary_experts"],
+            d_expert=config["moe_ffn_hidden_size"],
+            norm_topk_prob=bool(config.get("norm_topk_prob", True)),
+            sliding_window=config["sliding_window_size"],
+            window_layout=layout("sliding_window_layout", 0),
+            rope_layout=layout("rope_layout", 1),
+            max_seq_len=config["max_position_embeddings"],
+            rope_theta=float(config["rope_theta"]),
+            norm_eps=float(config["rms_norm_eps"]),
+            tie_embeddings=bool(config.get("tie_word_embeddings", False)),
+        )
     raise ValueError(
         f"Unsupported HF model_type {mt!r}; supported: llama, mistral, "
-        "mixtral, qwen2, gpt2, gpt_neox, gptj, opt, bert, vit, t5 (v1.1 "
-        "gated layout)."
+        "mixtral, qwen2, smallthinker (config only), gpt2, gpt_neox, gptj, "
+        "opt, bert, vit, t5 (v1.1 gated layout)."
     )
 
 

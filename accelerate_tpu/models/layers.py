@@ -117,8 +117,56 @@ def cache_write(buf: jax.Array, new: jax.Array, start: jax.Array) -> jax.Array:
     )(buf, new, start)
 
 
+def _row_cursors(start: jax.Array, batch: int) -> jax.Array:
+    """A scalar or (B,) cursor as (B,) int32."""
+    return jnp.broadcast_to(jnp.asarray(start, jnp.int32).reshape(-1), (batch,))
+
+
+def ring_positions(start: jax.Array, ring_len: int, batch: int) -> jax.Array:
+    """(B, W) the position each row of a ring buffer of ``ring_len`` rows
+    holds when the cursor stands at ``start`` (scalar or (B,)): position p
+    lives in row ``p mod W``, so row r holds the largest p < start with
+    ``p mod W == r``; -1 where no such position has been written yet."""
+    start = _row_cursors(start, batch)
+    r = jnp.arange(ring_len, dtype=jnp.int32)
+    last = start[:, None] - 1
+    held = last - (last - r[None, :]) % ring_len
+    return jnp.where(held >= 0, held, -1)
+
+
+def _ring_write_stacked(
+    all_buf: jax.Array, i: jax.Array, rows: jax.Array, start: jax.Array, valid: jax.Array | None
+) -> jax.Array:
+    """`cache_write_stacked` for several rows into a ring: row t of ``rows``
+    is position ``start + t`` and lands in ring row ``(start + t) mod W``;
+    only the first ``valid`` rows are real (scalar or (B,); all when None),
+    and of those the last W survive. Written as a gather and a select over
+    layer ``i``'s rows, not a scatter: a chunk that wraps is two runs of
+    rows, and rows past ``valid`` (a bucket's pad tail) must not land at
+    all, because in a ring they would land on rows still in the window."""
+    B, T = rows.shape[:2]
+    W = all_buf.shape[2]
+    start = _row_cursors(start, B)
+    n = _row_cursors(T if valid is None else valid, B)
+    r = jnp.arange(W, dtype=jnp.int32)
+    last = (start + n - 1)[:, None]
+    # The newest real row that lands in ring row r; negative where none does.
+    j = (n - 1)[:, None] - (last - r[None, :]) % W
+    tail = (1,) * (rows.ndim - 2)
+    new = jnp.take_along_axis(rows, jnp.clip(j, 0, T - 1).reshape((B, W) + tail), axis=1)
+    old = jax.lax.dynamic_index_in_dim(all_buf, i, 0, keepdims=False)
+    merged = jnp.where((j >= 0).reshape((B, W) + tail), new, old)
+    return jax.lax.dynamic_update_index_in_dim(all_buf, merged, i, 0)
+
+
 def cache_write_stacked(
-    all_buf: jax.Array, i: jax.Array, rows: jax.Array, start: jax.Array
+    all_buf: jax.Array,
+    i: jax.Array,
+    rows: jax.Array,
+    start: jax.Array,
+    *,
+    ring: bool = False,
+    valid: jax.Array | None = None,
 ) -> jax.Array:
     """Write ``rows`` (B, T, ...) into layer ``i`` of a layer-stacked cache
     buffer (L, B, S, ...) at offset ``start`` and return the updated stack.
@@ -127,9 +175,18 @@ def cache_write_stacked(
     engine's per-slot cursors, speculative decoding's per-row commits) is one
     scatter of B x T rows into ``[i, b, start_b + t]``. A scattered row that
     would land past the end of the buffer is dropped. Shared by every
-    family's cache path, which carries the stack through its layer scan."""
+    family's cache path, which carries the stack through its layer scan.
+
+    ``ring`` makes the buffer a ring of S rows (a sliding-window layer keeps
+    only its window): position p lives in row ``p mod S``. One row (a decode
+    step) is the same write at ``start mod S``; several rows may wrap and
+    carry ``valid`` (`_ring_write_stacked`)."""
     start = jnp.asarray(start, jnp.int32)
     rows = rows.astype(all_buf.dtype)
+    if ring:
+        if rows.shape[1] > 1:
+            return _ring_write_stacked(all_buf, i, rows, start, valid)
+        start = start % all_buf.shape[2]
     if start.ndim == 0:
         idx = (i, 0, start) + (0,) * (all_buf.ndim - 3)
         return jax.lax.dynamic_update_slice(all_buf, rows[None], idx)
@@ -155,12 +212,24 @@ def dequant_kv(vals: jax.Array, scales: jax.Array, dtype) -> jax.Array:
 
 
 def cache_append(
-    kv: dict[str, jax.Array], i: jax.Array, k: jax.Array, v: jax.Array, start: jax.Array
+    kv: dict[str, jax.Array],
+    i: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    start: jax.Array,
+    *,
+    ring: bool = False,
+    valid: jax.Array | None = None,
 ) -> dict[str, jax.Array]:
     """Write one layer's new keys and values (B, T, K, h) into the stacked
     cache leaves ``kv`` (a family cache without its ``length`` cursor: ``k``
     / ``v`` of shape (L, B, S, K*h) and, for an int8 cache, ``k_scale`` /
-    ``v_scale`` of shape (L, B, S, K)) at layer ``i``, offset ``start``."""
+    ``v_scale`` of shape (L, B, S, K)) at layer ``i``, offset ``start``.
+
+    A family whose layers are of two kinds keeps one such set of leaves for
+    each kind, ``i`` the layer's index within its kind; ``ring`` leaves hold
+    only a window's rows (`cache_write_stacked`), and a chunk written into
+    them says how many of its rows are real (``valid``)."""
     B, T = k.shape[:2]
     if "k_scale" in kv:
         k, k_scale = quantize_kv(k)
@@ -170,7 +239,8 @@ def cache_append(
         new = {}
     new["k"], new["v"] = k.reshape(B, T, -1), v.reshape(B, T, -1)
     return {
-        name: cache_write_stacked(buf, i, new[name], start) for name, buf in kv.items()
+        name: cache_write_stacked(buf, i, new[name], start, ring=ring, valid=valid)
+        for name, buf in kv.items()
     }
 
 
@@ -381,6 +451,50 @@ def dot_product_attention(
     return out.reshape(B, S, H, h)
 
 
+def position_masked_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    q_pos: jax.Array,
+    k_pos: jax.Array,
+    *,
+    window: int | None = None,
+    q_block: int | None = None,
+) -> jax.Array:
+    """`dot_product_attention` of q (B, S, H, h) over k / v (B, T, K, h) whose
+    visibility is given by positions, not by row order: key j is visible from
+    query i iff ``0 <= k_pos[j] <= q_pos[i]`` and, with ``window``,
+    ``q_pos[i] - k_pos[j] < window``. q_pos (B, S), k_pos (B, T) or (T,); a
+    negative key position marks a row that holds nothing. This is what a
+    prefill chunk runs against a cache: the rows of a full-length buffer are
+    their own positions, those of a ring are `ring_positions`.
+
+    ``q_block`` computes the queries in blocks of that many rows (when it
+    divides S), so that the fp32 scores of a long chunk against a long cache
+    are never whole in memory: 28 heads x 1024 queries x 16,384 keys are
+    1.9 GB, a block of 256 queries a quarter of that."""
+    B, S = q.shape[:2]
+    k_pos = jnp.broadcast_to(k_pos, (B, k.shape[1]))
+
+    def block(qb, pb):
+        seen = (k_pos[:, None, :] <= pb[:, :, None]) & (k_pos[:, None, :] >= 0)
+        if window is not None:
+            seen = seen & (pb[:, :, None] - k_pos[:, None, :] < window)
+        return dot_product_attention(qb, k, v, mask=seen)
+
+    if q_block is None or S <= q_block or S % q_block:
+        return block(q, q_pos)
+    n = S // q_block
+    out = jax.lax.map(
+        lambda qp: block(*qp),
+        (
+            q.reshape(B, n, q_block, *q.shape[2:]).swapaxes(0, 1),
+            q_pos.reshape(B, n, q_block).swapaxes(0, 1),
+        ),
+    )
+    return out.swapaxes(0, 1).reshape(q.shape)
+
+
 _ATTENTION_PATHS: contextvars.ContextVar[list[str] | None] = contextvars.ContextVar(
     "atx_cached_attention_paths", default=None
 )
@@ -402,6 +516,37 @@ def record_attention_paths():
         _ATTENTION_PATHS.reset(token)
 
 
+_STEP_COUNTS: contextvars.ContextVar[dict[str, jax.Array] | None] = contextvars.ContextVar(
+    "atx_step_counts", default=None
+)
+
+
+@contextlib.contextmanager
+def record_step_counts():
+    """Collect the exact counts a family's cached forward reports while it
+    is traced inside the block (`report_step_counts`): name -> int32 scalar
+    of the trace, summed over the reports. The serving engine wraps its
+    decode program's trace in it and returns what was collected beside the
+    step's tokens, so the counts ride the one fetch a step already makes."""
+    counts: dict[str, jax.Array] = {}
+    token = _STEP_COUNTS.set(counts)
+    try:
+        yield counts
+    finally:
+        _STEP_COUNTS.reset(token)
+
+
+def report_step_counts(counts: dict[str, jax.Array]) -> None:
+    """Hand `record_step_counts` what this forward counted (e.g. the expert
+    layer's `ops.moe.MOE_COUNTS`, summed over its layers). Values must belong
+    to the trace that is recording: report after the layer scan, from its
+    carry. A no-op when nothing records."""
+    into = _STEP_COUNTS.get()
+    if into is not None:
+        for name, value in counts.items():
+            into[name] = into[name] + value if name in into else value
+
+
 def cached_attention(
     q: jax.Array,
     kv: dict[str, jax.Array],
@@ -421,7 +566,14 @@ def cached_attention(
     the whole buffers and the layer index, int8 dequant included. Everything
     else (prefill, a window, kernels off, the CPU) slices layer ``i`` out of
     the stack and runs the reference `dot_product_attention` with the full
-    cache ``mask``."""
+    cache ``mask``.
+
+    A ring of W rows (a window layer's leaves, `cache_write_stacked`) is
+    read the same way after the step's row is written: every row it holds is
+    inside the window, the order of rows inside a softmax does not matter
+    (rotary is applied before the write), so ``lengths`` is
+    ``min(cursor + 1, W)``, ``mask`` its (B, 1, W) counterpart, ``i`` the
+    layer's index among the window layers, and ``window`` stays None."""
     out = None
     if lengths is not None and window is None:
         from ..native.pallas.decode_attention import maybe_flash_decode

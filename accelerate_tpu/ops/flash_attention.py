@@ -880,17 +880,22 @@ def pick_block(dim: int, candidates: tuple[int, ...] = (512, 256, 128, 64, 32, 1
 
 
 def tuned_call_kwargs(
-    name: str, interpret: bool, semantics: tuple[str, ...] | None = None
+    name: str,
+    interpret: bool,
+    semantics: tuple[str, ...] | None = None,
+    vmem_limit_bytes: int | None = None,
 ):
     """`pallas_call` kwargs every kernel of the package shares: its ``name``
     (``metadata`` is what reaches a device trace: a v5e names an operation by
     its HLO text, and JAX writes the metadata into the custom call's
-    ``frontend_attributes={kernel_metadata={...}}``) and the per-grid
-    dimension semantics (compiled mode only: the interpreter takes no
-    compiler params)."""
+    ``frontend_attributes={kernel_metadata={...}}``), the per-grid
+    dimension semantics and, for a kernel that stages more than Mosaic's
+    default scoped VMEM, its limit (compiled mode only: the interpreter
+    takes no compiler params)."""
     kwargs = {"name": name, "metadata": {"kernel": name}, "interpret": interpret}
     if semantics is not None and not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=tuple(semantics)
-        )
+        params = {"dimension_semantics": tuple(semantics)}
+        if vmem_limit_bytes is not None:
+            params["vmem_limit_bytes"] = vmem_limit_bytes
+        kwargs["compiler_params"] = pltpu.CompilerParams(**params)
     return kwargs

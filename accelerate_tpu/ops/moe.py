@@ -19,6 +19,15 @@ construction (static shapes, einsum dispatch, XLA inserts the all-to-alls):
   in this file;
 - aux losses: load-balance (Switch eq. 4) + router z-loss, returned for the
   model's loss function to weight in.
+
+Beside it stands the **dropless** layer (`moe_dropless`), for serving and
+for models whose routing may not lose a token: the same router arithmetic,
+then the ``rows x top_k`` assignments sorted by expert (a counting sort:
+one-hot ranks, no comparison sort), each expert's group padded to whole row
+tiles, one grouped expert feed-forward over the sorted rows
+(`native/pallas/moe_experts.py`, the weight stacks read in place; without
+the kernel `jax.lax.ragged_dot` on one layer's experts), and the weighted
+sum back. Padding rows are computed and counted; no row is dropped.
 """
 
 from __future__ import annotations
@@ -30,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.layers import truncated_normal_init
+from ..models.layers import activation_fn, truncated_normal_init
 from .fp8 import matmul_einsum
 
 Params = Any
@@ -63,13 +72,22 @@ def _n_groups(n_tokens: int, tokens_per_group: int) -> int:
     return n_tokens
 
 
+def router_probs(router: jax.Array, x: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(logits, softmax) of rows ``x`` (n, d) over the router's experts, in
+    fp32 at full matmul precision: tiny FLOPs, and logit precision decides
+    the expert choice. Shared by the capacity and the dropless layer."""
+    logits = jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    return logits, jax.nn.softmax(logits, axis=-1)
+
+
 def _group_moe(params: Params, xt: jax.Array, *, top_k: int, capacity: int):
     """Dispatch/FFN/combine for ONE token group. xt: (n, d)."""
     n, d = xt.shape
     E = params["router"].shape[-1]
-    # Router in fp32: tiny FLOPs, and logit precision decides expert choice.
-    logits = xt.astype(jnp.float32) @ params["router"].astype(jnp.float32)  # (n, E)
-    probs = jax.nn.softmax(logits, axis=-1)
+    logits, probs = router_probs(params["router"], xt)  # (n, E)
 
     # Top-k selection (static k) with per-round masking.
     remaining = probs
@@ -165,8 +183,7 @@ def moe_reference(params: Params, x: jax.Array, *, top_k: int = 2) -> jax.Array:
     unlimited capacity (for tests)."""
     B, S, d = x.shape
     xt = x.reshape(-1, d)
-    logits = xt.astype(jnp.float32) @ params["router"].astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
+    _, probs = router_probs(params["router"], xt)
     E = probs.shape[-1]
     _, topk_idx = jax.lax.top_k(probs, top_k)
 
@@ -181,3 +198,121 @@ def moe_reference(params: Params, x: jax.Array, *, top_k: int = 2) -> jax.Array:
     weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
     out = jnp.einsum("ne,ned->nd", weights.astype(xt.dtype), all_out)
     return out.reshape(B, S, d)
+
+
+# ------------------------------------------------------------------ dropless
+MOE_COUNTS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched", "moe_expert_rows_max")
+
+
+def dispatch_tile_rows(n_assignments: int, n_experts: int, dtype) -> int:
+    """Row tile of the grouped expert product: about the rows an expert gets
+    when routing is even, between the dtype's smallest tile and the MXU's
+    128 rows. A decode step (rows << experts) pads each touched expert to
+    the smallest tile; a prefill chunk fills whole MXU passes."""
+    from ..native.pallas.moe_experts import min_tile_rows
+
+    even = max(n_assignments // max(n_experts, 1), 1)
+    tile = 1 << (even - 1).bit_length()
+    return int(min(128, max(min_tile_rows(dtype), tile)))
+
+
+def _sorted_layout(expert: jax.Array, held: jax.Array, n_experts: int, tile: int):
+    """Where each assignment's row goes when the rows are sorted by expert
+    and every expert's group is padded to whole tiles of ``tile`` rows.
+
+    ``expert`` (A,) int32 in [0, n_experts) where ``held``. Returns ``dest``
+    (A,): the row of each assignment (the static row count where not held),
+    ``counts`` (E,) rows routed to each expert, ``tile_expert`` (T,) and
+    ``n_tiles`` (): the expert of each row tile and how many tiles are in
+    use, T the static bound ``(A + min(E, A) * (tile - 1)) // tile``."""
+    A = expert.shape[0]
+    n_row_tiles = (A + min(n_experts, A) * (tile - 1)) // tile
+    onehot = ((expert[:, None] == jnp.arange(n_experts)[None, :]) & held[:, None]).astype(jnp.int32)
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
+    counts = jnp.sum(onehot, axis=0)
+    tiles = (counts + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles)
+    n_tiles = tile_end[-1]
+    first_row = (tile_end - tiles) * tile
+    dest = jnp.where(held, first_row[jnp.clip(expert, 0, n_experts - 1)] + rank, n_row_tiles * tile)
+    # Tiles past the last one in use repeat its expert: nothing new to fetch.
+    t = jnp.minimum(jnp.arange(n_row_tiles), jnp.maximum(n_tiles - 1, 0))
+    tile_expert = jnp.minimum(jnp.searchsorted(tile_end, t, side="right"), n_experts - 1)
+    return dest, counts, tile_expert.astype(jnp.int32), n_tiles
+
+
+def moe_dropless(
+    params: Params,
+    x: jax.Array,
+    router_x: jax.Array | None = None,
+    *,
+    top_k: int,
+    activation: str = "relu",
+    renormalize: bool = True,
+    layer: jax.Array | int | None = None,
+    first_expert: int = 0,
+) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """Top-k routed gated experts that drop nothing. ``x`` (n, d) is what the
+    experts read, ``router_x`` (n, d) what the router reads (``x`` when
+    None: some models route from the layer's input, before attention).
+
+    ``params``: ``router`` (d, E_router) and the expert weights ``w_gate`` /
+    ``w_up`` (E, d, f), ``w_down`` (E, f, d) - or, with ``layer``, the
+    layer-stacked weights (L, E, ...), read at ``layer`` without slicing the
+    experts out of the stack (the router may then be stacked too, (L, d,
+    E_router), or that layer's own). The weights hold
+    experts ``first_expert .. first_expert + E`` of the router's E_router:
+    every row is routed over all of them, and the result is the part the held
+    experts give (all of it when E == E_router), as one chip of an
+    expert-parallel deployment computes before the exchange.
+
+    Weights of the chosen k are the router's softmax, renormalised over the
+    k when ``renormalize``. Returns (out (n, d), counts): ``MOE_COUNTS`` as
+    int32 scalars - assignments held here, rows the expert products ran
+    (padding included), experts with at least one row, the largest group."""
+    from ..native.pallas import kernel_mode
+    from ..native.pallas import moe_experts as kernel
+
+    n, d = x.shape
+    stacked = layer is not None
+    weights = [params[name] if stacked else params[name][None] for name in ("w_gate", "w_up", "w_down")]
+    router = params["router"]
+    if router.ndim == 3:
+        router = jax.lax.dynamic_index_in_dim(router, layer, 0, keepdims=False)
+    layer = jnp.asarray(layer if stacked else 0, jnp.int32)
+    E = weights[0].shape[1]
+
+    _, probs = router_probs(router, x if router_x is None else router_x)
+    gate, chosen = jax.lax.top_k(probs, top_k)  # (n, k)
+    if renormalize:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    expert = chosen.reshape(-1).astype(jnp.int32) - first_expert
+    held = (expert >= 0) & (expert < E)
+
+    mode = kernel_mode("moe_experts")
+    tile = dispatch_tile_rows(n * top_k, E, x.dtype)
+    if mode is None or not kernel.supported(weights[0], tile, x.dtype, compiled=mode == "compiled"):
+        mode, tile = None, 1  # `ragged_dot` needs no padding
+    dest, counts, tile_expert, n_tiles = _sorted_layout(expert, held, E, tile)
+    n_rows = tile_expert.shape[0] * tile
+    token = jnp.repeat(jnp.arange(n, dtype=jnp.int32), top_k)
+    source = jnp.zeros((n_rows,), jnp.int32).at[dest].set(token, mode="drop")
+    xs = x[source]  # padding rows read row 0; they are computed and never gathered
+    if mode is not None:
+        ys = kernel.moe_experts(
+            xs, *weights, tile_expert, n_tiles, layer,
+            tile_rows=tile, activation=activation_fn(activation), interpret=mode == "interpret",
+        )
+    else:
+        w_gate, w_up, w_down = (
+            jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False) for w in weights
+        )
+        hidden = activation_fn(activation)(jax.lax.ragged_dot(xs, w_gate.astype(xs.dtype), counts))
+        hidden = hidden * jax.lax.ragged_dot(xs, w_up.astype(xs.dtype), counts)
+        ys = jax.lax.ragged_dot(hidden, w_down.astype(xs.dtype), counts)
+    picked = ys[jnp.minimum(dest, n_rows - 1)].reshape(n, top_k, d).astype(jnp.float32)
+    # `where`, not a zero weight: a row that is not held gathers a row no tile wrote.
+    weighted = jnp.where(held.reshape(n, top_k, 1), picked * gate[..., None], 0.0)
+    out = jnp.sum(weighted, axis=1).astype(x.dtype)
+    stats = (jnp.sum(held), n_tiles * tile, jnp.sum(counts > 0), jnp.max(counts))
+    return out, {name: v.astype(jnp.int32) for name, v in zip(MOE_COUNTS, stats)}

@@ -41,6 +41,7 @@ the plain `Generator` is still the right tool.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import time
 from collections import deque
 from typing import Any, Callable, Iterable, Sequence
@@ -58,7 +59,9 @@ from ..models.layers import (
     cache_slot_view,
     cache_slot_write,
     record_attention_paths,
+    record_step_counts,
 )
+from ..ops.moe import MOE_COUNTS
 from ..utils.environment import (
     get_int_from_env,
     get_str_from_env,
@@ -74,6 +77,8 @@ __all__ = [
     "shared_prefix_trace",
     "default_buckets",
 ]
+
+logger = logging.getLogger(__name__)
 
 ApplyFn = Callable[[Any, jax.Array, Any], tuple[jax.Array, Any]]
 
@@ -198,7 +203,15 @@ class Engine:
     ``init_cache_fn(batch, max_len) -> cache`` follow the model-family
     cache contract (e.g. `models/llama.py:forward_with_cache` /
     ``init_cache``); every family cache whose non-``length`` leaves are
-    layer-stacked ``(L, B, T, ...)`` buffers works (bf16/fp32/int8).
+    layer-stacked ``(L, B, T, ...)`` buffers works (bf16/fp32/int8). A
+    family with two kinds of layer keeps one set of leaves for each, of
+    different ``L`` and ``T``: leaves shorter than ``max_len`` are rings that
+    hold a sliding window's rows (`models/layers.py:cache_write_stacked`).
+    Slot bookkeeping by cursor is the same; a prefill chunk then tells the
+    forward how many of its rows are real (``cache['valid']``), and the
+    prefix cache is off: its copies go "at the same sequence offset", which
+    a ring does not have (``stats['prefix_cache_off_for_ring']``; asking for
+    it explicitly is a ValueError).
 
     ``max_len`` is the per-slot KV capacity (prompt + new tokens must fit);
     defaults to ``2 * max(buckets)``. ``prefill_interleave`` is the number
@@ -277,6 +290,9 @@ class Engine:
         # committedness) stay IDENTICAL from the first call on — one compile
         # for decode, one per prefill bucket.
         self._kv = jax.device_put(kv, self._device)
+        # Rows of the shortest leaf where it is shorter than a slot: a ring.
+        shortest = min(int(v.shape[2]) for v in jax.tree.leaves(kv))
+        self._ring_len = shortest if shortest < self.max_len else 0
         config_ = self.config
         eos, pad = config_.eos_token_id, config_.pad_token_id
 
@@ -302,14 +318,16 @@ class Engine:
             when enabled (``ATX_KERNELS`` / ``ATX_KERNEL_DECODE_ATTN``,
             read at trace time): split-K over the slot KV cache, masked by
             each row's length cursor, with int8 KV dequantized in-kernel."""
-            with record_attention_paths() as paths:
+            with record_attention_paths() as paths, record_step_counts() as counts:
                 logits, new = apply_fn(params, tokens[:, None], dict(kv, length=lengths))
             # Trace time: which attention lowering this program compiled to.
             self.stats["decode_in_place"] = int(
                 bool(paths) and all(p == "in_place" for p in paths)
             )
             nxt = jax.vmap(_sample)(logits[:, -1, :], seeds, steps)
-            return nxt, {k: new[k] for k in kv}
+            # What the forward counted (an expert layer's routing; nothing
+            # for a dense model) leaves with the tokens: one fetch a step.
+            return (nxt, counts), {k: new[k] for k in kv}
 
         def prefill_fn(params, tokens, kv, slot, cursor, sample_pos, seed):
             """One bucket-padded prompt chunk into slot row ``slot`` at
@@ -318,7 +336,11 @@ class Engine:
             returned token (sampled at ``sample_pos``, the chunk's last
             REAL position) is only meaningful on a prompt's final chunk."""
             row = cache_slot_view(kv, slot)
-            logits, new = apply_fn(params, tokens, dict(row, length=cursor))
+            cache = dict(row, length=cursor)
+            if self._ring_len:
+                # In a ring the pad tail would land on rows still in the window.
+                cache["valid"] = sample_pos + 1
+            logits, new = apply_fn(params, tokens, cache)
             kv = cache_slot_write(kv, {k: new[k] for k in row}, slot)
             last = jnp.take_along_axis(logits[0], sample_pos[None, None], axis=0)[0]
             tok = _sample(last, seed, jnp.zeros((), jnp.int32))
@@ -350,6 +372,18 @@ class Engine:
             if prefix_cache is None
             else prefix_cache
         )
+        if self._ring_len:
+            if prefix_cache:
+                raise ValueError(
+                    f"this family's cache has ring leaves ({self._ring_len} rows for slots "
+                    f"of {self.max_len}): the prefix cache copies rows at the same sequence "
+                    "offset, which a ring does not have; run with prefix_cache off"
+                )
+            if enabled:
+                logger.info(
+                    "prefix cache off: the cache has ring leaves of %d rows", self._ring_len
+                )
+            enabled = False
         self.prefix_cache: PrefixCache | None = None
         self._pool: Any = None
         if enabled:
@@ -411,10 +445,18 @@ class Engine:
                 "prefix_promotions",
                 "cancelled",
                 "decode_in_place",
+                "prefix_cache_off_for_ring",
+                # Rows of KV a decode step had to read, summed over the
+                # decoding slots and the steps: per full-length layer, and per
+                # ring layer (capped at the ring's rows).
+                "kv_rows_live_full",
+                "kv_rows_live_window",
+                *MOE_COUNTS,  # the expert layer's, summed over layers and decode steps
             ),
             label="engine",
-            gauges=("decode_in_place",),
+            gauges=("decode_in_place", "prefix_cache_off_for_ring"),
         )
+        self.stats["prefix_cache_off_for_ring"] = int(bool(self._ring_len))
         _labels = ("engine",)
         self._tel_labels = self.stats.labels
         self._h_queue_wait = _telemetry.histogram(
@@ -896,19 +938,28 @@ class Engine:
                 # transfer is asynchronous and can alias numpy memory, so it
                 # may still be reading a host buffer when the lines below
                 # advance it in place.
-                tokens, self._kv = self._decode(
+                (tokens, counts), self._kv = self._decode(
                     self.params, tokens, lengths.copy(), self._kv, seeds, steps.copy()
                 )
-                fetched.append(tokens)
+                fetched.append((tokens, counts))
                 lengths[decoding] += 1
                 steps[decoding] += 1
+                live = int(lengths[decoding].sum())  # cursor + 1 of each decoding slot
+                self.stats["kv_rows_live_full"] += live
+                if self._ring_len:
+                    self.stats["kv_rows_live_window"] += int(
+                        np.minimum(lengths[decoding], self._ring_len).sum()
+                    )
         with _telemetry.span("serve_fetch"):
-            host_tokens = [np.asarray(t) for t in jax.device_get(fetched)]
+            host = jax.device_get(fetched)
         out: list[Completion] = []
         with _telemetry.span("serve_emit"):
             self.stats["decode_steps"] += block
             self.stats["decode_slot_steps"] += block * len(decoding)
-            for nxt in host_tokens:
+            for _, counts in host:
+                for name, value in counts.items():
+                    self.stats[name] += int(value)
+            for nxt, _ in host:
                 for i in decoding:
                     slot = self._slots[i]
                     if slot is None or not slot.decoding:

@@ -28,8 +28,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
-from accelerate_tpu.models import llama  # noqa: E402
-from accelerate_tpu.native.pallas import decode_attention, fused_adamw, quant_matmul  # noqa: E402
+from accelerate_tpu.models import llama, smallthinker  # noqa: E402
+from accelerate_tpu.native.pallas import decode_attention, fused_adamw, moe_experts, quant_matmul  # noqa: E402
 from accelerate_tpu.native.pallas.dispatch import force_kernels  # noqa: E402
 from accelerate_tpu.ops.flash_attention import flash_attention  # noqa: E402
 
@@ -87,6 +87,15 @@ def _fp8_matmul(a, b, s):
     return quant_matmul.scaled_matmul("mc,cn->mn", a, b, s, BF16, interpret=False)
 
 
+def _moe_experts(tile_rows):
+    def call(x, w_gate, w_up, w_down, tile_expert, n_tiles, layer):
+        return moe_experts.moe_experts(
+            x, w_gate, w_up, w_down, tile_expert, n_tiles, layer, tile_rows=tile_rows, interpret=False
+        )
+
+    return call
+
+
 def _adamw(g, mu, nu, p, count, lr):
     return fused_adamw.fused_adamw_update(
         g, mu, nu, p, count, lr, 0.9, 0.999, 1e-8, 0.01, interpret=False
@@ -99,6 +108,10 @@ _STACK = (2, SLOTS, SLOT_LEN, K * HD)  # a two-layer stacked cache, heads flatte
 _LEAF = ((D, FF), F32)
 _QKV_SHORT = [((1, 2048, H, HD), BF16), ((1, 2048, K, HD), BF16), ((1, 2048, K, HD), BF16)]
 F8 = jnp.float8_e4m3fn
+# SmallThinker-21BA3B: 64 experts of 2560 x 768, a two-layer stack; a decode
+# step's 96 assignments in tiles of 16 rows, a 1024-row chunk's 6144 in 128s.
+ST_E, ST_D, ST_F = 64, 2560, 768
+_EXPERT_STACKS = [((2, ST_E, ST_D, ST_F), BF16)] * 2 + [((2, ST_E, ST_F, ST_D), BF16)]
 _FLASH_BWD = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
 # name -> (function, [(shape, dtype)...], the kernels expected, by the name each carries)
 KERNELS = {
@@ -123,6 +136,18 @@ KERNELS = {
     "int8_matmul_decode": (_int8_matmul, [((SLOTS, D), BF16), ((D, FF), I8), ((1, FF), F32)], ["int8_matmul"]),
     "fp8_scaled_matmul": (_fp8_matmul, [((2048, D), F8), ((D, FF), F8), ((), F32)], ["scaled_matmul"]),
     "fused_adamw_leaf": (_adamw, [_LEAF, _LEAF, _LEAF, _LEAF, ((), I32), ((), F32)], ["fused_adamw"]),
+    # Three 3.9 MB weight blocks an expert, double-buffered: past Mosaic's
+    # default scoped VMEM, so the call raises the limit.
+    "moe_experts_decode": (
+        _moe_experts(16),
+        [((66 * 16, ST_D), BF16), *_EXPERT_STACKS, ((66,), I32), ((), I32), ((), I32)],
+        ["moe_experts"],
+    ),
+    "moe_experts_prefill": (
+        _moe_experts(128),
+        [((111 * 128, ST_D), BF16), *_EXPERT_STACKS, ((111,), I32), ((), I32), ((), I32)],
+        ["moe_experts"],
+    ),
 }
 
 
@@ -147,11 +172,14 @@ _CHAT_SLOTS, _CHAT_LEN = 32, 1024
 _MOVES = ("copy", "dynamic-slice", "dynamic-update-slice", "reshape", "transpose", "slice")
 
 
-def _cache_sized_moves(text, layer_elements):
+def _cache_sized_moves(text, layer_elements, dims=None):
     """Instructions of a compiled module (fused computations included) that
     copy, slice, reshape or update-slice an array of one layer's cache or
-    more: (name, opcode) pairs, named as a v5e trace would show them."""
+    more: (name, opcode) pairs, named as a v5e trace would show them.
+    ``dims`` (a regular expression on an array's ``a,b,c`` dimensions) keeps
+    only arrays of such a shape."""
     found = []
+    dims_wanted = re.compile(dims) if dims else None
     for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][a-z\-]*)\(", line)
         if m is None:
@@ -160,6 +188,7 @@ def _cache_sized_moves(text, layer_elements):
         sizes = [
             int(np.prod([int(d) for d in dims.split(",")]))
             for dims in re.findall(r"[a-z]\w*\[([0-9,]+)\]", result)
+            if dims_wanted is None or dims_wanted.search(dims)
         ]
         moved = opcode.removesuffix("-start").removesuffix("-done") in _MOVES or any(
             word in name for word in _MOVES
@@ -213,6 +242,55 @@ def test_engine_decode_touches_the_cache_in_place_on_v5e(v5e, monkeypatch, kerne
         assert any("slice" in name or "slice" in opcode for name, opcode in moves), moves
 
 
+def test_smallthinker_decode_reads_cache_and_experts_in_place_on_v5e(v5e, monkeypatch):
+    """The engine's decode program of one period (a full layer and three
+    windowed ones) at the published widths, 16 slots of 16,384, compiled for
+    the described chip: both kinds of cache are read by `flash_decode` where
+    they lie, the expert stacks by `moe_experts` where they lie, and no copy,
+    slice, reshape or update of a layer of either kind of cache (a ring layer
+    of 16 slots is 33.5 M elements) or of a layer's experts (126 M a matrix)
+    stands between. Arrays of other shapes are not looked at: XLA prefetches
+    the period's attention weights, 36.7 M elements of `wq`, by a slice."""
+    from accelerate_tpu import serving
+    from accelerate_tpu.generation import GenerationConfig
+    from accelerate_tpu.native.pallas import dispatch
+
+    monkeypatch.setattr(dispatch, "_on_tpu", lambda: True)
+    monkeypatch.setenv("ATX_SERVE_CAPACITY_CHECK", "off")
+    monkeypatch.setattr(serving.engine.jax, "device_put", lambda x, device=None: x)  # shapes only
+    cfg = smallthinker.SmallThinkerConfig(
+        vocab_size=1024, n_layers=4, window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1)
+    )
+    slots, max_len = 16, 16384
+    engine = serving.Engine(
+        lambda p, t, c: smallthinker.forward_with_cache(p, t, c, cfg),
+        lambda b, m: jax.eval_shape(lambda: smallthinker.init_cache(cfg, b, m)),
+        jax.eval_shape(lambda: smallthinker.init(jax.random.PRNGKey(0), cfg, BF16)),
+        GenerationConfig(), slots=slots, buckets=(256, 1024), max_len=max_len,
+    )
+    assert engine.prefix_cache is None and engine.stats["prefix_cache_off_for_ring"] == 1
+    one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
+    shapes = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        engine.abstract_decode_args(),
+    )
+    with force_kernels("on"):
+        compiled = jax.jit(engine._decode_fn, donate_argnums=(3,)).lower(*shapes).compile()
+    assert engine.stats["decode_in_place"] == 1
+    text = compiled.as_text()
+    named = set(re.findall(r'kernel_metadata=\{\s*"kernel":"(\w+)"', text))
+    assert named == {"flash_decode", "moe_experts"}
+    lanes = cfg.num_kv_heads * cfg.head_dim
+    ring_layer = slots * cfg.sliding_window * lanes
+    cache_or_experts = rf",{lanes}$|{ST_D},{ST_F}$|{ST_F},{ST_D}$"
+    assert _cache_sized_moves(text, ring_layer, cache_or_experts) == []
+    assert _cache_sized_moves(text, ring_layer) != []  # the filter is what lets the prefetch by
+    memory = compiled.memory_analysis()
+    cache_bytes = 2 * 2 * slots * lanes * (max_len + 3 * cfg.sliding_window)
+    assert memory.alias_size_in_bytes >= cache_bytes  # every leaf stays where it was donated
+    assert memory.temp_size_in_bytes < ST_E * ST_D * ST_F * 2  # under one matrix of a layer's experts
+
+
 def test_every_pallas_call_in_the_package_is_named():
     """Each `pl.pallas_call(` site takes its keywords from
     `tuned_call_kwargs`, which always gives it a name and the metadata that
@@ -240,7 +318,7 @@ def test_every_pallas_call_in_the_package_is_named():
                         for v in spread
                     )
                     sites.append((os.path.relpath(path, REPO), node.lineno, ok))
-    assert len(sites) == 9, sites
+    assert len(sites) == 10, sites
     assert [s for s in sites if not s[2]] == []
 
 
