@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.int8 import at_layer, hoist_layer_stacks
 from .layers import (
     AttentionSpec,
     apply_rope,
@@ -490,10 +491,14 @@ def forward_with_cache(
     # The layer-stacked cache rides the scan CARRY at every length: a step
     # writes its new rows into the donated buffers and attends against them
     # there (`layers.cache_append`, `layers.cached_attention`). As xs/ys the
-    # scan would restack the whole cache every step.
+    # scan would restack the whole cache every step. Int8 weight stacks the
+    # `int8_matmul` kernel will read stay out of the xs too, closed over
+    # whole: the kernel indexes them by ``i`` (`ops.int8.hoist_layer_stacks`).
+    blocks, stacks = hoist_layer_stacks(params["blocks"])
+
     def scan_body(carry, block):
         x, kv, i = carry
-        block = _maybe_dequantize(block, x.dtype)
+        block = _maybe_dequantize(at_layer(block, stacks, i), x.dtype)
         h = rms_norm(x, block["attn_norm"], config.norm_eps)
         q, k, v = attention_qkv(block["attn"], h)
         q = apply_rope(q, cos, sin, positions)
@@ -508,9 +513,7 @@ def forward_with_cache(
         return (x + ffn_out, kv, i + 1), None
 
     kv = {name: buf for name, buf in cache.items() if name != "length"}
-    (x, kv, _), _ = jax.lax.scan(
-        scan_body, (x, kv, jnp.zeros((), jnp.int32)), params["blocks"]
-    )
+    (x, kv, _), _ = jax.lax.scan(scan_body, (x, kv, jnp.zeros((), jnp.int32)), blocks)
     new_cache = dict(kv, length=start + T_new)
     x = rms_norm(x, params["final_norm"], config.norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, _lm_head(params, config).astype(x.dtype))

@@ -22,7 +22,12 @@ prefill, C = 14336, does not fit VMEM whole):
   nothing) and the elementwise ops replicate `quantize_act` literally; the
   one divergence from the fallback is the activation-scale divide, which
   Pallas lowers with TPU semantics (reciprocal-multiply, 1 ulp off IEEE) —
-  parity is ~1e-7 relative, not bitwise.
+  parity is ~1e-7 relative, not bitwise. The weight may be a layer stack
+  ``(L, ...)`` read in place at a traced ``layer``: the index rides scalar
+  prefetch and the weight block's index map picks the layer, so a scan over
+  layers hands the kernel the whole stack and no layer is sliced out of it
+  first (XLA cannot fuse a slice into a custom call: it would copy the
+  layer's matrix, and the kernel would read the copy).
 - :func:`scaled_matmul` — the fp8 contraction `(dot(qx, qw) * scale)` with
   fp8 operands fed to the MXU directly (``preferred_element_type=f32``)
   instead of XLA's materialized upcast. Quantization stays OUTSIDE (the
@@ -38,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from .dispatch import kernel_mode, pallas_available, register_kernel
+from .moe_experts import min_tile_rows
 
 register_kernel(
     "int8_matmul", "fused per-row quantize -> int8 MXU dot -> rescale"
@@ -136,14 +142,25 @@ def _plan(eq: str, a, b, out_dtype):
     bn = _tile(N, 128, pick_block)
     bc = _tile(C, 128, lambda d: _divisor(d, _CONTRACT_BLOCKS))
     a_item, b_item = jnp.dtype(a.dtype).itemsize, jnp.dtype(b.dtype).itemsize
-    staged = (
-        2 * bm * bc * a_item
-        + 2 * bc * bn * b_item
-        + 2 * bm * bn * jnp.dtype(out_dtype).itemsize
-        + bm * bn * 4  # accumulator scratch
-        + bm * bc * 9  # in-kernel quantization temporaries (2 x f32 + int8)
-    )
-    if staged > _VMEM_BUDGET:
+    out_item = jnp.dtype(out_dtype).itemsize
+
+    def staged(bn):
+        return (
+            2 * bm * bc * a_item
+            + 2 * bc * bn * b_item
+            + 2 * bm * bn * out_item
+            + bm * bn * 4  # accumulator scratch
+            + bm * bc * 9  # in-kernel quantization temporaries (2 x f32 + int8)
+        )
+
+    if bm == M and bn % 128 == 0:
+        # One row tile: every weight byte is read once, from HBM, and the
+        # (bc, bn) tile is the DMA that reads it. As wide as the budget
+        # holds: 16 grid steps of 3.7 MB for a 4096 x 14336 matrix, not 112
+        # of 0.5 MB whose fixed cost a step is half their transfer time.
+        wider = [n for n in range(bn, N + 1, 128) if N % n == 0 and staged(n) <= _VMEM_BUDGET]
+        bn = max(wider, default=bn)
+    if staged(bn) > _VMEM_BUDGET:
         return None
     return oa, ob, M, N, C, bm, bn, bc, tuple(a_rest), tuple(b_rest)
 
@@ -154,15 +171,30 @@ def _views(oa, ob, a, b, M, N, C):
     return a2, b2
 
 
+def _view_is_free(w_shape, n_out_axes: int, dtype) -> bool:
+    """Is the ``(C, N)`` view of a leading-contracted weight the same bytes?
+    The chip tiles an array's last two axes (sublanes x 128 lanes; 32
+    sublanes for int8): the columns must be the weight's own last axis, and
+    contracted axes merge freely only in whole sublane tiles. ``(h, k, d)``
+    with k = 128 is ``(h * k, d)`` as it lies; ``(d, h, k)`` as ``(d, h * k)``
+    is a copy."""
+    contracted = w_shape[: len(w_shape) - n_out_axes]
+    return n_out_axes == 1 and (
+        len(contracted) == 1 or contracted[-1] % min_tile_rows(dtype) == 0
+    )
+
+
 def _specs(oa, ob, bm, bn, bc):
+    # (Every index map takes the grid indices, then whatever rides scalar
+    # prefetch: nothing, or the int8 kernel's layer index.)
     if oa == "trail":
-        a_spec = pl.BlockSpec((bm, bc), lambda i, j, c: (i, c))
+        a_spec = pl.BlockSpec((bm, bc), lambda i, j, c, *_: (i, c))
     else:
-        a_spec = pl.BlockSpec((bc, bm), lambda i, j, c: (c, i))
+        a_spec = pl.BlockSpec((bc, bm), lambda i, j, c, *_: (c, i))
     if ob == "lead":
-        b_spec = pl.BlockSpec((bc, bn), lambda i, j, c: (c, j))
+        b_spec = pl.BlockSpec((bc, bn), lambda i, j, c, *_: (c, j))
     else:
-        b_spec = pl.BlockSpec((bn, bc), lambda i, j, c: (j, c))
+        b_spec = pl.BlockSpec((bn, bc), lambda i, j, c, *_: (j, c))
     return a_spec, b_spec
 
 
@@ -182,7 +214,8 @@ def _accumulate(acc_ref, part):
         acc_ref[...] += part
 
 
-def _int8_kernel(a_ref, sx_ref, b_ref, ws_ref, o_ref, acc_ref, *, dims):
+def _int8_kernel(layer_ref, a_ref, sx_ref, b_ref, ws_ref, o_ref, acc_ref, *, dims):
+    del layer_ref  # only the weight's block index map reads it
     # `quantize_act`'s rounding verbatim on one (bm, bc) tile against the
     # row scale, then an exact integer dot; only the scale divide (TPU
     # reciprocal semantics) can differ from the fallback, by 1 ulp.
@@ -214,15 +247,18 @@ def _scaled_kernel(a_ref, b_ref, s_ref, o_ref, acc_ref, *, dims):
         o_ref[...] = (acc_ref[...] * s_ref[0, 0]).astype(o_ref.dtype)
 
 
-def _tiled_call(name, kernel, plan, in_specs, out_dtype, acc_dtype, interpret):
+def _tiled_call(name, kernel, plan, in_specs, out_dtype, acc_dtype, interpret, n_prefetch=0):
     _, _, M, N, C, bm, bn, bc, _, _ = plan
     return pl.pallas_call(
         kernel,
-        grid=(M // bm, N // bn, C // bc),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, c: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_prefetch,
+            grid=(M // bm, N // bn, C // bc),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, c, *_: (i, j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
+        ),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         **tuned_call_kwargs(name, interpret, ("parallel", "parallel", "arbitrary")),
     )
 
@@ -232,21 +268,34 @@ def int8_matmul_fused(
     x: jax.Array,
     wq: jax.Array,
     w_scale: jax.Array,
+    layer: jax.Array | int | None = None,
     *,
     interpret: bool = False,
 ) -> jax.Array | None:
     """Fused `ops.int8.int8_einsum`: quantize rows of ``x``, int8 dot with
     ``wq``, rescale by ``row scale × w_scale``. Requires x contracted on its
     trailing axes (per-row groups = rows of the 2D view) and w on its
-    leading axes — true for every int8 forward equation. ``None`` when the
-    equation/shapes aren't supported (caller falls back)."""
-    plan = _plan(eq, x, wq, x.dtype)
+    leading axes — true for every int8 forward equation. With ``layer``,
+    ``wq`` is a stack ``(L, ...)`` of such weights, read in place at that
+    (traced) index; ``w_scale`` is the one layer's either way. ``None`` when
+    the equation/shapes aren't supported (caller falls back)."""
+    w_shape = wq.shape if layer is None else wq.shape[1:]
+    plan = _plan(eq, x, jax.ShapeDtypeStruct(w_shape, wq.dtype), x.dtype)
     if plan is None:
         return None
     oa, ob, M, N, C, bm, bn, bc, a_rest, b_rest = plan
     if oa != "trail" or ob != "lead":
         return None
-    x2, w2 = _views(oa, ob, x, wq, M, N, C)
+    if layer is not None and not _view_is_free(w_shape, len(b_rest), wq.dtype):
+        return None  # (L, C, N) would be a relayout of the whole stack, every call
+    x2 = x.reshape(M, C)
+    a_spec, w_spec = _specs(oa, ob, bm, bn, bc)
+    if layer is None:
+        w_view = wq.reshape(C, N)  # a lone matrix: the operand it always was
+    else:
+        w_view = wq.reshape(-1, C, N)
+        w_spec = pl.BlockSpec((None, bc, bn), lambda i, j, c, ly: (ly[0], c, j))
+    layer = jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1)
     # `quantize_act`'s per-row scale, over the whole contraction.
     amax = jnp.max(jnp.abs(x2.astype(jnp.float32)), axis=1, keepdims=True)
     sx = jnp.maximum(amax, 1e-12) / 127.0
@@ -254,23 +303,23 @@ def int8_matmul_fused(
     # and so is any output axis that shares one scale (per-head weights
     # quantize per head_dim channel) — broadcast to the per-column vector.
     ws2 = jnp.broadcast_to(
-        w_scale.astype(jnp.float32), (1,) * (wq.ndim - len(b_rest)) + b_rest
+        w_scale.astype(jnp.float32), (1,) * (len(w_shape) - len(b_rest)) + b_rest
     ).reshape(1, N)
-    a_spec, b_spec = _specs(oa, ob, bm, bn, bc)
     out = _tiled_call(
         "int8_matmul",
         functools.partial(_int8_kernel, dims=_dot_dims(oa, ob)),
         plan,
         [
             a_spec,
-            pl.BlockSpec((bm, 1), lambda i, j, c: (i, 0)),
-            b_spec,
-            pl.BlockSpec((1, bn), lambda i, j, c: (0, j)),
+            pl.BlockSpec((bm, 1), lambda i, j, c, ly: (i, 0)),
+            w_spec,
+            pl.BlockSpec((1, bn), lambda i, j, c, ly: (0, j)),
         ],
         x.dtype,
         jnp.int32,
         interpret,
-    )(x2, sx, w2, ws2)
+        n_prefetch=1,
+    )(layer, x2, sx, w_view, ws2)
     return out.reshape(a_rest + b_rest)
 
 
@@ -307,13 +356,18 @@ def scaled_matmul(
 
 
 def maybe_int8_matmul(
-    eq: str, x: jax.Array, wq: jax.Array, w_scale: jax.Array
+    eq: str,
+    x: jax.Array,
+    wq: jax.Array,
+    w_scale: jax.Array,
+    layer: jax.Array | int | None = None,
 ) -> jax.Array | None:
-    """Dispatch entry for `ops.int8.int8_einsum`."""
+    """Dispatch entry for `ops.int8.int8_einsum` (``layer``: ``wq`` is a
+    layer stack, read in place there)."""
     mode = kernel_mode("int8_matmul")
     if mode is None:
         return None
-    return int8_matmul_fused(eq, x, wq, w_scale, interpret=mode == "interpret")
+    return int8_matmul_fused(eq, x, wq, w_scale, layer, interpret=mode == "interpret")
 
 
 def maybe_scaled_matmul(

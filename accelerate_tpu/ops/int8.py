@@ -25,6 +25,12 @@ weights through :func:`int8_einsum_quantized` instead of dequantizing.
 Packed int4 weights unpack to int8 values first (elementwise) and then
 take the same int8 MXU contraction.
 
+A model that scans over layer-stacked weights keeps the int8 value stacks
+out of the scan's ``xs`` (:func:`hoist_layer_stacks`) and hands each
+contraction the whole stack with the layer index (:func:`at_layer`): the
+`int8_matmul` kernel reads the layer where it lies. Sliced by the scan, a
+layer's matrix is copied out of the stack before the kernel reads the copy.
+
 Inference-only by design: the backward of an int8 contraction would need
 requantized gradients; training stays on the bf16/fp8 paths.
 """
@@ -32,13 +38,17 @@ requantized gradients; training stays on the bf16/fp8 paths.
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import functools
 import threading
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 
 _MODE = threading.local()
+# Key of a quantized node whose values are a layer stack: the layer to read.
+_LAYER_KEY = "__layer__"
 
 
 def int8_compute_enabled() -> bool:
@@ -152,8 +162,81 @@ def _x_scale_to_out(eq: str, x_scale: jax.Array) -> jax.Array:
     return squeezed.reshape(shape)
 
 
+_WEIGHT_PATHS: contextvars.ContextVar[list[str] | None] = contextvars.ContextVar(
+    "atx_int8_weight_paths", default=None
+)
+
+
+@contextlib.contextmanager
+def record_weight_paths():
+    """Collect how every quantized contraction traced inside the block got
+    its weights: ``"in_place"`` (the kernel read the layer out of the stack
+    where it lies) or ``"sliced"`` (it, or the XLA fallback, was handed one
+    layer's matrix). Trace-time bookkeeping only, as
+    `models.layers.record_attention_paths`: the serving engine wraps its
+    programs' traces in it, so a silent fall to the slice shows in
+    ``Engine.stats['weights_sliced']``."""
+    paths: list[str] = []
+    token = _WEIGHT_PATHS.set(paths)
+    try:
+        yield paths
+    finally:
+        _WEIGHT_PATHS.reset(token)
+
+
+def hoist_layer_stacks(blocks: Any) -> tuple[Any, dict]:
+    """Split layer-stacked blocks for a scan whose body contracts through
+    `matmul_einsum`: ``(xs, stacks)``. Where the `int8_matmul` kernel will
+    run, ``xs`` is ``blocks`` without the int8 value stacks (scales, norms
+    and biases stay: kilobytes a layer) and ``stacks`` holds them by path,
+    for `at_layer` to hand back whole inside the body. Everywhere else
+    ``stacks`` is empty and the scan slices as before: outside
+    `int8_compute`, with the kernel off (XLA fuses the slice into its own
+    dot), for packed int4 (unpacked elementwise first: a whole stack would
+    be), and under a mesh of several devices (this runtime does not
+    partition a Pallas call: it would gather the stack)."""
+    from ..native.pallas.dispatch import kernel_mode
+    from ..parallel.mesh import ambient_mesh
+    from ..utils.quantization import _QUANT_KEY, is_quantized
+
+    if not int8_compute_enabled() or kernel_mode("int8_matmul") is None:
+        return blocks, {}
+    mesh = ambient_mesh()
+    if mesh is not None and mesh.axis_names and mesh.size > 1:
+        return blocks, {}
+    stacks: dict = {}
+
+    def visit(path, node):
+        if not (is_quantized(node) and _QUANT_KEY in node):
+            return node
+        stacks[path] = node[_QUANT_KEY]
+        return {**node, _QUANT_KEY: None}
+
+    return jax.tree_util.tree_map_with_path(visit, blocks, is_leaf=is_quantized), stacks
+
+
+def at_layer(block: Any, stacks: dict, layer: jax.Array) -> Any:
+    """One scan step's ``block`` with the stacks `hoist_layer_stacks` kept
+    back, whole, and the step's ``layer`` index beside each."""
+    from ..utils.quantization import _QUANT_KEY, is_quantized
+
+    if not stacks:
+        return block
+
+    def visit(path, node):
+        if path not in stacks:
+            return node
+        return {**node, _QUANT_KEY: stacks[path], _LAYER_KEY: layer}
+
+    return jax.tree_util.tree_map_with_path(visit, block, is_leaf=is_quantized)
+
+
 def int8_einsum(
-    eq: str, x: jax.Array, wq: jax.Array, w_scale: jax.Array
+    eq: str,
+    x: jax.Array,
+    wq: jax.Array,
+    w_scale: jax.Array,
+    layer: jax.Array | None = None,
 ) -> jax.Array:
     """``einsum(eq, x, dequant(wq))`` computed as int8×int8→int32 on the
     MXU: dynamic per-token activation quantization, int32 accumulation,
@@ -162,10 +245,23 @@ def int8_einsum(
     When the `int8_matmul` Pallas kernel is enabled (`native/pallas/`),
     the quantize -> dot -> rescale runs as one fused kernel — integer
     accumulation exact, parity within 1 ulp of the activation scale —
-    without the intermediate HBM round-trips."""
+    without the intermediate HBM round-trips. With ``layer``, ``wq`` is a
+    layer stack ``(L, ...)`` that the kernel reads in place at that index
+    (``w_scale`` is the layer's own); where it cannot (kernel off, or the
+    kernel's 2D view of this stack would be a relayout), the layer is
+    sliced out here and everything goes on as for a lone matrix."""
     from ..native.pallas.quant_matmul import maybe_int8_matmul
 
-    out = maybe_int8_matmul(eq, x, wq, w_scale)
+    out = None
+    if layer is not None:
+        out = maybe_int8_matmul(eq, x, wq, w_scale, layer)
+        if out is None:
+            wq = jax.lax.dynamic_index_in_dim(wq, layer, 0, keepdims=False)
+    paths = _WEIGHT_PATHS.get()
+    if paths is not None:
+        paths.append("sliced" if out is None else "in_place")
+    if out is None:
+        out = maybe_int8_matmul(eq, x, wq, w_scale)
     if out is not None:
         return out
     qx, sx = quantize_act(x, _x_contracted_axes(eq))
@@ -182,4 +278,4 @@ def int8_einsum_quantized(eq: str, x: jax.Array, wnode: dict) -> jax.Array:
 
     if _QUANT4_KEY in wnode:
         return int8_einsum(eq, x, _unpack_int4(wnode[_QUANT4_KEY]), wnode["scale"])
-    return int8_einsum(eq, x, wnode[_QUANT_KEY], wnode["scale"])
+    return int8_einsum(eq, x, wnode[_QUANT_KEY], wnode["scale"], wnode.get(_LAYER_KEY))

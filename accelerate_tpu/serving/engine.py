@@ -61,6 +61,7 @@ from ..models.layers import (
     record_attention_paths,
     record_step_counts,
 )
+from ..ops.int8 import record_weight_paths
 from ..ops.moe import MOE_COUNTS
 from ..utils.environment import (
     get_int_from_env,
@@ -196,6 +197,10 @@ class _Slot:
         self.occ_n = 0
 
 
+def _in_place_and_sliced(paths: list[str]) -> tuple[int, int]:
+    return paths.count("in_place"), paths.count("sliced")
+
+
 class Engine:
     """Continuous-batching engine over a family cached forward.
 
@@ -318,12 +323,18 @@ class Engine:
             when enabled (``ATX_KERNELS`` / ``ATX_KERNEL_DECODE_ATTN``,
             read at trace time): split-K over the slot KV cache, masked by
             each row's length cursor, with int8 KV dequantized in-kernel."""
-            with record_attention_paths() as paths, record_step_counts() as counts:
+            with (
+                record_attention_paths() as paths,
+                record_step_counts() as counts,
+                record_weight_paths() as weights,
+            ):
                 logits, new = apply_fn(params, tokens[:, None], dict(kv, length=lengths))
-            # Trace time: which attention lowering this program compiled to.
+            # Trace time: which attention lowering this program compiled to,
+            # and how its quantized contractions get their weights.
             self.stats["decode_in_place"] = int(
                 bool(paths) and all(p == "in_place" for p in paths)
             )
+            self._weight_paths["decode"] = _in_place_and_sliced(weights)
             nxt = jax.vmap(_sample)(logits[:, -1, :], seeds, steps)
             # What the forward counted (an expert layer's routing; nothing
             # for a dense model) leaves with the tokens: one fetch a step.
@@ -340,12 +351,18 @@ class Engine:
             if self._ring_len:
                 # In a ring the pad tail would land on rows still in the window.
                 cache["valid"] = sample_pos + 1
-            logits, new = apply_fn(params, tokens, cache)
+            with record_weight_paths() as weights:
+                logits, new = apply_fn(params, tokens, cache)
+            self._weight_paths[tokens.shape[1]] = _in_place_and_sliced(weights)
             kv = cache_slot_write(kv, {k: new[k] for k in row}, slot)
             last = jnp.take_along_axis(logits[0], sample_pos[None, None], axis=0)[0]
             tok = _sample(last, seed, jnp.zeros((), jnp.int32))
             return tok, kv
 
+        # Per program ("decode", or a prefill bucket's rows), as traced: how
+        # many of its quantized contractions read their weight stack in
+        # place, and how many were handed a slice.
+        self._weight_paths: dict[Any, tuple[int, int]] = {}
         self._decode_fn = decode_fn
         self._prefill_fn = prefill_fn
         self._decode = jax.jit(decode_fn, donate_argnums=(3,))
@@ -445,6 +462,12 @@ class Engine:
                 "prefix_promotions",
                 "cancelled",
                 "decode_in_place",
+                # Quantized contractions of the programs dispatched (decode
+                # steps and prefill chunks) whose int8 weights the kernel
+                # read out of the layer stack where it lies, and those handed
+                # one layer's matrix sliced out of it (a copy a layer).
+                "weights_in_place",
+                "weights_sliced",
                 "prefix_cache_off_for_ring",
                 # Rows of KV a decode step had to read, summed over the
                 # decoding slots and the steps: per full-length layer, and per
@@ -838,6 +861,7 @@ class Engine:
             )
             slot.cursor += real
             self.stats["prefill_chunks"] += 1
+            self._count_weight_paths(buf.shape[1])
             self.prefill_signatures.append(buf.shape[1])
             if self._trace:
                 _flight.record_span(
@@ -942,6 +966,7 @@ class Engine:
                     self.params, tokens, lengths.copy(), self._kv, seeds, steps.copy()
                 )
                 fetched.append((tokens, counts))
+                self._count_weight_paths("decode")
                 lengths[decoding] += 1
                 steps[decoding] += 1
                 live = int(lengths[decoding].sum())  # cursor + 1 of each decoding slot
@@ -967,6 +992,12 @@ class Engine:
                     slot.cursor += 1
                     out.extend(self._emit(i, int(nxt[i])))
         return out
+
+    def _count_weight_paths(self, program: Any) -> None:
+        """One dispatch of ``program``: add what its trace recorded."""
+        in_place, sliced = self._weight_paths.get(program, (0, 0))
+        self.stats["weights_in_place"] += in_place
+        self.stats["weights_sliced"] += sliced
 
     def _emit(self, slot_id: int, tok: int) -> list[Completion]:
         """Record one generated token for a slot; finish/evict on EOS, a

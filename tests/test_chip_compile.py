@@ -83,6 +83,10 @@ def _int8_matmul(x, w, s):
     return quant_matmul.int8_matmul_fused("mc,cn->mn", x, w, s, interpret=False)
 
 
+def _int8_matmul_stack(x, w, s, layer):
+    return quant_matmul.int8_matmul_fused("mc,cn->mn", x, w, s, layer, interpret=False)
+
+
 def _fp8_matmul(a, b, s):
     return quant_matmul.scaled_matmul("mc,cn->mn", a, b, s, BF16, interpret=False)
 
@@ -134,6 +138,14 @@ KERNELS = {
     # (14336) staged per block was 43 MB of VMEM against a 16 MB limit.
     "int8_matmul_prefill": (_int8_matmul, [((2048, FF), BF16), ((FF, D), I8), ((1, D), F32)], ["int8_matmul"]),
     "int8_matmul_decode": (_int8_matmul, [((SLOTS, D), BF16), ((D, FF), I8), ((1, FF), F32)], ["int8_matmul"]),
+    # A layer stack read in place at a traced layer: the chat cell's 32 decode
+    # rows (one row tile: 3.7 MB weight tiles) and a 1024-row chunk.
+    "int8_matmul_stack_decode": (
+        _int8_matmul_stack, [((32, D), BF16), ((32, D, FF), I8), ((1, FF), F32), ((), I32)], ["int8_matmul"],
+    ),
+    "int8_matmul_stack_prefill": (
+        _int8_matmul_stack, [((1024, D), BF16), ((32, D, FF), I8), ((1, FF), F32), ((), I32)], ["int8_matmul"],
+    ),
     "fp8_scaled_matmul": (_fp8_matmul, [((2048, D), F8), ((D, FF), F8), ((), F32)], ["scaled_matmul"]),
     "fused_adamw_leaf": (_adamw, [_LEAF, _LEAF, _LEAF, _LEAF, ((), I32), ((), F32)], ["fused_adamw"]),
     # Three 3.9 MB weight blocks an expert, double-buffered: past Mosaic's
@@ -289,6 +301,78 @@ def test_smallthinker_decode_reads_cache_and_experts_in_place_on_v5e(v5e, monkey
     cache_bytes = 2 * 2 * slots * lanes * (max_len + 3 * cfg.sliding_window)
     assert memory.alias_size_in_bytes >= cache_bytes  # every leaf stays where it was donated
     assert memory.temp_size_in_bytes < ST_E * ST_D * ST_F * 2  # under one matrix of a layer's experts
+
+
+def _s8_results(text, dims):
+    """(shape, opcode) of every instruction of a compiled module whose result
+    is an int8 array of ``dims`` (a regular expression on ``a,b,c``), other
+    than the ones that move nothing: parameters, tuple elements, bitcasts."""
+    found = re.findall(r"= s8\[([0-9,]+)\]\S* ([a-z][a-z\-]*)\(", text)
+    still = ("parameter", "get-tuple-element", "bitcast")
+    return [(shape, op) for shape, op in found if re.search(dims, shape) and op not in still]
+
+
+@pytest.mark.parametrize("program", ["decode", 256])
+def test_engine_reads_int8_weight_stacks_in_place_on_v5e(v5e, monkeypatch, program):
+    """The int8 engine's decode step and 256-row prefill chunk at the Mistral
+    widths (eight layers: at four XLA moves the whole `wo` stack, 67 MB, into its
+    fast memory before the loop), compiled for the described chip: `int8_matmul` reads
+    gate, up, down and `wo` out of their layer stacks where they lie, so no
+    instruction's result is one layer's matrix of those (58.7 MB each, a copy
+    a layer a step) nor a whole stack; `wq`, `wk`, `wv` are sliced, their
+    (d, h * k) view being a relayout. Handed what the scan slices, the same
+    check finds the copies: it has teeth."""
+    from accelerate_tpu import serving
+    from accelerate_tpu.generation import GenerationConfig
+    from accelerate_tpu.native.pallas import dispatch
+    from accelerate_tpu.ops.int8 import with_int8_compute
+    from accelerate_tpu.utils.quantization import quantize_pytree
+
+    monkeypatch.setattr(dispatch, "_on_tpu", lambda: True)
+    monkeypatch.setenv("ATX_SERVE_CAPACITY_CHECK", "off")
+    monkeypatch.setattr(serving.engine.jax, "device_put", lambda x, device=None: x)  # shapes only
+    cfg = llama.LlamaConfig(
+        vocab_size=512, d_model=D, n_layers=8, num_heads=H, num_kv_heads=K, head_dim=HD,
+        d_ff=FF, max_seq_len=_CHAT_LEN,
+    )
+    params = jax.eval_shape(
+        lambda: quantize_pytree(llama.init(jax.random.PRNGKey(0), cfg, BF16))
+    )
+    one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree
+    )
+
+    def compile_program():
+        engine = serving.Engine(
+            with_int8_compute(lambda p, t, c: llama.forward_with_cache(p, t, c, cfg)),
+            lambda b, m: jax.eval_shape(lambda: llama.init_cache(cfg, b, m)),
+            params, GenerationConfig(), slots=_CHAT_SLOTS, buckets=(256,), max_len=_CHAT_LEN,
+            prefix_cache=False,
+        )
+        decode_args = on_chip(engine.abstract_decode_args())
+        with force_kernels("on"):
+            if program == "decode":
+                fn, args, donate = engine._decode_fn, decode_args, 3
+            else:
+                scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=one_chip)
+                chunk = jax.ShapeDtypeStruct((1, program), I32, sharding=one_chip)
+                args = (decode_args[0], chunk, decode_args[3], *map(scalar, (I32, I32, I32, jnp.uint32)))
+                fn, donate = engine._prefill_fn, 2
+            text = jax.jit(fn, donate_argnums=(donate,)).lower(*args).compile().as_text()
+        return text, engine._weight_paths[program]
+
+    one_matrix = rf"^(1,)?({D},{FF}|{FF},{D}|{H},{HD},{D})$"
+    text, paths = compile_program()
+    assert paths == (4, 3)  # in place, sliced
+    assert len(set(re.findall(r"%(int8_matmul[.\d]*) = ", text))) == 7
+    assert _s8_results(text, one_matrix) == []
+    assert _s8_results(text, "^8,") == []  # no stack is copied into the loop
+    assert len(_s8_results(text, rf"^{D},{D}$")) == 1  # wq: sliced and laid out anew
+    monkeypatch.setattr(llama, "hoist_layer_stacks", lambda blocks: (blocks, {}))
+    text, paths = compile_program()
+    assert paths == (0, 7)
+    assert len(_s8_results(text, one_matrix)) >= 4
 
 
 def test_every_pallas_call_in_the_package_is_named():

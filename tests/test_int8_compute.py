@@ -320,3 +320,164 @@ def test_int8_kernel_takes_per_head_weights():
         out = int8_ops.int8_einsum_quantized("bsd,dhk->bshk", x, node)
     assert out.shape == (2, 8, 4, 16)
     np.testing.assert_array_equal(np.asarray(out, np.float32), np.asarray(ref, np.float32))
+
+
+# ------------------------------------------------ layer stacks read in place
+# A decoder layer's seven quantized contractions: (equation, activation
+# shape, one layer's weight shape, the path `int8_einsum` takes for a stack).
+LAYER_CONTRACTIONS = {
+    "wq": ("bsd,dhk->bshk", (2, 8, 64), (64, 4, 32), "sliced"),
+    "wk": ("bsd,dhk->bshk", (2, 8, 64), (64, 2, 32), "sliced"),
+    "wv": ("bsd,dhk->bshk", (2, 8, 64), (64, 2, 32), "sliced"),
+    "wo": ("bshk,hkd->bsd", (2, 8, 4, 32), (4, 32, 64), "in_place"),
+    "w_gate": ("bsd,df->bsf", (2, 8, 64), (64, 128), "in_place"),
+    "w_up": ("bsd,df->bsf", (2, 8, 64), (64, 128), "in_place"),
+    "w_down": ("bsf,fd->bsd", (2, 8, 128), (128, 64), "in_place"),
+}
+
+
+@pytest.mark.parametrize("name", list(LAYER_CONTRACTIONS))
+def test_int8_einsum_of_a_stack_at_a_traced_layer_equals_the_sliced_call(name):
+    """`int8_einsum_quantized` on a node that carries the whole stack and the
+    scan's layer counter, against the node the scan would have sliced out:
+    bitwise, whichever path the stack takes (the kernel reads it in place
+    where its 2D view is the same bytes, else the layer is sliced here)."""
+    from accelerate_tpu.native.pallas.dispatch import force_kernels
+    from accelerate_tpu.ops.int8 import _LAYER_KEY, record_weight_paths
+
+    eq, x_shape, w_shape, path = LAYER_CONTRACTIONS[name]
+    layers = 3
+    x = jax.random.normal(jax.random.PRNGKey(0), x_shape, jnp.bfloat16)
+    node = quantize_array(jax.random.normal(jax.random.PRNGKey(1), (layers,) + w_shape))
+
+    @jax.jit
+    def scanned(stack, scales):
+        def body(i, scale):
+            layer = {"__quant__": stack, "scale": scale, _LAYER_KEY: i}
+            return i + 1, int8_einsum_quantized(eq, x, layer)
+
+        return jax.lax.scan(body, jnp.zeros((), jnp.int32), scales)[1]
+
+    with force_kernels("interpret"), record_weight_paths() as paths:
+        got = scanned(node["__quant__"], node["scale"])
+        assert paths == [path]
+        for i in range(layers):
+            one = {"__quant__": node["__quant__"][i], "scale": node["scale"][i]}
+            want = int8_einsum_quantized(eq, x, one)
+            np.testing.assert_array_equal(
+                np.asarray(got[i], np.float32), np.asarray(want, np.float32)
+            )
+        assert paths == [path] + ["sliced"] * layers  # a lone matrix was handed over sliced
+
+
+class TestLlamaReadsItsStacksInPlace:
+    """`llama.forward_with_cache` under `int8_compute`: the int8 value stacks
+    leave the scan's xs and reach the kernel whole, with the layer counter."""
+
+    @staticmethod
+    def _model(bits=8):
+        from accelerate_tpu.models import llama
+        from accelerate_tpu.utils.quantization import quantize_pytree
+
+        cfg = llama.LlamaConfig.tiny(vocab_size=64, head_dim=32, n_layers=3)
+        params = quantize_pytree(llama.init(jax.random.PRNGKey(0), cfg), min_size=512, bits=bits)
+        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 6), 0, 64, jnp.int32)
+        return llama, cfg, params, toks
+
+    @staticmethod
+    def _prefill_and_decode(llama, cfg, params, toks):
+        """Logits of a 5-token prefill and a decode step, and the paths each
+        program's seven contractions recorded."""
+        from accelerate_tpu.ops.int8 import record_weight_paths, with_int8_compute
+
+        # A new function object: the trace cache must not hand back a program
+        # traced under another kernel mode.
+        step = jax.jit(with_int8_compute(lambda p, t, c: llama.forward_with_cache(p, t, c, cfg)))
+        with record_weight_paths() as paths:
+            first, cache = step(params, toks[:, :5], llama.init_cache(cfg, 2, 16))
+            second, _ = step(params, toks[:, 5:], cache)
+        return np.asarray(first, np.float32), np.asarray(second, np.float32), paths
+
+    def test_logits_equal_the_sliced_program_bitwise(self, monkeypatch):
+        from accelerate_tpu.native.pallas.dispatch import force_kernels
+
+        llama, *model = self._model()
+        with force_kernels("interpret"):
+            first, second, paths = self._prefill_and_decode(llama, *model)
+            assert paths == (["sliced"] * 3 + ["in_place"] * 4) * 2
+            # The same kernel, handed what the scan slices out of its xs.
+            monkeypatch.setattr(llama, "hoist_layer_stacks", lambda blocks: (blocks, {}))
+            first_sliced, second_sliced, paths = self._prefill_and_decode(llama, *model)
+            assert paths == ["sliced"] * 14
+        np.testing.assert_array_equal(first, first_sliced)
+        np.testing.assert_array_equal(second, second_sliced)
+        monkeypatch.undo()
+        # The XLA fallback divides by the activation scale in IEEE; the
+        # kernel's divide is 1 ulp off, on top of bf16 rounding.
+        with force_kernels("off", "int8_matmul"):
+            first_off, second_off, paths = self._prefill_and_decode(llama, *model)
+        assert paths == ["sliced"] * 14
+        np.testing.assert_allclose(first, first_off, rtol=1e-2, atol=1e-2)
+        np.testing.assert_allclose(second, second_off, rtol=1e-2, atol=1e-2)
+
+    def test_packed_int4_keeps_the_slice(self):
+        """Unpacking is elementwise before the contraction: of a whole stack
+        it would be every step's. The scan slices the packed layer."""
+        from accelerate_tpu.native.pallas.dispatch import force_kernels
+        from accelerate_tpu.ops.int8 import hoist_layer_stacks
+        from accelerate_tpu.utils.quantization import has_quantized
+
+        llama, cfg, params, toks = self._model(bits=4)
+        assert "__quant4__" in params["blocks"]["mlp"]["w_gate"]
+        with force_kernels("interpret"):
+            with int8_compute():
+                blocks, stacks = hoist_layer_stacks(params["blocks"])
+            assert stacks == {} and has_quantized(blocks)
+            assert blocks["mlp"]["w_gate"]["__quant4__"] is params["blocks"]["mlp"]["w_gate"]["__quant4__"]
+            *_, paths = self._prefill_and_decode(llama, cfg, params, toks)
+        assert paths == ["sliced"] * 14
+
+    def test_a_mesh_of_several_devices_keeps_the_slice(self):
+        """A Pallas call is not partitioned by this runtime: handed a stack
+        sharded over `tensor` it would gather it. Under a mesh of one device
+        (or none) the stacks are hoisted."""
+        from jax.sharding import Mesh
+
+        from accelerate_tpu.native.pallas.dispatch import force_kernels
+        from accelerate_tpu.ops.int8 import hoist_layer_stacks
+
+        llama, cfg, params, toks = self._model()
+        hoisted = lambda: hoist_layer_stacks(params["blocks"])[1]
+        with force_kernels("interpret"), int8_compute():
+            assert len(hoisted()) == 7
+            with jax.sharding.set_mesh(Mesh(np.array(jax.devices()[:1]), ("tensor",))):
+                assert len(hoisted()) == 7
+            with jax.sharding.set_mesh(Mesh(np.array(jax.devices()[:2]), ("tensor",))):
+                assert hoisted() == {}
+                *_, paths = self._prefill_and_decode(llama, cfg, params, toks)
+        assert paths == ["sliced"] * 14
+
+    def test_outside_int8_compute_or_with_the_kernel_off_nothing_is_hoisted(self):
+        from accelerate_tpu.native.pallas.dispatch import force_kernels
+        from accelerate_tpu.ops.int8 import hoist_layer_stacks
+
+        llama, cfg, params, toks = self._model()
+        with force_kernels("interpret"):
+            assert hoist_layer_stacks(params["blocks"])[1] == {}  # dequantize-first path
+        with force_kernels("off", "int8_matmul"), int8_compute():
+            assert hoist_layer_stacks(params["blocks"])[1] == {}  # XLA fuses its own slice
+
+    def test_streamed_blocks_are_lone_matrices(self):
+        """`forward_with_cache_offloaded` stages one layer's block at a time
+        from the host: a 2D weight with no layer axis, the kernel as it was."""
+        from accelerate_tpu.native.pallas.dispatch import force_kernels
+        from accelerate_tpu.ops.int8 import record_weight_paths
+
+        llama, cfg, params, toks = self._model()
+        host = dict(params, blocks=jax.tree.map(np.asarray, params["blocks"]))
+        with force_kernels("interpret"), int8_compute(), record_weight_paths() as paths:
+            logits, _ = llama.forward_with_cache_offloaded(
+                host, toks, llama.init_cache(cfg, 2, 16), cfg
+            )
+        assert paths == ["sliced"] * 7  # one jitted layer step, traced once
+        assert logits.shape == (2, 6, 64) and bool(jnp.isfinite(logits).all())

@@ -331,6 +331,72 @@ class TestQuantMatmul:
             rtol=1e-2, atol=1e-2,  # bf16 output rounding on top of the 1 ulp
         )
 
+    # The seven quantized contractions of a decoder layer, as a small layer
+    # stack: (equation, activation shape, one layer's weight shape, does the
+    # kernel take the stack in place). Per-head weights (d, h, k) share one
+    # scale a head_dim channel; as (d, h * k) they are a relayout, so the
+    # kernel declines their stack; (h, k, d) is (h * k, d) as it lies.
+    STACKED = {
+        "wq": ("bsd,dhk->bshk", (2, 8, 64), (64, 4, 32), False),
+        "wk": ("bsd,dhk->bshk", (2, 8, 64), (64, 2, 32), False),
+        "wv": ("bsd,dhk->bshk", (2, 8, 64), (64, 2, 32), False),
+        "wo": ("bshk,hkd->bsd", (2, 8, 4, 32), (4, 32, 64), True),
+        "w_gate": ("bsd,df->bsf", (2, 8, 64), (64, 128), True),
+        "w_up": ("bsd,df->bsf", (2, 8, 64), (64, 128), True),
+        "w_down": ("bsf,fd->bsd", (2, 8, 128), (128, 64), True),
+    }
+
+    @pytest.mark.parametrize("name", list(STACKED))
+    def test_int8_kernel_reads_a_layer_stack_in_place(self, name):
+        """The kernel handed the whole stack and a traced layer index inside
+        `lax.scan` against the same kernel handed each layer sliced out:
+        bitwise, per-head scales included."""
+        from accelerate_tpu.utils.quantization import quantize_array
+
+        eq, x_shape, w_shape, in_place = self.STACKED[name]
+        layers = 3
+        x = jax.random.normal(jax.random.PRNGKey(0), x_shape, jnp.bfloat16)
+        node = quantize_array(jax.random.normal(jax.random.PRNGKey(1), (layers,) + w_shape))
+        stack, scales = node["__quant__"], node["scale"]
+        assert scales.shape[0] == layers and scales.shape[-1] == w_shape[-1]
+
+        def whole_stack(i, scale):
+            return quant_matmul.int8_matmul_fused(eq, x, stack, scale, i, interpret=True)
+
+        if not in_place:
+            assert whole_stack(jnp.int32(1), scales[1]) is None
+            return
+
+        @jax.jit
+        def scanned(stack, scales):
+            body = lambda i, scale: (i + 1, whole_stack(i, scale))
+            return jax.lax.scan(body, jnp.zeros((), jnp.int32), scales)[1]
+
+        got = scanned(stack, scales)
+        for i in range(layers):
+            want = quant_matmul.int8_matmul_fused(eq, x, stack[i], scales[i], interpret=True)
+            np.testing.assert_array_equal(
+                np.asarray(got[i], np.float32), np.asarray(want, np.float32)
+            )
+        assert not np.array_equal(np.asarray(got[0], np.float32), np.asarray(got[1], np.float32))
+
+    def test_one_row_tile_takes_weight_tiles_up_to_the_budget(self):
+        """A call whose rows are one tile reads every weight byte once, from
+        HBM: its weight tile is its DMA and is sized from the shapes up to
+        the budget. Two row tiles (a 1024-row chunk) keep `pick_block`'s."""
+        w = jax.ShapeDtypeStruct((4096, 14336), jnp.int8)
+        plan = lambda rows: quant_matmul._plan(
+            "mc,cn->mn", jax.ShapeDtypeStruct((rows, 4096), jnp.bfloat16), w, jnp.bfloat16
+        )
+        assert plan(32)[5:8] == (32, 3584, 1024)
+        assert plan(256)[5:8] == (256, 2048, 1024)
+        assert plan(1024)[5:8] == (512, 512, 1024)
+        down = quant_matmul._plan(
+            "mc,cn->mn", jax.ShapeDtypeStruct((32, 14336), jnp.bfloat16),
+            jax.ShapeDtypeStruct((14336, 4096), jnp.int8), jnp.bfloat16,
+        )
+        assert down[5:8] == (32, 4096, 1024)
+
     def test_scaled_matmul_matches_reference_all_orientations(self):
         f8 = jnp.float8_e4m3fn
         for eq, ashape, bshape in (

@@ -278,6 +278,40 @@ class TestCompileDiscipline:
         ]
         assert value == in_place
 
+    @pytest.mark.parametrize(
+        "weights, mode, in_place, sliced",
+        [("int8", "interpret", 4, 3), ("int8", "off", 0, 7), ("bf16", "interpret", 0, 0)],
+    )
+    def test_stats_count_how_programs_get_their_int8_weights(self, weights, mode, in_place, sliced):
+        """Every dispatch of a decode step or a prefill chunk adds how many of
+        its quantized contractions read the layer stack in place and how many
+        were handed a slice, as its trace recorded them: a silent fall to the
+        slice path shows among the counters."""
+        from accelerate_tpu.native.pallas.dispatch import force_kernels
+        from accelerate_tpu.ops.int8 import with_int8_compute
+        from accelerate_tpu.utils.quantization import quantize_pytree
+
+        cfg = llama.LlamaConfig.tiny(vocab_size=61, max_seq_len=64, head_dim=32)
+        params = llama.init(jax.random.PRNGKey(1), cfg)
+        if weights == "int8":
+            params = quantize_pytree(params, min_size=512)
+        apply_fn = with_int8_compute(lambda p, t, c: llama.forward_with_cache(p, t, c, cfg))
+        eng = serving.Engine(
+            apply_fn, lambda b, m: llama.init_cache(cfg, b, m), params, GenerationConfig(),
+            slots=2, buckets=(8, 16), max_len=64,
+        )
+        requests = [
+            serving.Request(prompt=np.arange(1, 1 + n, dtype=np.int32), max_new_tokens=4)
+            for n in (11, 20)
+        ]
+        with force_kernels(mode):
+            done = eng.serve(requests)
+        dispatched = eng.stats["decode_steps"] + eng.stats["prefill_chunks"]
+        assert eng.stats["prefill_chunks"] == 3 and eng.stats["decode_steps"] >= 3
+        assert eng.stats["weights_in_place"] == in_place * dispatched
+        assert eng.stats["weights_sliced"] == sliced * dispatched
+        assert [len(d.tokens) for d in done] == [4, 4]
+
 
 class TestPoissonSmoke:
     def test_poisson_16_requests_all_complete_and_match_solo(self, params):
