@@ -52,7 +52,7 @@ lint-graph:
 		python -m accelerate_tpu.commands.cli lint examples --severity error
 
 # Static performance lint + budget ratchet (ATX6xx, docs/performance.md
-# "perf campaign"): the example train steps plus the bench-scale llama2b
+# "perf campaign"): the example train steps plus the 1.64B llama2b
 # config are compiled abstractly, the roofline rules run at error
 # severity, and the ATX601 series (static MFU bound, exposed-comms bytes,
 # padding-waste fraction) are checked against the committed
@@ -174,7 +174,7 @@ smoke-chaos:
 		--severity error
 
 # CPU tracing lane (docs/observability.md, "Request tracing & the flight
-# recorder"): flight-recorder ring / postmortem-bundle / bench --compare
+# recorder"): flight-recorder ring / postmortem-bundle
 # unit tests incl. the exactly-once-through-failover and SystemExit-flush
 # subprocess gates, a 16-request Poisson trace served twice proving
 # ATX_TRACE_REQUESTS=1 is bit-identical to =0 with `atx trace --check

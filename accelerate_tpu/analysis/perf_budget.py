@@ -6,7 +6,7 @@ fraction from the ATX601 roofline, the peak-HBM figure from the ATX701
 memory timeline, and the serving planner's static max-slots from ATX706 —
 and `atx lint perf|memory --budgets perf/budgets.json` (the `make
 lint-perf` / `make lint-memory` lanes) fails when any of them regresses
-past tolerance: the static twin of `bench.py --compare`. A PR that
+past tolerance. A PR that
 improves a series re-baselines it with `--write-budgets`, so the budget
 only moves in the good direction deliberately — a ratchet.
 

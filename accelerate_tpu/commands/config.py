@@ -148,8 +148,9 @@ def interactive_config() -> LaunchConfig:
         print(
             "  NOTE: fp8 only pays off on chips with native fp8 MXU support; "
             "on other hardware (e.g. TPU v5e) XLA upcasts the fp8 values — "
-            "you keep the quantization error and get NO speedup. Check "
-            "`bench.py`'s fp8_matmul_speedup field on your chip first."
+            "you keep the quantization error and get NO speedup. See the "
+            "table in accelerate_tpu/utils/fp8_telemetry.py and "
+            "docs/performance.md (fp8) first."
         )
         cfg.force_fp8 = (
             _ask(

@@ -567,8 +567,8 @@ def run(args: argparse.Namespace) -> int:
         print(
             "[accelerate-tpu launch] fp8 selected: only beneficial on chips "
             "with native fp8 MXU support; elsewhere XLA upcasts the values — "
-            "quantization error with no speedup (see bench.py "
-            "fp8_matmul_speedup).",
+            "quantization error with no speedup (see the table in "
+            "accelerate_tpu/utils/fp8_telemetry.py and docs/performance.md).",
             file=sys.stderr,
         )
         speedup = _fp8_speedup_for_local_devices()

@@ -182,7 +182,7 @@ def _scenario_lm_example(**options: Any):
 
 
 def _scenario_llama2b(**options: Any):
-    """llama 1.64B train step (the bench.py llama2b phase), linted fully
+    """llama 1.64B train step, linted fully
     abstractly: the real 24-layer seq-4096 config with remat +
     adafactor is traced/lowered/compiled with zero parameters
     materialized — the scenario the ATX601 roofline bounds for real
@@ -220,8 +220,8 @@ def _scenario_llama2b(**options: Any):
         attention_impl="dot",
         loss_chunk_size=512,
     )
-    # bench trains batch 2 on one chip; abstractly the batch axis must
-    # divide the 8 simulated devices the lint lanes force.
+    # Abstractly the batch axis must divide the 8 simulated devices the
+    # lint lanes force.
     batch = {"input_ids": np.zeros((8, 4096), np.int32)}
     report = analysis.lint_training(
         acc,
@@ -421,7 +421,7 @@ SCENARIOS: dict[str, Callable[..., tuple[str, Any]]] = {
 }
 
 # `atx lint perf`: the scenario set the ATX6xx budget ratchet covers
-# (`make lint-perf`) — the example train steps plus the bench-scale llama.
+# (`make lint-perf`) — the example train steps plus the 1.64B llama.
 PERF_SCENARIOS = ("nlp_example", "lm_example", "cv_example", "llama2b")
 
 # `atx lint memory`: the ATX7xx HBM-timeline set (`make lint-memory`) —

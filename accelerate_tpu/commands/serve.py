@@ -4,8 +4,7 @@ A benchmarking driver for `serving.Engine` (docs/serving.md): builds a
 model-zoo preset with random weights (or loads a local HF repo), replays a
 Poisson arrival trace of mixed-length requests through the engine, and
 prints one JSON line of serving metrics (`serve_tokens_per_sec`,
-`serve_p50_ms`, `serve_p99_ms`, occupancy) — the same fields bench.py's
-serve phase reports, runnable standalone on any host:
+`serve_p50_ms`, `serve_p99_ms`, occupancy), runnable standalone on any host:
 
     atx serve --model llama-tiny --slots 8 --requests 64 --rate 16
 

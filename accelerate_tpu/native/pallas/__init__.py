@@ -11,8 +11,8 @@ layer's experts out of the weight stack first).
 Every kernel sits behind the dispatch-by-availability registry in
 `dispatch.py`: TPU backend + pallas importable + shape/dtype supported →
 kernel; anything else → the exact current lowering, byte-identical to a
-build without this package. `ATX_KERNELS` / `ATX_KERNEL_<NAME>` force any
-kernel off, on, or into interpret mode (the CPU bit-parity test path).
+build without this package. `force_kernels(mode, name=None)` pins any kernel
+off, on, or into interpret mode (the CPU bit-parity test path).
 """
 
 from __future__ import annotations

@@ -15,9 +15,8 @@ No layer is sliced out of the stack and nothing is reshaped on the way in,
 so a decode step moves only the rows it attends to.
 
 The int8-KV variant dequantizes inside the kernel (``k * scale`` per cache
-block) — that is the bandwidth win the kv16k bench measures: the fallback
-lowering materializes the full bf16 dequant copy of a 16k-token cache before
-a single attention flop, this kernel reads the int8 bytes once.
+block): the fallback lowering materializes the full bf16 dequant copy of the
+cache before a single attention flop, this kernel reads the int8 bytes once.
 
 Parity vs `models.layers.dot_product_attention` is to tolerance, not bitwise:
 the oracle computes one full-row softmax, this kernel merges per-block

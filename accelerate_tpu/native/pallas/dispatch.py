@@ -2,27 +2,20 @@
 
 Every kernel in this package is OPTIONAL: the call site always carries the
 exact current XLA lowering as its fallback, and `kernel_mode(name)` decides
-per trace whether the Pallas kernel replaces it. The decision is:
+per trace whether the Pallas kernel replaces it. The code chooses from what
+it can observe: the compiled kernel iff the backend is a TPU and pallas
+imports, the fallback lowering everywhere else (so a CPU runs the XLA path
+untouched). `force_kernels(mode)` is the seam for tests, the chip gate's
+kernel-vs-fallback parity and the `perf/` comparisons:
 
-1. a programmatic override (`force_kernels(...)` — tests and the bench's
-   on/off comparison phases), else
-2. ``ATX_KERNEL_<NAME>`` (per-kernel env knob, e.g.
-   ``ATX_KERNEL_DECODE_ATTN=0``), else
-3. ``ATX_KERNELS`` (the global knob), else
-4. ``auto``.
+- ``"off"``        — never use the kernel (fallback lowering);
+- ``"on"``         — what the code picks by itself (compiled iff TPU);
+- ``"interpret"``  — the kernel in Pallas interpret mode (runs anywhere,
+  slowly): the CPU parity-test path.
 
-Knob values:
-
-- ``0`` / ``off`` / ``false``  — never use the kernel (fallback lowering);
-- ``1`` / ``on`` / ``auto``    — use the compiled kernel iff the backend is
-  TPU and pallas imports; otherwise fall back (so CPU CI and older jax
-  run the reference path untouched);
-- ``interpret``                — force the kernel in Pallas interpret mode
-  (runs anywhere, slowly) — the CPU bit-parity test path.
-
-Like the fp8/int8 modes (`ops/fp8.py`), the mode is read at TRACE time:
-jit caches traced inside different modes belong to different function
-objects or different traces; the bench phases re-trace per mode.
+Like the fp8/int8 modes (`ops/fp8.py`), the mode is read at TRACE time: a
+function jitted inside one mode keeps that mode's lowering, so a comparison
+of two modes traces the function once in each.
 
 Shape/dtype support is the CALL SITE's job — `kernel_mode` answers "may
 this kernel run", the kernel module's own `supported()` predicate answers
@@ -33,8 +26,6 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import os
-import re
 import threading
 from typing import Any
 
@@ -43,8 +34,7 @@ _FORCE = threading.local()
 # name -> one-line description (introspection via `kernel_status`).
 _REGISTRY: dict[str, str] = {}
 
-_OFF = {"0", "off", "false", "no"}
-_ON = {"1", "on", "auto", "true", "yes", ""}
+_MODES = ("off", "on", "interpret")
 
 
 def register_kernel(name: str, doc: str = "") -> None:
@@ -68,51 +58,31 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _env_knob(name: str) -> str | None:
-    per = os.environ.get("ATX_KERNEL_" + re.sub(r"[^A-Za-z0-9]", "_", name).upper())
-    if per is not None:
-        return per
-    return os.environ.get("ATX_KERNELS")
-
-
-def _resolve(raw: str | None) -> str | None:
-    """Knob string -> None (fallback) | 'compiled' | 'interpret'."""
-    if raw is None:
-        raw = "auto"
-    raw = raw.strip().lower()
-    if raw in _OFF:
-        return None
-    if raw == "interpret":
-        return "interpret" if pallas_available() else None
-    if raw in _ON:
-        return "compiled" if (_on_tpu() and pallas_available()) else None
-    raise ValueError(
-        f"unknown kernel knob value {raw!r}; expected 0/off, 1/on/auto, "
-        "or interpret"
-    )
-
-
 def kernel_mode(name: str) -> str | None:
     """May kernel ``name`` replace its fallback in the current trace?
 
     Returns ``None`` (run the exact fallback lowering), ``"compiled"`` (TPU
     Pallas), or ``"interpret"`` (Pallas interpret mode — any backend).
     """
-    forced = getattr(_FORCE, "mode", None)
-    if forced is not None:
-        override = forced.get(name, forced.get(None))
-        if override is not None:
-            return _resolve(override)
-    return _resolve(_env_knob(name))
+    forced = getattr(_FORCE, "mode", None) or {}
+    mode = forced.get(name, forced.get(None, "on"))
+    if mode == "off" or not pallas_available():
+        return None
+    if mode == "interpret":
+        return "interpret"
+    return "compiled" if _on_tpu() else None
 
 
 @contextlib.contextmanager
 def force_kernels(mode: str, name: str | None = None):
-    """Programmatic override of the env knobs while active (including during
-    jit tracing): ``force_kernels("interpret")`` puts every kernel in
+    """Override the code's own choice while active (including during jit
+    tracing): ``force_kernels("interpret")`` puts every kernel in
     interpret mode (the CPU parity-test path), ``force_kernels("off")``
     pins the fallback lowerings, ``force_kernels("on", "fused_adamw")``
-    overrides one kernel only. Nests; inner wins for its keys."""
+    overrides one kernel only. Nests; inner wins for its keys, and a
+    kernel's own entry beats the one for all kernels."""
+    if mode not in _MODES:
+        raise ValueError(f"unknown kernel mode {mode!r}; expected one of {_MODES}")
     prev = getattr(_FORCE, "mode", None)
     new = dict(prev or {})
     new[name] = mode
@@ -125,15 +95,8 @@ def force_kernels(mode: str, name: str | None = None):
 
 def kernel_status() -> list[dict[str, Any]]:
     """Registry snapshot: every registered kernel with its resolved mode
-    under the current env/overrides (the `atx lint kernels` / docs
-    surface)."""
-    out = []
-    for name, doc in sorted(_REGISTRY.items()):
-        try:
-            mode = kernel_mode(name)
-        except ValueError as e:
-            mode = f"error: {e}"
-        out.append(
-            {"kernel": name, "doc": doc, "mode": mode or "fallback"}
-        )
-    return out
+    under the current overrides (the `atx lint kernels` / docs surface)."""
+    return [
+        {"kernel": name, "doc": doc, "mode": kernel_mode(name) or "fallback"}
+        for name, doc in sorted(_REGISTRY.items())
+    ]

@@ -5,8 +5,7 @@ a dozen elementwise ops; XLA fuses most of them but still materializes the
 bias-corrected intermediates and walks param/grad/moments more than once.
 This kernel is the whole update — moment EMAs, bias correction, the
 weight-decay term, and the learning-rate step — in one pass per block, with
-the moment buffers aliased in place (``input_output_aliases``), which is the
-shape the ~6x-off ``hostoffload_adamw_mfu`` bench number wants: the
+the moment buffers aliased in place (``input_output_aliases``): the
 host-offloaded tier's per-layer device-side update becomes one
 read-modify-write over the layer slice.
 

@@ -6,8 +6,7 @@ link behind Python-level per-leaf dispatch (one giant call at a time), which
 bounds the 8B big-model load, host-offloaded AdamW and over-RAM streamed
 decode alike. This module turns every such transfer into *chunks issued
 concurrently from a worker pool*, with prefetch and completion futures so
-traffic overlaps compute instead of blocking it. What it buys on a given
-host link is `bench.py`'s `transfer_mib_s` over `transfer_blocking_mib_s`.
+traffic overlaps compute instead of blocking it.
 
 Three mechanisms, one engine:
 

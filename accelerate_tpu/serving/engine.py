@@ -106,7 +106,7 @@ def default_buckets() -> tuple[int, ...]:
 @dataclasses.dataclass
 class Request:
     """One generation request. ``arrival`` is seconds relative to the trace
-    start (used by `Engine.serve(realtime=True)` and the bench); ``seed``
+    start (used by `Engine.serve(realtime=True)`); ``seed``
     drives the per-request sampling stream, so a request's tokens don't
     depend on which other requests share the batch. ``max_new_tokens=None``
     falls back to the engine config's budget; ``stop_sequences`` are
@@ -320,9 +320,9 @@ class Engine:
 
             The T=1 attention inside ``apply_fn`` routes through the
             `flash-decode Pallas kernel <native/pallas/decode_attention.py>`
-            when enabled (``ATX_KERNELS`` / ``ATX_KERNEL_DECODE_ATTN``,
-            read at trace time): split-K over the slot KV cache, masked by
-            each row's length cursor, with int8 KV dequantized in-kernel."""
+            where `dispatch.kernel_mode` allows it (on a TPU, or under
+            ``force_kernels``; read at trace time): split-K over the slot KV
+            cache, masked by each row's cursor, int8 KV dequantized in-kernel."""
             with (
                 record_attention_paths() as paths,
                 record_step_counts() as counts,
@@ -1153,8 +1153,8 @@ class Engine:
         }
 
     def prefix_metrics(self) -> dict:
-        """Prefix-cache counters in reporting shape (`atx serve` JSON /
-        bench.py serve phase). ``prefill_saved_frac`` is the fraction of
+        """Prefix-cache counters in reporting shape (`atx serve` JSON).
+        ``prefill_saved_frac`` is the fraction of
         all admitted prompt tokens that were served by KV copy instead of
         prefill compute — the headline number for shared-prefix traffic."""
         if self.prefix_cache is None:
@@ -1232,7 +1232,7 @@ def poisson_trace(
     stop_sequences: Sequence[Sequence[int]] | None = None,
 ) -> list[Request]:
     """Synthetic mixed-length request trace with Poisson arrivals at
-    ``rate`` requests/sec — the bench.py / `atx serve` workload shape.
+    ``rate`` requests/sec — the `atx serve` workload shape.
     ``stop_sequences`` (if given) is attached to every request."""
     rng = np.random.RandomState(seed)
     arrivals = np.cumsum(rng.exponential(1.0 / rate, n))

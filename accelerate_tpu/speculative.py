@@ -38,10 +38,8 @@ accept rate equals the teacher-forced draft/target argmax-agreement rate,
 and draft == target through the external-draft path accepts everything
 (tests/test_speculative.py::TestAcceptRateRegression pins both). The 0.0
 was draft QUALITY — a 2-layer prefix of random weights shares no
-distribution with its 24-layer target — so bench.py now trains a
-correlated draft/target pair on a synthetic task before measuring
-(`_train_affine_lm`), making the accept rate a property of the mechanism
-again.
+distribution with its 24-layer target — so an accept rate says something
+about the mechanism only for a draft/target pair that is correlated.
 
 Guarantees (both tested):
 - greedy (``do_sample=False``): output is bit-identical to target-only

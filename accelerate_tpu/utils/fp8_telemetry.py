@@ -1,14 +1,13 @@
 """Per-device-kind fp8 matmul speedup telemetry.
 
 fp8 on a chip without fp8 MXU support is a lose-lose: XLA upcasts the
-scaled values, so you pay quantization error for zero speedup (measured
-0.51x on TPU v5e by `bench.py`'s `fp8_matmul_speedup`). The launcher refuses
-`--mixed_precision fp8` on device kinds with recorded speedup <= 1 unless
-`--force_fp8` is passed (reference analog: the TE/ao fp8 recipes are only
-wired for hardware that benefits, `utils/ao.py:103`).
+scaled values, so you pay quantization error for zero speedup. The launcher
+refuses `--mixed_precision fp8` on device kinds with recorded speedup <= 1
+unless `--force_fp8` is passed (reference analog: the TE/ao fp8 recipes are
+only wired for hardware that benefits, `utils/ao.py:103`).
 
-`bench.py` records fresh measurements here, so the table self-updates the
-first time a bench runs on a new chip generation.
+`record()` persists a speedup measured on the local chip (an fp8 against a
+bf16 matmul of the same shape), which then overrides the built-in table.
 """
 
 from __future__ import annotations
@@ -16,10 +15,11 @@ from __future__ import annotations
 import json
 import os
 
-# Measured by bench.py on real hardware (kind -> fp8/bf16 matmul speedup).
-# v5e has no fp8 MXU: the fp8 path lowers to upcast-and-multiply.
+# kind -> fp8/bf16 matmul speedup. v5e has no fp8 MXU: the fp8 path lowers
+# to upcast-and-multiply. The entry predates `benchmarks/` and is in no
+# ledger line; all the launcher reads of it is that it is <= 1.
 _BUILTIN: dict[str, float] = {
-    "TPU v5 lite": 0.51,  # bench.py fp8_matmul_speedup
+    "TPU v5 lite": 0.51,
 }
 
 
@@ -31,7 +31,7 @@ def _store_path() -> str:
 
 
 def record(device_kind: str, speedup: float) -> None:
-    """Persist a measured fp8 speedup for this device kind (bench.py)."""
+    """Persist a measured fp8 speedup for this device kind."""
     path = _store_path()
     os.makedirs(os.path.dirname(path), exist_ok=True)
     data: dict[str, float] = {}

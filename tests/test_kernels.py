@@ -21,7 +21,6 @@ from accelerate_tpu.native.pallas import (
     pallas_available,
 )
 from accelerate_tpu.native.pallas import decode_attention, fused_adamw, quant_matmul
-from accelerate_tpu.utils.environment import patch_environment
 
 pytestmark = pytest.mark.skipif(
     not pallas_available(), reason="jax.experimental.pallas not importable"
@@ -35,24 +34,27 @@ class TestDispatch:
         assert kernel_mode("decode_attn") is None
 
     def test_global_and_per_kernel_knobs(self):
-        with patch_environment(ATX_KERNELS="interpret"):
+        with force_kernels("interpret"):
             assert kernel_mode("decode_attn") == "interpret"
-        with patch_environment(ATX_KERNELS="0"):
+        with force_kernels("off"):
             assert kernel_mode("decode_attn") is None
-        # Per-kernel knob beats the global one.
-        with patch_environment(
-            ATX_KERNELS="0", ATX_KERNEL_DECODE_ATTN="interpret"
-        ):
+        # A kernel's own override beats the one for all kernels, whichever
+        # was entered first.
+        with force_kernels("off"), force_kernels("interpret", "decode_attn"):
             assert kernel_mode("decode_attn") == "interpret"
             assert kernel_mode("fused_adamw") is None
-        # "on"/"1"/"auto" mean compiled-iff-TPU: fallback on CPU.
-        with patch_environment(ATX_KERNELS="on"):
+        with force_kernels("interpret", "decode_attn"), force_kernels("off"):
+            assert kernel_mode("decode_attn") == "interpret"
+            assert kernel_mode("fused_adamw") is None
+        # "on" means compiled-iff-TPU: fallback on CPU.
+        with force_kernels("on"):
             assert kernel_mode("decode_attn") is None
 
     def test_unknown_knob_value_raises(self):
-        with patch_environment(ATX_KERNELS="fastplease"):
-            with pytest.raises(ValueError, match="unknown kernel knob"):
-                kernel_mode("decode_attn")
+        with pytest.raises(ValueError, match="unknown kernel mode 'fastplease'"):
+            with force_kernels("fastplease"):
+                pass
+        assert kernel_mode("decode_attn") is None  # nothing was left forced
 
     def test_force_kernels_nests_and_restores(self):
         with force_kernels("off"):
@@ -61,12 +63,7 @@ class TestDispatch:
                 assert kernel_mode("decode_attn") == "interpret"
                 assert kernel_mode("fused_adamw") is None  # outer "off"
             assert kernel_mode("decode_attn") is None
-        assert kernel_mode("decode_attn") is None  # env default again
-
-    def test_force_beats_env(self):
-        with patch_environment(ATX_KERNELS="interpret"):
-            with force_kernels("off"):
-                assert kernel_mode("int8_matmul") is None
+        assert kernel_mode("decode_attn") is None  # the code's own choice again
 
     def test_kernel_status_lists_all_kernels(self):
         names = {row["kernel"] for row in kernel_status()}
@@ -206,8 +203,8 @@ class TestFlashDecode:
         assert not decode_attention.supported(q3, jnp.zeros((1, 2, 64, 2 * 16 + 8)))
 
     def test_forward_with_cache_off_is_byte_identical_to_default(self):
-        # ATX_KERNELS=0 acceptance: on this backend the default resolves to
-        # the fallback anyway, so forcing "off" must change NOTHING.
+        # On this backend the code's own choice is the fallback, so forcing
+        # "off" must change NOTHING.
         from accelerate_tpu.models import llama
 
         config = llama.LlamaConfig.tiny()

@@ -8,9 +8,7 @@ regression. Runs on the 8-device CPU simulation (conftest) under
 jax 0.4.37.
 """
 
-import importlib.util
 import json
-import os
 import warnings
 from types import SimpleNamespace
 
@@ -25,8 +23,6 @@ from accelerate_tpu.analysis import rules_memory
 from accelerate_tpu.analysis.findings import Finding, Report
 from accelerate_tpu.state import AcceleratorState
 from accelerate_tpu.utils.environment import patch_environment
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def sds(*shape, dtype=jnp.float32):
@@ -603,40 +599,6 @@ class TestMemoryBudgetRatchet:
             _memory_report(peak_mib=100.9, max_slots=63)
         )
         assert perf_budget.check_budgets(budgets, {"scn": wobble}) == []
-
-
-# ----------------------------------------------------------- bench series
-def _load_bench():
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test_memory", os.path.join(REPO, "bench.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestBenchMemorySeries:
-    def test_direction_of_memory_suffixes(self):
-        bench = _load_bench()
-        assert bench._direction("train_peak_hbm_mib") == -1
-        assert bench._direction("serve_static_max_slots") == 1
-
-    def test_committed_baseline_has_memory_series(self):
-        baseline = json.load(
-            open(os.path.join(REPO, "perf", "bench_static_baseline.json"))
-        )
-        assert baseline["train_peak_hbm_mib"] > 0
-        assert baseline["serve_static_max_slots"] > 0
-
-    def test_compare_gates_on_memory_series(self, tmp_path):
-        bench = _load_bench()
-        old = {"train_peak_hbm_mib": 100.0, "serve_static_max_slots": 64}
-        new = {"train_peak_hbm_mib": 120.0, "serve_static_max_slots": 32}
-        po, pn = tmp_path / "old.json", tmp_path / "new.json"
-        po.write_text(json.dumps(old))
-        pn.write_text(json.dumps(new))
-        regressions, compared = bench.compare_results(str(po), str(pn))
-        assert compared == 2 and len(regressions) == 2
 
 
 # ------------------------------------------- ATX105 <-> ATX701 reconciliation

@@ -6,7 +6,6 @@ persists/overrides correctly. Runs on the 8-device CPU simulation
 (conftest) under jax 0.4.37.
 """
 
-import importlib.util
 import json
 import os
 import types
@@ -521,38 +520,3 @@ class TestAutotuneCache:
         monkeypatch.setenv("ATX_AUTOTUNE_DIR", str(tmp_path))
         cache = autotune.AutotuneCache(chip="v5e")
         assert cache.get("flash", (4096,), "any") is None
-
-
-# ----------------------------------------------------------- bench series
-def _load_bench():
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test_perf", os.path.join(REPO, "bench.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestBenchStaticSeries:
-    def test_direction_of_new_suffixes(self):
-        bench = _load_bench()
-        assert bench._direction("train_static_mfu_bound") == 1
-        assert bench._direction("train_exposed_comms_mib") == -1
-        assert bench._direction("train_padding_waste_frac") == -1
-
-    def test_compare_flags_static_regression(self, tmp_path):
-        bench = _load_bench()
-        old = {"train_static_mfu_bound": 0.6, "train_exposed_comms_mib": 1.0}
-        new = {"train_static_mfu_bound": 0.4, "train_exposed_comms_mib": 2.0}
-        po, pn = tmp_path / "old.json", tmp_path / "new.json"
-        po.write_text(json.dumps(old))
-        pn.write_text(json.dumps(new))
-        regressions, compared = bench.compare_results(str(po), str(pn))
-        assert compared == 2 and len(regressions) == 2
-
-    def test_committed_baseline_has_static_series(self):
-        baseline = json.load(
-            open(os.path.join(REPO, "perf", "bench_static_baseline.json"))
-        )
-        assert "train_static_mfu_bound" in baseline
-        assert "train_exposed_comms_mib" in baseline
