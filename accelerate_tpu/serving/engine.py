@@ -61,6 +61,7 @@ from ..models.layers import (
     record_attention_paths,
     record_step_counts,
 )
+from ..native.pallas.decode_attention import rows_fetched
 from ..ops.int8 import record_weight_paths
 from ..ops.moe import MOE_COUNTS
 from ..utils.environment import (
@@ -298,6 +299,13 @@ class Engine:
         # Rows of the shortest leaf where it is shorter than a slot: a ring.
         shortest = min(int(v.shape[2]) for v in jax.tree.leaves(kv))
         self._ring_len = shortest if shortest < self.max_len else 0
+        # Bytes of one cached token by the rows of its leaf (the widest leaf
+        # of that length: K or V, not their scales): what sizes the decode
+        # kernel's blocks, for `_kv_rows_fetched`.
+        self._kv_row_bytes: dict[int, int] = {}
+        for v in jax.tree.leaves(kv):
+            rows, width = int(v.shape[2]), int(v.shape[3]) * np.dtype(v.dtype).itemsize
+            self._kv_row_bytes[rows] = max(self._kv_row_bytes.get(rows, 0), width)
         config_ = self.config
         eos, pad = config_.eos_token_id, config_.pad_token_id
 
@@ -474,6 +482,13 @@ class Engine:
                 # ring layer (capped at the ring's rows).
                 "kv_rows_live_full",
                 "kv_rows_live_window",
+                # Rows of KV a decode step's attention copied out of one
+                # such layer, summed over every slot (free and mid-prefill
+                # ones too) and the steps: whole blocks up to each cursor
+                # under the flash-decode kernel, every row of every slot
+                # under the sliced lowering.
+                "kv_rows_fetched_full",
+                "kv_rows_fetched_window",
                 *MOE_COUNTS,  # the expert layer's, summed over layers and decode steps
             ),
             label="engine",
@@ -967,13 +982,16 @@ class Engine:
                 )
                 fetched.append((tokens, counts))
                 self._count_weight_paths("decode")
+                attended = lengths + 1  # what the attention is handed, slot by slot
                 lengths[decoding] += 1
                 steps[decoding] += 1
-                live = int(lengths[decoding].sum())  # cursor + 1 of each decoding slot
-                self.stats["kv_rows_live_full"] += live
+                self.stats["kv_rows_live_full"] += int(attended[decoding].sum())
+                self.stats["kv_rows_fetched_full"] += self._kv_rows_fetched(attended, self.max_len)
                 if self._ring_len:
-                    self.stats["kv_rows_live_window"] += int(
-                        np.minimum(lengths[decoding], self._ring_len).sum()
+                    attended = np.minimum(attended, self._ring_len)
+                    self.stats["kv_rows_live_window"] += int(attended[decoding].sum())
+                    self.stats["kv_rows_fetched_window"] += self._kv_rows_fetched(
+                        attended, self._ring_len
                     )
         with _telemetry.span("serve_fetch"):
             host = jax.device_get(fetched)
@@ -992,6 +1010,14 @@ class Engine:
                     slot.cursor += 1
                     out.extend(self._emit(i, int(nxt[i])))
         return out
+
+    def _kv_rows_fetched(self, attended: np.ndarray, rows: int) -> int:
+        """Rows one decode step's attention copies out of a layer whose
+        leaves hold ``rows`` a slot: the kernel's own arithmetic where the
+        traced program reads the cache in place, else every row."""
+        if not self.stats["decode_in_place"]:
+            return attended.size * rows
+        return rows_fetched(attended, rows, self._kv_row_bytes[rows])
 
     def _count_weight_paths(self, program: Any) -> None:
         """One dispatch of ``program``: add what its trace recorded."""

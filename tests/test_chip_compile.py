@@ -79,6 +79,17 @@ def _decode_int8(q, k, v, lengths, layer, ks, vs):
     )
 
 
+def _decode_case(slots, slot_len, heads=H, kv_heads=K, int8=False):
+    """A KERNELS entry: `flash_decode` over a two-layer cache of ``slots`` x
+    ``slot_len`` rows of ``kv_heads`` heads of 128."""
+    stack = (2, slots, slot_len, kv_heads * 128)
+    kv = (stack, I8 if int8 else BF16)
+    operands = [((slots, 1, heads, 128), BF16), kv, kv, ((slots,), I32), ((), I32)]
+    if int8:
+        operands += [(stack[:3] + (kv_heads,), BF16)] * 2
+    return (_decode_int8 if int8 else _decode, operands, ["flash_decode"])
+
+
 def _int8_matmul(x, w, s):
     return quant_matmul.int8_matmul_fused("mc,cn->mn", x, w, s, interpret=False)
 
@@ -107,8 +118,6 @@ def _adamw(g, mu, nu, p, count, lr):
 
 
 _QKV = [((1, SEQ, H, HD), BF16), ((1, SEQ, K, HD), BF16), ((1, SEQ, K, HD), BF16)]
-_SLOT_Q = ((SLOTS, 1, H, HD), BF16)
-_STACK = (2, SLOTS, SLOT_LEN, K * HD)  # a two-layer stacked cache, heads flattened
 _LEAF = ((D, FF), F32)
 _QKV_SHORT = [((1, 2048, H, HD), BF16), ((1, 2048, K, HD), BF16), ((1, 2048, K, HD), BF16)]
 F8 = jnp.float8_e4m3fn
@@ -123,17 +132,16 @@ KERNELS = {
     "flash_fwd_bwd": (_flash_grad, _QKV, _FLASH_BWD),
     # Under 4096 tokens the whole K and V of a head stay in VMEM.
     "flash_resident_fwd_bwd": (_flash_grad, _QKV_SHORT, [k + "_resident" for k in _FLASH_BWD]),
-    "flash_decode_bf16": (
-        _decode,
-        [_SLOT_Q, (_STACK, BF16), (_STACK, BF16), ((SLOTS,), I32), ((), I32)],
-        ["flash_decode"],
-    ),
-    "flash_decode_int8_kv": (
-        _decode_int8,
-        [_SLOT_Q, (_STACK, I8), (_STACK, I8), ((SLOTS,), I32), ((), I32),
-         (_STACK[:3] + (K,), BF16), (_STACK[:3] + (K,), BF16)],
-        ["flash_decode"],
-    ),
+    "flash_decode_bf16": _decode_case(SLOTS, SLOT_LEN),
+    "flash_decode_int8_kv": _decode_case(SLOTS, SLOT_LEN, int8=True),
+    # The serve cells' caches (two layers of each): the chat cell's 32 slots of
+    # 1024, the long cell's 4 of 8192 (also as int8 KV), and both kinds of the
+    # mixed cell's leaves (16 slots, 4 kv heads, 7 query heads a kv head).
+    "flash_decode_chat": _decode_case(32, 1024),
+    "flash_decode_long": _decode_case(4, 8192),
+    "flash_decode_long_int8_kv": _decode_case(4, 8192, int8=True),
+    "flash_decode_mixed_full": _decode_case(16, 16384, heads=28, kv_heads=4),
+    "flash_decode_mixed_ring": _decode_case(16, 4096, heads=28, kv_heads=4),
     # The down projection of a 2048-token prefill: the whole contraction
     # (14336) staged per block was 43 MB of VMEM against a 16 MB limit.
     "int8_matmul_prefill": (_int8_matmul, [((2048, FF), BF16), ((FF, D), I8), ((1, D), F32)], ["int8_matmul"]),

@@ -127,6 +127,16 @@ class TestFlashDecode:
         ref = _ref_decode(q, k, v, lengths)
         np.testing.assert_allclose(out, ref, rtol=2e-6, atol=2e-6)
 
+    @pytest.mark.parametrize("K, group", [(12, 2), (16, 1), (3, 3)])
+    def test_kv_heads_in_sets_of_one_product(self, K, group):
+        """Up to eight kv heads share a product (their queries block-diagonal
+        in one operand); more are walked in sets, a count eight does not
+        divide in sets of its largest divisor."""
+        q, k, v = _decode_operands(jnp.float32, B=3, T=64, K=K, group=group)
+        lengths = jnp.asarray([5, 64, 33], jnp.int32)
+        out = decode_attention.flash_decode(q, _stacked(k), _stacked(v), lengths, interpret=True)
+        np.testing.assert_allclose(out, _ref_decode(q, k, v, lengths), rtol=2e-6, atol=2e-6)
+
     def test_bf16_parity(self):
         q, k, v = _decode_operands(jnp.bfloat16)
         out = decode_attention.flash_decode(q, _stacked(k), _stacked(v), 40, interpret=True)
@@ -187,6 +197,69 @@ class TestFlashDecode:
         np.testing.assert_allclose(
             out.astype(np.float32), ref.astype(np.float32), rtol=3e-2, atol=3e-2
         )
+
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    def test_cursors_around_a_block_edge_and_noise_past_them(self, kv_dtype):
+        """SmallThinker's head layout (4 kv heads of 128 in 512 lanes, 7
+        query heads each) at layer 2 of a stack of noise, one batch with
+        cursors at 0, 1, either side of a block edge and the whole slot.
+        Past every cursor the cache holds loud noise the oracle never sees:
+        a block the index map keeps pointing at, or a dead row of a live
+        block, must not reach the output."""
+        from accelerate_tpu.models.layers import (
+            dequant_kv, dot_product_attention, quantize_kv,
+        )
+
+        T, K, h, layer = 2048, 4, 128, 2
+        blk = decode_attention.pick_block(T, K * h * (1 if kv_dtype == "int8" else 2))
+        assert T // blk >= 2
+        lengths = np.asarray([0, 1, blk - 1, blk, blk + 1, T], np.int32)
+        q, k, v = _decode_operands(jnp.bfloat16, B=len(lengths), T=T, K=K, group=7, h=h)
+        dead = (np.arange(T)[None, :] >= lengths[:, None])[:, :, None]  # (B, T, 1)
+
+        def loud(x):
+            past = dead.reshape(dead.shape + (1,) * (x.ndim - 3))
+            return jnp.where(past, 100.0 * (1 + jnp.abs(x.astype(jnp.float32))), x).astype(x.dtype)
+
+        scales = {}
+        if kv_dtype == "int8":
+            (k_q, ksc), (v_q, vsc) = quantize_kv(k), quantize_kv(v)
+            k, v = dequant_kv(k_q, ksc, q.dtype), dequant_kv(v_q, vsc, q.dtype)
+            k_in = jnp.where(dead[..., None], 127, k_q).astype(jnp.int8)
+            v_in = jnp.where(dead[..., None], -127, v_q).astype(jnp.int8)
+            scales = {"k_scale": _stacked(loud(ksc), layer, 3), "v_scale": _stacked(loud(vsc), layer, 3)}
+        else:
+            k_in, v_in = loud(k), loud(v)
+        out = jax.jit(
+            lambda i: decode_attention.flash_decode(
+                q, _stacked(k_in, layer, 3), _stacked(v_in, layer, 3), jnp.asarray(lengths), i,
+                interpret=True, **scales,
+            )
+        )(jnp.int32(layer))
+        out = np.asarray(out.astype(jnp.float32))
+        mask = (jnp.arange(T)[None, :] < lengths[:, None])[:, None, :]
+        ref = np.asarray(dot_product_attention(q, k, v, mask=mask).astype(jnp.float32))
+        np.testing.assert_allclose(out[1:], ref[1:], rtol=3e-2, atol=3e-2)
+        assert not out[0].any()  # nothing attended: no block is computed, the row reads 0
+
+    @pytest.mark.parametrize("blk", [128, 512])
+    def test_rows_fetched_is_what_the_grid_fetches(self, blk, monkeypatch):
+        """The engine's ``kv_rows_fetched_*`` arithmetic against the grid
+        itself: one step a block, so a call copies the distinct (row, block)
+        pairs of `live_steps`, which the K / V index maps read."""
+        T, row_bytes = 2048, 1024
+        monkeypatch.setenv("ATX_BLOCK_DECODE_ATTENTION", str(blk))
+        assert decode_attention.pick_block(T, row_bytes) == blk
+        lengths = np.asarray([0, 1, blk - 1, blk, blk + 1, 3 * blk, T - 1, T, T + 1], np.int32)
+        n, row, block = decode_attention.live_steps(jnp.asarray(lengths), T, blk)
+        assert row.shape == block.shape == (len(lengths) * (T // blk),)
+        steps = list(zip(np.asarray(row)[: int(n)].tolist(), np.asarray(block)[: int(n)].tolist()))
+        assert steps == sorted(set(steps))  # rows in order, a row's blocks in order, none twice
+        by_row = [sum(1 for r, _ in steps if r == b) for b in range(len(lengths))]
+        assert by_row == [1, 1, 1, 1, 2, 3, T // blk, T // blk, T // blk]
+        assert decode_attention.rows_fetched(lengths, T, row_bytes) == len(steps) * blk
+        # Every slot full: the whole layer, as the sliced lowering reads it.
+        assert decode_attention.rows_fetched(np.full(7, T), T, row_bytes) == 7 * T
 
     def test_unsupported_shapes_fall_back(self):
         q, k, v = _decode_operands(jnp.float32, T=12)  # 12 has no block divisor

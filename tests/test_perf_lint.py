@@ -510,7 +510,7 @@ class TestAutotuneCache:
         # the wired kernels rely on divide-exactly semantics
         from accelerate_tpu.native.pallas import decode_attention
 
-        blk = decode_attention.pick_block(4096)
+        blk = decode_attention.pick_block(4096, 2048)  # a cache of 4096 rows of 2 KB
         assert blk is not None and 4096 % blk == 0
 
     def test_corrupt_cache_file_is_empty_cache(self, tmp_path, monkeypatch):

@@ -278,6 +278,33 @@ class TestCompileDiscipline:
         ]
         assert value == in_place
 
+    @pytest.mark.parametrize("mode", ["interpret", "off"])
+    def test_stats_count_the_rows_decode_attention_fetches(self, params, mode, monkeypatch):
+        """Beside the live rows of the decoding slots, the rows a step's
+        attention copies out of a layer over every slot: whole blocks up to
+        each cursor under the kernel (a free slot one block), every row of
+        every slot under the sliced lowering."""
+        from accelerate_tpu.native.pallas.dispatch import force_kernels
+
+        monkeypatch.setenv("ATX_BLOCK_DECODE_ATTENTION", "16")  # four blocks a slot of 64
+        eng = _engine(params, slots=3, max_len=64)
+        with force_kernels(mode):
+            eng.serve([serving.Request(prompt=np.arange(1, 31, dtype=np.int32), max_new_tokens=6)])
+        live, fetched = eng.stats["kv_rows_live_full"], eng.stats["kv_rows_fetched_full"]
+        assert eng.stats["decode_steps"] == 5  # the first token comes from the prefill
+        whole = 5 * 3 * 64
+        full = np.full(3, 64)
+        assert live == sum(31 + i for i in range(5))  # cursor + 1 of the one decoding slot
+        assert eng.stats["kv_rows_fetched_window"] == 0  # no ring leaves
+        if mode == "off":
+            assert fetched == whole
+        else:
+            # A step: a block of 16 for either free slot, cdiv(cursor + 1, 16) for the third.
+            assert fetched == sum(2 * 16 + -(-(31 + i) // 16) * 16 for i in range(5)) == 2 * 64 + 3 * 80
+            assert live <= fetched < whole
+            assert eng._kv_rows_fetched(full, 64) == 3 * 64  # only when every slot is full
+            assert eng._kv_rows_fetched(full - [0, 0, 16], 64) == 3 * 64 - 16
+
     @pytest.mark.parametrize(
         "weights, mode, in_place, sliced",
         [("int8", "interpret", 4, 3), ("int8", "off", 0, 7), ("bf16", "interpret", 0, 0)],
