@@ -8,12 +8,12 @@ module exposes: a frozen ``*Config``, ``init(rng, config) -> params``,
 (`parallel/tp.py`).
 """
 
-from . import bert, gpt, hf, llama, smallthinker, t5, vit
+from . import bert, gpt, hf, llama, olmo_hybrid, smallthinker, t5, vit
 from .hf import from_hf_config, load_pretrained, save_pretrained
 from .layers import cross_entropy_loss, dot_product_attention
 
 __all__ = [
-    "bert", "gpt", "hf", "llama", "smallthinker", "t5", "vit",
+    "bert", "gpt", "hf", "llama", "olmo_hybrid", "smallthinker", "t5", "vit",
     "cross_entropy_loss", "dot_product_attention",
     "from_hf_config", "load_pretrained", "save_pretrained",
 ]

@@ -22,8 +22,8 @@ a leaf reads only the matching byte ranges of the source tensors, so a 70B
 repo never materializes a full tensor on any host (the streaming contract of
 `load_checkpoint_and_dispatch`).
 
-Supported ``model_type``s: smallthinker (its config only: `from_hf_config`),
-llama, mistral, mixtral, qwen2 (the llama
+Supported ``model_type``s: smallthinker and olmo_hybrid (their configs only:
+`from_hf_config`), llama, mistral, mixtral, qwen2 (the llama
 family — mixtral routes through the MoE blocks, qwen2 adds q/k/v biases),
 gpt2, gpt_neox, gptj, opt (the gpt family — variant knobs select rotary
 style, parallel residual, activation, and bias layout; these are the
@@ -1096,9 +1096,59 @@ def from_hf_config(config: Any) -> tuple[str, Any]:
             norm_eps=float(config["rms_norm_eps"]),
             tie_embeddings=bool(config.get("tie_word_embeddings", False)),
         )
+    if mt == "olmo_hybrid":
+        from .olmo_hybrid import FULL, LINEAR, OlmoHybridConfig
+
+        # The config maps; a checkpoint's tensor names are not known here,
+        # so `load_pretrained` has no key specs for this family. What the
+        # family does not implement is refused by the key that asks for it.
+        if not config.get("linear_allow_neg_eigval", False):
+            raise ValueError(
+                "linear_allow_neg_eigval=false (beta in (0, 1)) is not implemented for the "
+                "olmo_hybrid family: its write strength is beta = 2 sigmoid(b), in (0, 2)"
+            )
+        heads = config["linear_num_key_heads"]
+        if config["linear_num_value_heads"] != heads:
+            raise ValueError(
+                f"linear_num_value_heads ({config['linear_num_value_heads']}) differs from "
+                f"linear_num_key_heads ({heads}): grouped value heads are not implemented"
+            )
+        rope = config.get("rope_parameters") or {}
+        if rope.get("rope_theta") is not None or config.get("rope_theta") is not None:
+            raise ValueError(
+                "a rotary setting (rope_parameters.rope_theta / rope_theta) is not implemented "
+                "for the olmo_hybrid family: its full-attention layers carry no rotary term"
+            )
+        if config.get("attention_bias"):
+            raise ValueError("attention_bias=true is not implemented for the olmo_hybrid family")
+        if config.get("hidden_act", "silu") != "silu":
+            raise ValueError(f"hidden_act {config['hidden_act']!r} is not implemented for the olmo_hybrid family")
+        n_layers = config["num_hidden_layers"]
+        # A depth-cut config keeps the published per-layer layout whole: its
+        # first num_hidden_layers entries apply.
+        kinds = tuple(config["layer_types"])[:n_layers]
+        if len(kinds) < n_layers or set(kinds) - {LINEAR, FULL}:
+            raise ValueError(f"layer_types must name {n_layers} layers of {LINEAR!r} / {FULL!r}")
+        return "olmo_hybrid", OlmoHybridConfig(
+            vocab_size=config["vocab_size"],
+            d_model=config["hidden_size"],
+            d_ff=config["intermediate_size"],
+            n_layers=n_layers,
+            num_heads=config["num_attention_heads"],
+            num_kv_heads=config["num_key_value_heads"],
+            head_dim=config.get("head_dim") or config["hidden_size"] // config["num_attention_heads"],
+            layer_types=kinds,
+            linear_heads=heads,
+            linear_key_dim=config["linear_key_head_dim"],
+            linear_value_dim=config["linear_value_head_dim"],
+            conv_kernel=config["linear_conv_kernel_dim"],
+            max_seq_len=config["max_position_embeddings"],
+            norm_eps=float(config["rms_norm_eps"]),
+            tie_embeddings=bool(config.get("tie_word_embeddings", False)),
+        )
     raise ValueError(
         f"Unsupported HF model_type {mt!r}; supported: llama, mistral, "
-        "mixtral, qwen2, smallthinker (config only), gpt2, gpt_neox, gptj, "
+        "mixtral, qwen2, smallthinker (config only), olmo_hybrid (config only), gpt2, gpt_neox, gptj, "
         "opt, bert, vit, t5 (v1.1 gated layout)."
     )
 

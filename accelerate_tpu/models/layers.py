@@ -244,6 +244,19 @@ def cache_append(
     }
 
 
+STATE_PREFIX = "state"
+
+
+def is_state_leaf(name: str) -> bool:
+    """Whether a family cache's leaf ``name`` is a recurrent state: laid out
+    ``(L, B, ...)`` with no row axis, one value a slot whatever the cursor
+    (a delta-rule state matrix, a convolution's last inputs). A family marks
+    such a leaf by its name, ``state`` or ``state_<what>``; every other
+    non-``length`` leaf is rows, ``(L, B, T, ...)``. Told apart by name, not
+    by extent: a ``(L, B, 30, 96, 192)`` state is no ring of 30 rows."""
+    return name == STATE_PREFIX or name.startswith(STATE_PREFIX + "_")
+
+
 def cache_slot_view(kv: Any, slot: jax.Array) -> Any:
     """Slice one slot row (batch axis 1) out of every layer-stacked KV leaf.
 

@@ -1,12 +1,14 @@
 """Pallas hot-path kernel tier (ROADMAP direction 3).
 
-Custom TPU kernels for four hot paths the XLA lowerings leave on the
+Custom TPU kernels for the hot paths the XLA lowerings leave on the
 table: flash-decode attention over the slot KV cache (the fallback ignores
 KV-quantization bandwidth headroom), fused quantize→dot→rescale matmuls for
 the int8/fp8 paths (fp8 round-trips through XLA's upcast), a single-pass
 fused AdamW update (the host-offloaded optimizer tier), and the grouped
 expert feed-forward of the dropless MoE layer (the fallback slices a
-layer's experts out of the weight stack first).
+layer's experts out of the weight stack first), and the gated delta rule's
+state kernels (`gated_delta.py`: a decode step on the state stack in place,
+a prefill chunk's state pass).
 
 Every kernel sits behind the dispatch-by-availability registry in
 `dispatch.py`: TPU backend + pallas importable + shape/dtype supported →
@@ -28,4 +30,4 @@ from .dispatch import (  # noqa: F401
 # Importing a kernel module registers it: `kernel_status()` lists every
 # kernel as soon as the package is imported, not only those a trace has
 # already reached.
-from . import decode_attention, fused_adamw, moe_experts, quant_matmul  # noqa: E402,F401
+from . import decode_attention, fused_adamw, gated_delta, moe_experts, quant_matmul  # noqa: E402,F401

@@ -58,6 +58,7 @@ from ..models.layers import (
     cache_slot_copy,
     cache_slot_view,
     cache_slot_write,
+    is_state_leaf,
     record_attention_paths,
     record_step_counts,
 )
@@ -148,7 +149,9 @@ class Completion:
     holds whatever was generated before the cancel) / ``"failed"``
     (`serving.Router` only: replica deaths exhausted the retry budget) /
     ``"shed"`` (`serving.Router` only: evicted from the admission queue
-    under overload to make room for a higher-priority request)."""
+    under overload to make room for a higher-priority request). ``slot`` is
+    the slot the request finished in (-1 for one that was cancelled):
+    `Engine.slot_state` reads what it left there."""
 
     rid: int
     prompt: np.ndarray
@@ -161,6 +164,7 @@ class Completion:
     finish_reason: str = "length"
     admitted_at: float = 0.0
     prefill_started_at: float = 0.0
+    slot: int = -1
 
 
 class _Slot:
@@ -218,6 +222,18 @@ class Engine:
     prefix cache is off: its copies go "at the same sequence offset", which
     a ring does not have (``stats['prefix_cache_off_for_ring']``; asking for
     it explicitly is a ValueError).
+
+    A family with recurrent layers keeps **state leaves** beside its rows
+    (`models/layers.py:is_state_leaf`: named ``state`` / ``state_<what>``,
+    laid out ``(L, B, ...)`` with no row axis). A state has no cursor to hide
+    behind, so the engine tells the forward what the cursor told it for
+    rows: a prefill chunk carries ``cache['valid']`` (a bucket's pad tail
+    advances no state), a decode step ``cache['decoding']`` (the (B,) mask of
+    the decoding slots: free and mid-prefill slots keep their state), and
+    the first chunk of a request (cursor 0) zeroes its slot's states inside
+    the chunk's own program. The prefix cache is off for such a cache too:
+    a state at a block boundary is not kept anywhere
+    (``stats['prefix_cache_off_for_state']``; asking for it is a ValueError).
 
     ``max_len`` is the per-slot KV capacity (prompt + new tokens must fit);
     defaults to ``2 * max(buckets)``. ``prefill_interleave`` is the number
@@ -296,14 +312,20 @@ class Engine:
         # committedness) stay IDENTICAL from the first call on — one compile
         # for decode, one per prefill bucket.
         self._kv = jax.device_put(kv, self._device)
+        # Recurrent states, told apart by name: they have no row axis, and
+        # nothing below that reads a leaf's rows may look at them.
+        self._state_names = tuple(sorted(k for k in kv if is_state_leaf(k)))
+        rows_of = [v for k, v in kv.items() if k not in self._state_names]
+        # Layers that keep a state: what one decode step's live slots count by.
+        self._state_layers = max((int(kv[k].shape[0]) for k in self._state_names), default=0)
         # Rows of the shortest leaf where it is shorter than a slot: a ring.
-        shortest = min(int(v.shape[2]) for v in jax.tree.leaves(kv))
+        shortest = min((int(v.shape[2]) for v in jax.tree.leaves(rows_of)), default=self.max_len)
         self._ring_len = shortest if shortest < self.max_len else 0
         # Bytes of one cached token by the rows of its leaf (the widest leaf
         # of that length: K or V, not their scales): what sizes the decode
         # kernel's blocks, for `_kv_rows_fetched`.
         self._kv_row_bytes: dict[int, int] = {}
-        for v in jax.tree.leaves(kv):
+        for v in jax.tree.leaves(rows_of):
             rows, width = int(v.shape[2]), int(v.shape[3]) * np.dtype(v.dtype).itemsize
             self._kv_row_bytes[rows] = max(self._kv_row_bytes.get(rows, 0), width)
         config_ = self.config
@@ -320,11 +342,13 @@ class Engine:
                 jnp.int32
             )
 
-        def decode_fn(params, tokens, lengths, kv, seeds, steps):
+        def decode_fn(params, tokens, lengths, kv, seeds, steps, *decoding):
             """One token for every slot. Free/mid-prefill slots compute too
             (static shapes) — their write lands at their cursor, a position
             the next prefill chunk fully overwrites, and their output is
-            dropped by the host scheduler.
+            dropped by the host scheduler. A cache with state leaves brings
+            one more operand, the (N,) mask of the decoding slots: the
+            forward leaves every other slot's state as it is.
 
             The T=1 attention inside ``apply_fn`` routes through the
             `flash-decode Pallas kernel <native/pallas/decode_attention.py>`
@@ -336,7 +360,10 @@ class Engine:
                 record_step_counts() as counts,
                 record_weight_paths() as weights,
             ):
-                logits, new = apply_fn(params, tokens[:, None], dict(kv, length=lengths))
+                cache = dict(kv, length=lengths)
+                if decoding:
+                    (cache["decoding"],) = decoding
+                logits, new = apply_fn(params, tokens[:, None], cache)
             # Trace time: which attention lowering this program compiled to,
             # and how its quantized contractions get their weights.
             self.stats["decode_in_place"] = int(
@@ -355,9 +382,14 @@ class Engine:
             returned token (sampled at ``sample_pos``, the chunk's last
             REAL position) is only meaningful on a prompt's final chunk."""
             row = cache_slot_view(kv, slot)
+            for name in self._state_names:
+                # A request's first chunk starts from no state, whatever the
+                # slot's last occupant left: zeroed here, in this program.
+                row[name] = jnp.where(cursor == 0, jnp.zeros_like(row[name]), row[name])
             cache = dict(row, length=cursor)
-            if self._ring_len:
-                # In a ring the pad tail would land on rows still in the window.
+            if self._ring_len or self._state_names:
+                # In a ring the pad tail would land on rows still in the
+                # window; a state would advance over it.
                 cache["valid"] = sample_pos + 1
             with record_weight_paths() as weights:
                 logits, new = apply_fn(params, tokens, cache)
@@ -408,6 +440,16 @@ class Engine:
                 logger.info(
                     "prefix cache off: the cache has ring leaves of %d rows", self._ring_len
                 )
+            enabled = False
+        if self._state_names:
+            if prefix_cache:
+                raise ValueError(
+                    f"this family's cache has state leaves {self._state_names}: the prefix "
+                    "cache copies rows, and a recurrent state at a prefix's end is kept "
+                    "nowhere; run with prefix_cache off"
+                )
+            if enabled:
+                logger.info("prefix cache off: the cache has state leaves %s", self._state_names)
             enabled = False
         self.prefix_cache: PrefixCache | None = None
         self._pool: Any = None
@@ -489,12 +531,26 @@ class Engine:
                 # under the sliced lowering.
                 "kv_rows_fetched_full",
                 "kv_rows_fetched_window",
+                "prefix_cache_off_for_state",
+                # Recurrent states (a cache with state leaves; 0 otherwise).
+                # At every decode step: decoding slots x layers that keep a
+                # state, and the (slot, layer) states the step's kernel read
+                # and wrote (the forward's own count: `gdn_decode` visits the
+                # decoding slots, the XLA lowering selects over all of them).
+                "state_slots_live",
+                "state_slots_touched",
+                # At every prefill chunk: its real rows and its bucket's rows,
+                # which the chunkwise form ran over.
+                "state_rows_real",
+                "state_rows_padded",
+                "state_resets",  # first chunks: a slot's states zeroed
                 *MOE_COUNTS,  # the expert layer's, summed over layers and decode steps
             ),
             label="engine",
-            gauges=("decode_in_place", "prefix_cache_off_for_ring"),
+            gauges=("decode_in_place", "prefix_cache_off_for_ring", "prefix_cache_off_for_state"),
         )
         self.stats["prefix_cache_off_for_ring"] = int(bool(self._ring_len))
+        self.stats["prefix_cache_off_for_state"] = int(bool(self._state_names))
         _labels = ("engine",)
         self._tel_labels = self.stats.labels
         self._h_queue_wait = _telemetry.histogram(
@@ -690,6 +746,13 @@ class Engine:
         return out
 
     # ---------------------------------------------------------- scheduler
+    def slot_state(self, slot: int) -> dict[str, np.ndarray]:
+        """The state leaves of one slot, on the host, each ``(layers, ...)``:
+        what the slot's last occupant left (a finished request's states stay
+        until the next admission's first chunk zeroes them). Empty for a
+        cache without state leaves."""
+        return {n: np.asarray(self._kv[n][:, slot]) for n in self._state_names}
+
     @property
     def busy(self) -> bool:
         return bool(self._queue) or any(s is not None for s in self._slots)
@@ -874,6 +937,10 @@ class Engine:
                 np.int32(real - 1),
                 np.uint32(slot.req.seed),
             )
+            if self._state_names:
+                self.stats["state_resets"] += slot.cursor == 0
+                self.stats["state_rows_real"] += real
+                self.stats["state_rows_padded"] += buf.shape[1]
             slot.cursor += real
             self.stats["prefill_chunks"] += 1
             self._count_weight_paths(buf.shape[1])
@@ -972,19 +1039,26 @@ class Engine:
             # decode step silently compiles twice (committed vs uncommitted
             # int32 (N,)).
             tokens = jax.device_put(tokens, self._device)
+            extra = ()
+            if self._state_names:
+                mask = np.zeros((self.n_slots,), bool)
+                mask[decoding] = True
+                extra = (mask,)
             for _ in range(block):
                 # The dispatch gets its own copies of the cursors: the
                 # transfer is asynchronous and can alias numpy memory, so it
                 # may still be reading a host buffer when the lines below
                 # advance it in place.
                 (tokens, counts), self._kv = self._decode(
-                    self.params, tokens, lengths.copy(), self._kv, seeds, steps.copy()
+                    self.params, tokens, lengths.copy(), self._kv, seeds, steps.copy(), *extra
                 )
                 fetched.append((tokens, counts))
                 self._count_weight_paths("decode")
                 attended = lengths + 1  # what the attention is handed, slot by slot
                 lengths[decoding] += 1
                 steps[decoding] += 1
+                if self._state_names:
+                    self.stats["state_slots_live"] += len(decoding) * self._state_layers
                 self.stats["kv_rows_live_full"] += int(attended[decoding].sum())
                 self.stats["kv_rows_fetched_full"] += self._kv_rows_fetched(attended, self.max_len)
                 if self._ring_len:
@@ -1015,6 +1089,8 @@ class Engine:
         """Rows one decode step's attention copies out of a layer whose
         leaves hold ``rows`` a slot: the kernel's own arithmetic where the
         traced program reads the cache in place, else every row."""
+        if rows not in self._kv_row_bytes:
+            return 0  # a cache of states only keeps no rows
         if not self.stats["decode_in_place"]:
             return attended.size * rows
         return rows_fetched(attended, rows, self._kv_row_bytes[rows])
@@ -1063,6 +1139,7 @@ class Engine:
             finish_reason="eos" if eos_hit else ("stop" if stop_hit else "length"),
             admitted_at=slot.t_admit,
             prefill_started_at=slot.t_prefill0,
+            slot=slot_id,
         )
         self._record_request(completion)
         if self._trace:
@@ -1219,6 +1296,7 @@ class Engine:
             jax.tree.map(sds, self._kv),
             vec(np.uint32),
             vec(np.int32),
+            *((vec(np.bool_),) if self._state_names else ()),
         )
 
     def copy_fn_for_bucket(self, bucket: int):

@@ -28,8 +28,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
-from accelerate_tpu.models import llama, smallthinker  # noqa: E402
-from accelerate_tpu.native.pallas import decode_attention, fused_adamw, moe_experts, quant_matmul  # noqa: E402
+from accelerate_tpu.models import llama, olmo_hybrid, smallthinker  # noqa: E402
+from accelerate_tpu.native.pallas import decode_attention, fused_adamw, gated_delta, moe_experts, quant_matmul  # noqa: E402
+from accelerate_tpu.ops import gated_delta as gated_delta_ops  # noqa: E402
 from accelerate_tpu.native.pallas.dispatch import force_kernels  # noqa: E402
 from accelerate_tpu.ops.flash_attention import flash_attention  # noqa: E402
 
@@ -111,6 +112,14 @@ def _moe_experts(tile_rows):
     return call
 
 
+def _gdn_decode(q, k, v, alpha, beta, state, layer, decoding):
+    return gated_delta.gdn_decode(q, k, v, alpha, beta, state, layer, decoding, interpret=False)
+
+
+def _gdn_chunk(q, k, v, g, beta, state):
+    return gated_delta.gdn_chunk(gated_delta_ops.chunk_prepare(q, k, v, g, beta), state, interpret=False)
+
+
 def _adamw(g, mu, nu, p, count, lr):
     return fused_adamw.fused_adamw_update(
         g, mu, nu, p, count, lr, 0.9, 0.999, 1e-8, 0.01, interpret=False
@@ -163,6 +172,22 @@ KERNELS = {
         [((66 * 16, ST_D), BF16), *_EXPERT_STACKS, ((66,), I32), ((), I32), ((), I32)],
         ["moe_experts"],
     ),
+    # Olmo-Hybrid-7B: the delta rule's state stack of two layers x 32 slots x
+    # 30 heads of 96 x 192 float32, one token a slot in place; a 256-row
+    # chunk's state pass; and `flash_decode` at 30 kv heads of one query head.
+    "gdn_decode": (
+        _gdn_decode,
+        [((32, 30, 96), F32)] * 2 + [((32, 30, 192), F32)] + [((32, 30), F32)] * 2
+        + [((2, 32, 30, 96, 192), F32), ((), I32), ((32,), jnp.bool_)],
+        ["gdn_decode"],
+    ),
+    "gdn_chunk_256": (
+        _gdn_chunk,
+        [((1, 256, 30, 96), F32)] * 2 + [((1, 256, 30, 192), F32)] + [((1, 256, 30), F32)] * 2
+        + [((1, 30, 96, 192), F32)],
+        ["gdn_chunk"],
+    ),
+    "flash_decode_hybrid_mha": _decode_case(32, 2048, heads=30, kv_heads=30),
     "moe_experts_prefill": (
         _moe_experts(128),
         [((111 * 128, ST_D), BF16), *_EXPERT_STACKS, ((111,), I32), ((), I32), ((), I32)],
@@ -311,6 +336,62 @@ def test_smallthinker_decode_reads_cache_and_experts_in_place_on_v5e(v5e, monkey
     assert memory.temp_size_in_bytes < ST_E * ST_D * ST_F * 2  # under one matrix of a layer's experts
 
 
+@pytest.mark.parametrize("program", ["decode", 256])
+def test_olmo_hybrid_engine_programs_at_the_published_widths_on_v5e(v5e, monkeypatch, program):
+    """The engine's decode step and 256-row prefill chunk of one period (three
+    linear-attention layers and a full one) at the published widths, 32 slots
+    of 2048, compiled for the described chip: the decode step runs
+    `gdn_decode` on the state stack and `flash_decode` on the rows in place
+    (every leaf stays where it was donated), the chunk runs `gdn_chunk`, and
+    neither copies a layer's weights before it reads them: handed a period's
+    slice of the stacks, XLA did (1.5 GB of temporaries a decode step at 16
+    layers)."""
+    from accelerate_tpu import serving
+    from accelerate_tpu.generation import GenerationConfig
+    from accelerate_tpu.native.pallas import dispatch
+
+    monkeypatch.setattr(dispatch, "_on_tpu", lambda: True)
+    monkeypatch.setenv("ATX_SERVE_CAPACITY_CHECK", "off")
+    monkeypatch.setattr(serving.engine.jax, "device_put", lambda x, device=None: x)  # shapes only
+    cfg = olmo_hybrid.OlmoHybridConfig(vocab_size=1024, n_layers=4, max_seq_len=2048)
+    slots, max_len = 32, 2048
+    engine = serving.Engine(
+        lambda p, t, c: olmo_hybrid.forward_with_cache(p, t, c, cfg),
+        lambda b, m: jax.eval_shape(lambda: olmo_hybrid.init_cache(cfg, b, m)),
+        jax.eval_shape(lambda: olmo_hybrid.init(jax.random.PRNGKey(0), cfg, BF16)),
+        GenerationConfig(), slots=slots, buckets=(64, 128, 256), max_len=max_len,
+    )
+    assert engine.prefix_cache is None and engine.stats["prefix_cache_off_for_state"] == 1
+    assert engine._ring_len == 0 and engine._state_names == ("state_conv", "state_gdn")
+    one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree
+    )
+    args = engine.abstract_decode_args()
+    with force_kernels("on"):
+        if program == "decode":
+            compiled = jax.jit(engine._decode_fn, donate_argnums=(3,)).lower(*on_chip(args)).compile()
+        else:
+            scalar = lambda dt: jax.ShapeDtypeStruct((), dt)
+            chunk = (args[0], jax.ShapeDtypeStruct((1, program), np.int32), args[3],
+                     scalar(np.int32), scalar(np.int32), scalar(np.int32), scalar(np.uint32))
+            compiled = jax.jit(engine._prefill_fn, donate_argnums=(2,)).lower(*on_chip(chunk)).compile()
+    named = set(re.findall(r'kernel_metadata=\{\s*"kernel":"(\w+)"', compiled.as_text()))
+    memory = compiled.memory_analysis()
+    cache_bytes = sum(
+        int(np.prod(s.shape)) * s.dtype.itemsize for s in jax.tree.leaves(args[3])
+    )
+    assert memory.alias_size_in_bytes >= cache_bytes  # every leaf stays where it was donated
+    one_matrix = cfg.d_model * cfg.d_ff * 2  # a layer's smallest feed-forward matrix, bf16
+    if program == "decode":
+        assert named == {"gdn_decode", "flash_decode"} and engine.stats["decode_in_place"] == 1
+        assert memory.temp_size_in_bytes < one_matrix
+    else:
+        assert named == {"gdn_chunk"}
+        # scores of 30 heads x 256 queries x 2048 rows in float32 are 63 MB; no weights beside them
+        assert memory.temp_size_in_bytes < 6 * one_matrix
+
+
 def _s8_results(text, dims):
     """(shape, opcode) of every instruction of a compiled module whose result
     is an int8 array of ``dims`` (a regular expression on ``a,b,c``), other
@@ -410,7 +491,7 @@ def test_every_pallas_call_in_the_package_is_named():
                         for v in spread
                     )
                     sites.append((os.path.relpath(path, REPO), node.lineno, ok))
-    assert len(sites) == 10, sites
+    assert len(sites) == 12, sites
     assert [s for s in sites if not s[2]] == []
 
 
@@ -456,7 +537,9 @@ _TINY_TRAIN = dict(
 def _rehearse_kernel_parity():
     errors = chip_smoke.kernel_parity_phase(seq_len=64, cache_len=64, head_dim=16, seed=0)
     chip_smoke.check_parity(errors)
-    assert set(errors) == {"flash_fwd_bwd", "flash_decode", "flash_decode_int8_kv", "int8_matmul"}
+    assert set(errors) == {
+        "flash_fwd_bwd", "flash_decode", "flash_decode_int8_kv", "int8_matmul", "gdn_chunk", "gdn_decode",
+    }
 
 
 def _rehearse_serve():

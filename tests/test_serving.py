@@ -720,3 +720,152 @@ class TestCancelAndValidation:
         rid = eng.submit(np.arange(32, dtype=np.int32) % 61, 6)
         (c,) = eng.run_until_idle()
         assert c.rid == rid and c.n_new == 6
+
+
+# --------------------------------------------------------- state leaves
+class TestStateLeaves:
+    """A family whose cache holds recurrent states beside KV rows
+    (`models/olmo_hybrid.py`): a state has no cursor to hide behind, so the
+    engine tells the forward which rows are real and which slots decode, and
+    zeroes a slot's states inside its first chunk's program."""
+
+    from accelerate_tpu.models import olmo_hybrid as family
+
+    CFG = family.OlmoHybridConfig.tiny(n_layers=4, vocab_size=97)
+
+    @pytest.fixture(scope="class")
+    def hybrid(self):
+        params = self.family.init(jax.random.PRNGKey(2), self.CFG)
+        # decays and write strengths that differ token by token
+        params["linear"]["w_ab"] = params["linear"]["w_ab"] * 3.0
+        return params
+
+    def _engine(self, params, **kw):
+        kw.setdefault("slots", 3)
+        kw.setdefault("buckets", (16, 32))
+        kw.setdefault("max_len", 160)
+        cfg = self.CFG
+        return serving.Engine(
+            lambda p, t, c: self.family.forward_with_cache(p, t, c, cfg),
+            lambda b, m: self.family.init_cache(cfg, b, m, jnp.float32),
+            params, GenerationConfig(max_new_tokens=8), **kw,
+        )
+
+    @staticmethod
+    def _prompts():
+        rng = np.random.default_rng(0)
+        return [rng.integers(0, 97, n) for n in (5, 20, 70, 33, 90)]
+
+    def _serve(self, engine, prompts=None, max_new=8):
+        for p in prompts or self._prompts():
+            engine.submit(p, max_new_tokens=max_new)
+        done = {c.rid: c for c in engine.run_until_idle()}
+        return [done[i].tokens for i in sorted(done)]
+
+    @pytest.mark.parametrize(
+        "kw", [{"buckets": (64,)}, {"buckets": (16, 64), "decode_block": 4}, {"slots": 1}, {"prefill_interleave": 0}],
+        ids=["one-bucket", "decode-block-4", "one-slot", "prefill-first"],
+    )
+    def test_same_tokens_whatever_the_bucket_split_block_or_company(self, hybrid, kw):
+        """The bucket split of a prompt (and so where the state is handed
+        over and how long the pad tail is), the decode block and which
+        other requests share the batch change no token."""
+        want = self._serve(self._engine(hybrid))
+        got = self._serve(self._engine(hybrid, **kw))
+        for a, b in zip(want, got):
+            np.testing.assert_array_equal(a, b)
+
+    def test_matches_solo_generate(self, hybrid):
+        got = self._serve(self._engine(hybrid))
+        for prompt, tokens in zip(self._prompts(), got):
+            out = self.family.generate(
+                hybrid, jnp.asarray(prompt)[None], self.CFG, generation_config=GenerationConfig(max_new_tokens=8)
+            )
+            np.testing.assert_array_equal(np.asarray(out)[0, len(prompt):], tokens)
+
+    def test_a_neighbours_decode_steps_do_not_touch_a_slot_in_mid_prefill(self, hybrid):
+        engine = self._engine(hybrid, slots=2, buckets=(16,))
+        rng = np.random.default_rng(1)
+        engine.submit(rng.integers(0, 97, 10), max_new_tokens=40)  # decodes from the second step on
+        engine.step()
+        engine.submit(rng.integers(0, 97, 80), max_new_tokens=4)  # five chunks, a decode step between each
+        while engine._slots[1] is None or engine._slots[1].cursor == 0:
+            engine.step()  # until its first chunk has run
+        assert engine.actions[-1] == "prefill" and not engine._slots[1].decoding
+        state = lambda: {n: np.asarray(engine._kv[n][:, 1]) for n in engine._state_names}
+        before = state()
+        assert any(np.abs(v).max() > 0 for v in before.values())
+        engine.step()
+        assert engine.actions[-1] == "decode"  # the neighbour's step; slot 1 rode along
+        for name, value in state().items():
+            np.testing.assert_array_equal(value, before[name])
+        engine.step()
+        assert engine.actions[-1] == "prefill"
+        assert any(np.abs(state()[n] - before[n]).max() > 0 for n in before)
+
+    def test_a_reused_slot_starts_from_zero(self, hybrid):
+        """One slot, two requests one after the other: the second finds the
+        first's state in its slot and must not see it."""
+        prompts = self._prompts()[:2]
+        engine = self._engine(hybrid, slots=1)
+        both = self._serve(engine, prompts)
+        alone = self._serve(self._engine(hybrid, slots=1), prompts[1:])
+        np.testing.assert_array_equal(both[1], alone[0])
+        assert engine.stats["state_resets"] == 2
+
+    def test_a_finished_request_leaves_its_states_in_its_slot(self, hybrid):
+        """`Completion.slot` names the slot and `Engine.slot_state` reads it:
+        the states after the prompt and all but the last token, whatever the
+        neighbours went on to do; a cache of rows only has none."""
+        engine = self._engine(hybrid)
+        for p in self._prompts():
+            engine.submit(p, max_new_tokens=8)
+        done = sorted(engine.run_until_idle(), key=lambda c: c.rid)
+        assert all(0 <= c.slot < 3 for c in done) and len({c.slot for c in done[:3]}) == 3
+        last = done[-1]  # nobody took its slot after it
+        left = engine.slot_state(last.slot)
+        assert set(left) == {"state_conv", "state_gdn"}
+        seen = np.concatenate([last.prompt, last.tokens[:-1]])[None]
+        cache = self.family.init_cache(self.CFG, 1, 160, jnp.float32)
+        _, cache = self.family.forward_with_cache(hybrid, jnp.asarray(seen), cache, self.CFG)
+        for name, value in left.items():
+            np.testing.assert_allclose(value, np.asarray(cache[name])[:, 0], atol=1e-4)
+
+    def test_counters_and_the_prefix_cache(self, hybrid, monkeypatch):
+        engine = self._engine(hybrid)
+        assert engine._state_names == ("state_conv", "state_gdn") and engine._ring_len == 0
+        assert engine.prefix_cache is None and engine.stats["prefix_cache_off_for_state"] == 1
+        self._serve(engine)
+        s = engine.stats
+        assert s["state_slots_live"] == 3 * s["decode_slot_steps"]  # three linear layers
+        assert s["state_slots_touched"] == 3 * 3 * s["decode_steps"]  # the XLA lowering selects over every slot
+        assert s["state_rows_real"] == s["prompt_tokens"] == 218
+        assert s["state_rows_padded"] == sum(engine.prefill_signatures) and s["state_resets"] == 5
+        assert len(engine.abstract_decode_args()) == 7
+        with pytest.raises(ValueError, match="state leaves"):
+            self._engine(hybrid, prefix_cache=True)
+        from accelerate_tpu.native.pallas.dispatch import force_kernels
+
+        with force_kernels("interpret"):
+            kernel = self._engine(hybrid)
+            got = self._serve(kernel)
+        assert kernel.stats["state_slots_touched"] == kernel.stats["state_slots_live"] == s["state_slots_live"]
+        for a, b in zip(self._serve(self._engine(hybrid)), got):
+            np.testing.assert_array_equal(a, b)
+
+    def test_a_cache_without_state_leaves_traces_what_it_did(self, params):
+        """No new operand and no new key for the families that keep rows only."""
+        engine = _engine(params)
+        assert engine._state_names == () and len(engine.abstract_decode_args()) == 6
+        assert engine.slot_state(0) == {}
+        seen = {}
+
+        def spy(p, t, c):
+            seen[t.shape[1]] = set(c)
+            return _apply(p, t, c)
+
+        spied = serving.Engine(spy, _init_cache, params, GenerationConfig(max_new_tokens=3), slots=2, buckets=(8,), max_len=32)
+        spied.submit(np.arange(5), max_new_tokens=3)
+        spied.run_until_idle()
+        assert seen == {8: {"k", "v", "length"}, 1: {"k", "v", "length"}}
+        assert spied.stats["state_slots_live"] == spied.stats["state_resets"] == spied.stats["prefix_cache_off_for_state"] == 0
