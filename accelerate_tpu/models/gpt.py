@@ -54,6 +54,7 @@ from .layers import (
     mlp_gelu,
     remat_policy,
     rope_frequencies,
+    traced_once_for,
     truncated_normal_init,
 )
 
@@ -421,9 +422,10 @@ def forward_with_cache(
         return (x, kv, i + 1), None
 
     kv = {"k": cache["k"], "v": cache["v"]}
-    (x, kv, _), _ = jax.lax.scan(
-        scan_body, (x, kv, jnp.zeros((), jnp.int32)), params["blocks"]
-    )
+    with traced_once_for(config.n_layers):
+        (x, kv, _), _ = jax.lax.scan(
+            scan_body, (x, kv, jnp.zeros((), jnp.int32)), params["blocks"]
+        )
     x = layer_norm(x, params["lnf_scale"], params["lnf_bias"], config.norm_eps)
     logits = _logits(params, x, config)
     return logits, dict(kv, length=start + T_new)

@@ -511,22 +511,50 @@ def position_masked_attention(
 _ATTENTION_PATHS: contextvars.ContextVar[list[str] | None] = contextvars.ContextVar(
     "atx_cached_attention_paths", default=None
 )
+_LAYERS_A_TRACE: contextvars.ContextVar[int] = contextvars.ContextVar(
+    "atx_layers_a_trace", default=1
+)
 
 
 @contextlib.contextmanager
 def record_attention_paths():
-    """Collect which lowering every `cached_attention` call traced inside the
-    block took: ``"in_place"`` (the flash-decode kernel reads the stacked
-    cache where it lies) or ``"sliced"`` (one layer sliced out of the stack
-    for `dot_product_attention`). Trace-time bookkeeping only — the serving
-    engine wraps its decode program's trace in it, so a silent fall to the
-    sliced lowering shows in ``Engine.stats['decode_in_place']``."""
+    """Collect which lowering every layer's attention over a stacked cache
+    took in the program traced inside the block, an entry a layer:
+    ``"in_place"`` (a kernel reads the stacked cache where it lies:
+    `flash_decode` for one query row, `flash_prefill` for a chunk) or
+    ``"sliced"`` (one layer sliced out of the stack for
+    `dot_product_attention`). Trace-time bookkeeping only: the serving
+    engine wraps the traces of its decode and prefill programs in it, so a
+    silent fall to the sliced lowering shows in
+    ``Engine.stats['decode_in_place']`` / ``['prefill_attn_*']``."""
     paths: list[str] = []
     token = _ATTENTION_PATHS.set(paths)
     try:
         yield paths
     finally:
         _ATTENTION_PATHS.reset(token)
+
+
+@contextlib.contextmanager
+def traced_once_for(layers: int):
+    """Round a `lax.scan` whose body, traced once, runs ``layers`` layers'
+    attention for every call it makes: what is noted inside the block counts
+    ``layers`` times. The scan's caller says so because only it knows; a
+    loop that says nothing has its traced calls counted one each."""
+    token = _LAYERS_A_TRACE.set(layers)
+    try:
+        yield
+    finally:
+        _LAYERS_A_TRACE.reset(token)
+
+
+def note_attention_path(path: str) -> None:
+    """Tell `record_attention_paths` that an attention over a layer-stacked
+    cache took ``path``. `cached_attention` reports its own; a call site
+    that slices a layer out by hand (a ring's chunk) reports here."""
+    paths = _ATTENTION_PATHS.get()
+    if paths is not None:
+        paths.extend([path] * _LAYERS_A_TRACE.get())
 
 
 _STEP_COUNTS: contextvars.ContextVar[dict[str, jax.Array] | None] = contextvars.ContextVar(
@@ -568,25 +596,40 @@ def cached_attention(
     mask: jax.Array | None = None,
     lengths: jax.Array | None = None,
     window: int | None = None,
+    start: jax.Array | None = None,
+    q_block: int | None = None,
 ) -> jax.Array:
     """Attention of ``q`` (B, T_new, H, h) over layer ``i`` of the stacked
-    cache leaves ``kv`` (see `cache_append`).
+    cache leaves ``kv`` (see `cache_append`), the new rows already written.
 
-    One cache layout, two lowerings, chosen by what the shapes show. A decode
-    step (one query token, cursor-masked by ``lengths``, no sliding window)
-    whose shapes the `native/pallas` flash-decode kernel supports, with the
-    `decode_attn` kernel enabled, reads the stack in place: the kernel takes
-    the whole buffers and the layer index, int8 dequant included. Everything
-    else (prefill, a window, kernels off, the CPU) slices layer ``i`` out of
-    the stack and runs the reference `dot_product_attention` with the full
-    cache ``mask``.
+    One cache layout, one entry for every family, and the lowering chosen by
+    what the shapes show. With the kernel's name enabled
+    (`native/pallas/dispatch.py`) and its ``supported()`` saying yes, a
+    kernel reads the stack in place, handed the whole buffers and the layer
+    index:
+
+    - a decode step (one query token, cursor-masked by ``lengths``, no
+      sliding window): `flash_decode`, int8 dequant included;
+    - a prefill chunk (more than one query row, written at the cursor
+      ``start`` of a full-length leaf, no sliding window): `flash_prefill`,
+      which visits the rows up to the cursor and no further.
+
+    Everything else (an int8 cache's chunk, a window, a few speculative
+    rows, a length no block divides, kernels off, the CPU) slices layer
+    ``i`` out of the stack and runs the reference `dot_product_attention`
+    with the full cache ``mask``; a chunk that hands no ``mask`` states its
+    visibility by ``start`` alone (key row ``j`` is seen from the query at
+    ``start + r`` iff ``j <= start + r``), computed ``q_block`` queries at a
+    time (`position_masked_attention`).
 
     A ring of W rows (a window layer's leaves, `cache_write_stacked`) is
     read the same way after the step's row is written: every row it holds is
     inside the window, the order of rows inside a softmax does not matter
     (rotary is applied before the write), so ``lengths`` is
     ``min(cursor + 1, W)``, ``mask`` its (B, 1, W) counterpart, ``i`` the
-    layer's index among the window layers, and ``window`` stays None."""
+    layer's index among the window layers, and ``window`` stays None. A
+    ring's chunk is not this function's: its rows are not in position
+    order."""
     out = None
     if lengths is not None and window is None:
         from ..native.pallas.decode_attention import maybe_flash_decode
@@ -595,9 +638,13 @@ def cached_attention(
             q, kv["k"], kv["v"], lengths, i,
             k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"),
         )
-    paths = _ATTENTION_PATHS.get()
-    if paths is not None:
-        paths.append("sliced" if out is None else "in_place")
+    elif lengths is None and start is not None:
+        from ..native.pallas.prefill_attention import maybe_flash_prefill
+
+        out = maybe_flash_prefill(
+            q, kv["k"], kv["v"], start, i, quantized="k_scale" in kv, window=window
+        )
+    note_attention_path("sliced" if out is None else "in_place")
     if out is not None:
         return out
     layer = {
@@ -610,7 +657,13 @@ def cached_attention(
         # Dequant stays elementwise on the sliced layer: HBM reads int8.
         k = dequant_kv(k, layer["k_scale"], q.dtype)
         v = dequant_kv(v, layer["v_scale"], q.dtype)
-    return dot_product_attention(q, k.astype(q.dtype), v.astype(q.dtype), mask=mask)
+    k, v = k.astype(q.dtype), v.astype(q.dtype)
+    if mask is None:
+        return position_masked_attention(
+            q, k, v, cache_positions(start, q.shape[1], q.shape[0]),
+            jnp.arange(k.shape[1], dtype=jnp.int32), q_block=q_block,
+        )
+    return dot_product_attention(q, k, v, mask=mask)
 
 
 # ------------------------------------------------------------------ attention block

@@ -48,6 +48,7 @@ from .layers import (
     RopeScaling,
     rope_frequencies,
     swiglu,
+    traced_once_for,
     truncated_normal_init,
 )
 
@@ -484,8 +485,9 @@ def forward_with_cache(
     x = params["embed"][tokens]
     # Decode steps (T_new == 1) may take the Pallas flash-decode kernel:
     # valid prefix per row after the write is positions[:, 0] + 1 (works for
-    # the scalar cursor and the per-row speculative cursors alike). Prefill
-    # and sliding-window configs always run the masked reference attention.
+    # the scalar cursor and the per-row speculative cursors alike). A chunk
+    # (T_new > 1) may take the flash-prefill kernel by its cursor ``start``;
+    # sliding-window configs always run the masked reference attention.
     decode_lengths = positions[:, 0] + 1 if T_new == 1 else None
 
     # The layer-stacked cache rides the scan CARRY at every length: a step
@@ -505,7 +507,7 @@ def forward_with_cache(
         k = apply_rope(k, cos, sin, positions)
         kv = cache_append(kv, i, k, v, start)
         attn = cached_attention(
-            q, kv, i, mask=mask, lengths=decode_lengths, window=config.sliding_window
+            q, kv, i, mask=mask, lengths=decode_lengths, window=config.sliding_window, start=start
         )
         x = x + attention_out(block["attn"], attn)
         h = rms_norm(x, block["mlp_norm"], config.norm_eps)
@@ -513,7 +515,8 @@ def forward_with_cache(
         return (x + ffn_out, kv, i + 1), None
 
     kv = {name: buf for name, buf in cache.items() if name != "length"}
-    (x, kv, _), _ = jax.lax.scan(scan_body, (x, kv, jnp.zeros((), jnp.int32)), blocks)
+    with traced_once_for(config.n_layers):
+        (x, kv, _), _ = jax.lax.scan(scan_body, (x, kv, jnp.zeros((), jnp.int32)), blocks)
     new_cache = dict(kv, length=start + T_new)
     x = rms_norm(x, params["final_norm"], config.norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, _lm_head(params, config).astype(x.dtype))

@@ -56,6 +56,7 @@ from .layers import (
     position_masked_attention,
     report_step_counts,
     rms_norm,
+    traced_once_for,
     truncated_normal_init,
 )
 
@@ -277,9 +278,10 @@ def _scan_periods(params, x, config, state, layer_fn):
             x, state = layer_fn(kind, block, i, x, state)
         return (x, state, p + 1), None
 
-    (x, state, _), _ = jax.lax.scan(
-        body, (x, state, jnp.zeros((), jnp.int32)), None, length=config.n_layers // P
-    )
+    with traced_once_for(config.n_layers // P):
+        (x, state, _), _ = jax.lax.scan(
+            body, (x, state, jnp.zeros((), jnp.int32)), None, length=config.n_layers // P
+        )
     return x, state
 
 
@@ -375,8 +377,9 @@ def forward_with_cache(
 
     A decode step (T_new == 1) runs the rule's recurrent form on the state
     stack in place (`gdn_decode`) and `layers.cached_attention` (the
-    flash-decode kernel); a chunk runs the chunkwise form and attends by
-    position against the full layers' buffers after its write."""
+    flash-decode kernel); a chunk runs the chunkwise form and, after its
+    write, attends against the full layers' buffers through
+    `layers.cached_attention` by the cursor (the flash-prefill kernel)."""
     B, T_new = tokens.shape
     start = cache["length"]
     valid, decoding = cache.get("valid"), cache.get("decoding")
@@ -400,15 +403,8 @@ def forward_with_cache(
             if decode:
                 attn = cached_attention(q, leaves, i, mask=mask, lengths=lengths)
             else:
-                rows = {
-                    n: jax.lax.dynamic_index_in_dim(buf, i, 0, keepdims=False)
-                    .reshape(B, -1, *k.shape[2:]).astype(q.dtype)
-                    for n, buf in leaves.items()
-                }
-                attn = position_masked_attention(
-                    q, rows["k"], rows["v"], positions,
-                    jnp.arange(rows["k"].shape[1], dtype=jnp.int32),
-                    q_block=config.attention_q_block,
+                attn = cached_attention(
+                    q, leaves, i, start=start, q_block=config.attention_q_block
                 )
             mixed = attention_out(block["attn"], attn)
             return _finish_block(block, x, mixed, config), {**state, "kv": leaves}

@@ -57,9 +57,11 @@ from .layers import (
     cache_positions,
     cached_attention,
     init_attention,
+    note_attention_path,
     position_masked_attention,
     report_step_counts,
     ring_positions,
+    traced_once_for,
     rms_norm,
     rope_frequencies,
     truncated_normal_init,
@@ -239,9 +241,10 @@ def _scan_periods(params, x, config, state, layer_fn):
             counts = {name: counts[name] + c[name] for name in counts}
         return (x, state, counts, p + 1), None
 
-    (x, state, counts, _), _ = jax.lax.scan(
-        body, (x, state, zero, jnp.zeros((), jnp.int32)), scanned
-    )
+    with traced_once_for(config.n_layers // P):
+        (x, state, counts, _), _ = jax.lax.scan(
+            body, (x, state, zero, jnp.zeros((), jnp.int32)), scanned
+        )
     return x, state, counts
 
 
@@ -313,10 +316,12 @@ def forward_with_cache(
 
     A decode step (T_new == 1) writes its row and reads both kinds of cache
     through `layers.cached_attention` (the flash-decode kernel, in place, with
-    ``min(cursor + 1, window)`` valid rows on a ring). A chunk attends by
-    position (`layers.position_masked_attention`): a full layer against its
-    buffer after the write, a windowed layer against the ring as it stood
-    plus the chunk's own rows, and then writes."""
+    ``min(cursor + 1, window)`` valid rows on a ring). A chunk's full layers
+    write and then go through `layers.cached_attention` too, by the cursor
+    (the flash-prefill kernel, in place and up to the cursor, or a layer
+    sliced out for `layers.position_masked_attention`); a windowed layer
+    attends by position against the ring as it stood plus the chunk's own
+    rows, and then writes."""
     B, T_new = tokens.shape
     start = cache["length"]
     valid = cache.get("valid")
@@ -371,19 +376,12 @@ def forward_with_cache(
                     positions, k_pos,
                     window=config.sliding_window, q_block=config.attention_q_block,
                 )
+                note_attention_path("sliced")
                 leaves = cache_append(leaves, i, k, v, start, ring=True, valid=valid)
             else:
                 leaves = cache_append(leaves, i, k, v, start)
-                rows = {
-                    n: jax.lax.dynamic_index_in_dim(buf, i, 0, keepdims=False).reshape(
-                        B, -1, *k.shape[2:]
-                    ).astype(q.dtype)
-                    for n, buf in leaves.items()
-                }
-                out = position_masked_attention(
-                    q, rows["k"], rows["v"], positions,
-                    jnp.arange(rows["k"].shape[1], dtype=jnp.int32),
-                    q_block=config.attention_q_block,
+                out = cached_attention(
+                    q, leaves, i, start=start, q_block=config.attention_q_block
                 )
             return out, {**state, windowed: leaves}
 

@@ -2,7 +2,9 @@
 
 Custom TPU kernels for the hot paths the XLA lowerings leave on the
 table: flash-decode attention over the slot KV cache (the fallback ignores
-KV-quantization bandwidth headroom), fused quantize→dot→rescale matmuls for
+KV-quantization bandwidth headroom), a prefill chunk's flash attention over
+the same cache up to its cursor (the fallback scores every row of the slot
+and writes the scores out), fused quantize→dot→rescale matmuls for
 the int8/fp8 paths (fp8 round-trips through XLA's upcast), a single-pass
 fused AdamW update (the host-offloaded optimizer tier), and the grouped
 expert feed-forward of the dropless MoE layer (the fallback slices a
@@ -30,4 +32,11 @@ from .dispatch import (  # noqa: F401
 # Importing a kernel module registers it: `kernel_status()` lists every
 # kernel as soon as the package is imported, not only those a trace has
 # already reached.
-from . import decode_attention, fused_adamw, gated_delta, moe_experts, quant_matmul  # noqa: E402,F401
+from . import (  # noqa: E402,F401
+    decode_attention,
+    fused_adamw,
+    gated_delta,
+    moe_experts,
+    prefill_attention,
+    quant_matmul,
+)

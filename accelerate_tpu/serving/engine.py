@@ -391,9 +391,12 @@ class Engine:
                 # In a ring the pad tail would land on rows still in the
                 # window; a state would advance over it.
                 cache["valid"] = sample_pos + 1
-            with record_weight_paths() as weights:
+            with record_attention_paths() as paths, record_weight_paths() as weights:
                 logits, new = apply_fn(params, tokens, cache)
             self._weight_paths[tokens.shape[1]] = _in_place_and_sliced(weights)
+            self._attention_paths[tokens.shape[1]] = (
+                paths.count("in_place"), paths.count("sliced")
+            )
             kv = cache_slot_write(kv, {k: new[k] for k in row}, slot)
             last = jnp.take_along_axis(logits[0], sample_pos[None, None], axis=0)[0]
             tok = _sample(last, seed, jnp.zeros((), jnp.int32))
@@ -403,6 +406,10 @@ class Engine:
         # many of its quantized contractions read their weight stack in
         # place, and how many were handed a slice.
         self._weight_paths: dict[Any, tuple[int, int]] = {}
+        # Per prefill bucket, as traced: how many layers' attention over the
+        # cache a kernel computes on the stack where it lies (up to the
+        # cursor), and how many slice their layer out (a ring's included).
+        self._attention_paths: dict[int, tuple[int, int]] = {}
         self._decode_fn = decode_fn
         self._prefill_fn = prefill_fn
         self._decode = jax.jit(decode_fn, donate_argnums=(3,))
@@ -518,6 +525,12 @@ class Engine:
                 # one layer's matrix sliced out of it (a copy a layer).
                 "weights_in_place",
                 "weights_sliced",
+                # Layers of the prefill chunks dispatched whose attention
+                # over the cache the flash-prefill kernel computed on the
+                # stack where it lies, and those that sliced their layer out
+                # and scored every row of the slot (a ring layer's chunk too).
+                "prefill_attn_in_place",
+                "prefill_attn_sliced",
                 "prefix_cache_off_for_ring",
                 # Rows of KV a decode step had to read, summed over the
                 # decoding slots and the steps: per full-length layer, and per
@@ -943,7 +956,7 @@ class Engine:
                 self.stats["state_rows_padded"] += buf.shape[1]
             slot.cursor += real
             self.stats["prefill_chunks"] += 1
-            self._count_weight_paths(buf.shape[1])
+            self._count_paths(buf.shape[1])
             self.prefill_signatures.append(buf.shape[1])
             if self._trace:
                 _flight.record_span(
@@ -1053,7 +1066,7 @@ class Engine:
                     self.params, tokens, lengths.copy(), self._kv, seeds, steps.copy(), *extra
                 )
                 fetched.append((tokens, counts))
-                self._count_weight_paths("decode")
+                self._count_paths("decode")
                 attended = lengths + 1  # what the attention is handed, slot by slot
                 lengths[decoding] += 1
                 steps[decoding] += 1
@@ -1095,11 +1108,14 @@ class Engine:
             return attended.size * rows
         return rows_fetched(attended, rows, self._kv_row_bytes[rows])
 
-    def _count_weight_paths(self, program: Any) -> None:
+    def _count_paths(self, program: Any) -> None:
         """One dispatch of ``program``: add what its trace recorded."""
         in_place, sliced = self._weight_paths.get(program, (0, 0))
         self.stats["weights_in_place"] += in_place
         self.stats["weights_sliced"] += sliced
+        in_place, sliced = self._attention_paths.get(program, (0, 0))  # prefill buckets only
+        self.stats["prefill_attn_in_place"] += in_place
+        self.stats["prefill_attn_sliced"] += sliced
 
     def _emit(self, slot_id: int, tok: int) -> list[Completion]:
         """Record one generated token for a slot; finish/evict on EOS, a

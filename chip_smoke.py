@@ -56,9 +56,21 @@ SHARDED_RTOL = 2e-2
 SERVE_REQUESTS = ((128, 128), (24, 40), (57, 64), (200, 48), (90, 32), (16, 24))
 SERVE_ENGINE = {"slots": 4, "buckets": (64, 128), "max_len": 256}
 # A served token may fall this far short of the reference's top logit,
-# relative to it, and still count as its argmax: 4 bf16 ulps (the top two of
-# 128k random logits are ~5% apart on average, often closer).
-ARGMAX_RTOL = 2.0**-5
+# relative to it, and still count as its argmax (the top two of 128k random
+# logits are ~5% apart on average, often closer): the worst of a request's
+# 128 tokens, and their mean. The worst is a lottery of near-ties and guards
+# against a wrong token (which reads ~1); the mean is what tells a precision
+# from the next. Both lie between two readings of this check at this config,
+# through `serve_phase`'s own raise (`perf/smoke_argmax_limit.py`; my chip
+# runs, PR 35). Sound, over seeds 0-19 with a chunk's attention through
+# `flash_prefill`: worst 0.013-0.049, mean 0.0003-0.0023; over seeds 0-15
+# with it sliced as before PR 35: worst 0.017-0.047, mean 0.0003-0.0019 (the
+# 2^-5 that stood here alone refused 11 of the 20 and 6 of the 16). One
+# precision down, every matrix of the engine's weights on float8_e4m3's grid
+# and the references on the weights as made, over seeds 0-14: worst
+# 0.063-0.131, mean 0.0046-0.0155, refused 15 of 15.
+ARGMAX_RTOL = 2.0**-4
+ARGMAX_MEAN_RTOL = 0.0033
 
 _COLLECTIVE = re.compile(
     r"\b(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)(?:-start)?\("
@@ -272,6 +284,21 @@ def kernel_parity_phase(*, seq_len: int, cache_len: int, head_dim: int, seed: in
     }
     out["flash_decode_int8_kv"] = both(decode, dq, dk, dv, int8_cache)
 
+    # A prefill chunk: a quarter of a slot's rows written into layer 1 of a
+    # stack that holds other rows, at cursors that sit on no block edge.
+    rows = cache_len // 4
+    starts = np.linspace(0, cache_len - rows, slots).astype(np.int32)
+    starts[1:-1] -= 3
+    starts = jnp.asarray(starts)
+    cq, ck, cv = normal(slots, rows, 8, head_dim), normal(slots, rows, kv_heads, head_dim), normal(slots, rows, kv_heads, head_dim)
+
+    def chunk(q, k, v, kv):
+        kv = cache_append(kv, 1, k, v, starts)
+        return cached_attention(q, kv, 1, start=starts)
+
+    held = {"k": normal(2, slots, cache_len, flat), "v": normal(2, slots, cache_len, flat)}
+    out["flash_prefill"] = both(chunk, cq, ck, cv, held)
+
     x = normal(2, 8, 4 * head_dim)
     wq, w_scale = quantize_act(normal(4 * head_dim, 2, head_dim), (0,))
     out["int8_matmul"] = both(lambda x: int8_einsum("bsd,dhk->bshk", x, wq, w_scale), x)
@@ -402,11 +429,12 @@ def serve_phase(config, *, requests, engine_kwargs: dict, seed: int) -> dict:
         return np.asarray((top - chosen) / jnp.abs(top))
 
     gap = short_of_top(served)
-    if gap.max() > ARGMAX_RTOL:
+    if gap.max() > ARGMAX_RTOL or gap.mean() > ARGMAX_MEAN_RTOL:
         at = int(np.argmax(gap))
         raise RuntimeError(
-            f"served token {at} of {n_new} is not the reference argmax: its logit is "
-            f"{gap[at]:.3f} of the top logit short of it (allowed {ARGMAX_RTOL})"
+            f"served tokens are not the reference argmax: token {at} of {n_new} falls "
+            f"{gap[at]:.3f} of the top logit short of it (allowed {ARGMAX_RTOL}), the {n_new} "
+            f"fall {gap.mean():.4f} short on average (allowed {ARGMAX_MEAN_RTOL})"
         )
     if equal < n_new:
         solo_gap = float(short_of_top(np.where(np.arange(n_new) == equal, solo, served))[equal])
@@ -448,6 +476,7 @@ def serve_phase(config, *, requests, engine_kwargs: dict, seed: int) -> dict:
         "equal_to_generator": f"{equal} of {n_new}" + ("" if equal == n_new else ", then a tie"),
         "reference_argmax_or_tie": f"{n_new} of {n_new}",
         "worst_short_of_top_logit": round(float(gap.max()), 5),
+        "mean_short_of_top_logit": round(float(gap.mean()), 5),
         "decode_compile_s": round(decode_compile_s, 2),
         "decode_tpu_custom_calls": decode_text.count("tpu_custom_call"),
         "peak_bytes_in_use": _peak_bytes(device),

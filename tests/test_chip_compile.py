@@ -29,7 +29,9 @@ sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
 from accelerate_tpu.models import llama, olmo_hybrid, smallthinker  # noqa: E402
-from accelerate_tpu.native.pallas import decode_attention, fused_adamw, gated_delta, moe_experts, quant_matmul  # noqa: E402
+from accelerate_tpu.native.pallas import (  # noqa: E402
+    decode_attention, fused_adamw, gated_delta, moe_experts, prefill_attention, quant_matmul,
+)
 from accelerate_tpu.ops import gated_delta as gated_delta_ops  # noqa: E402
 from accelerate_tpu.native.pallas.dispatch import force_kernels  # noqa: E402
 from accelerate_tpu.ops.flash_attention import flash_attention  # noqa: E402
@@ -89,6 +91,25 @@ def _decode_case(slots, slot_len, heads=H, kv_heads=K, int8=False):
     if int8:
         operands += [(stack[:3] + (kv_heads,), BF16)] * 2
     return (_decode_int8 if int8 else _decode, operands, ["flash_decode"])
+
+
+def _prefill(q, k, v, start, layer):
+    return prefill_attention.flash_prefill(q, k, v, start, layer, interpret=False)
+
+
+def _prefill_case(rows, slot_len, heads=H, kv_heads=K, batch=None):
+    """A KERNELS entry: `flash_prefill` of a ``rows``-row chunk over the
+    engine's batch-1 row view of a two-layer cache of ``slot_len`` rows of
+    ``kv_heads`` heads of 128; with ``batch``, over that many rows of the
+    cache, each at a cursor of its own (a `Generator`'s prefill, speculative
+    decoding's verification)."""
+    kv = ((2, batch or 1, slot_len, kv_heads * 128), BF16)
+    q = ((batch or 1, rows, heads, 128), BF16)
+    assert prefill_attention.supported(
+        jax.ShapeDtypeStruct(*q), jax.ShapeDtypeStruct(*kv), compiled=True
+    )
+    start = ((), I32) if batch is None else ((batch,), I32)
+    return (_prefill, [q, kv, kv, start, ((), I32)], ["flash_prefill"])
 
 
 def _int8_matmul(x, w, s):
@@ -193,6 +214,19 @@ KERNELS = {
         [((111 * 128, ST_D), BF16), *_EXPERT_STACKS, ((111,), I32), ((), I32), ((), I32)],
         ["moe_experts"],
     ),
+    # A prefill chunk against the serve cells' slots, every bucket of each:
+    # long (32/8 heads, 8192 rows), chat (1024 rows), mixed (28/4, 16,384
+    # rows: seven query heads folded into a tile), hybrid (30/30, 2048 rows).
+    **{f"flash_prefill_long_{s}": _prefill_case(s, 8192) for s in (256, 1024)},
+    **{f"flash_prefill_chat_{s}": _prefill_case(s, 1024) for s in (32, 64, 128, 256)},
+    **{f"flash_prefill_mixed_{s}": _prefill_case(s, 16384, heads=28, kv_heads=4) for s in (256, 1024)},
+    **{f"flash_prefill_hybrid_{s}": _prefill_case(s, 2048, heads=30, kv_heads=30) for s in (64, 128, 256)},
+    # The widest tile the module picks: eight query heads a kv head, 2048 rows a product.
+    "flash_prefill_group_8": _prefill_case(1024, 8192, heads=64, kv_heads=8),
+    # Callers other than the engine, at cursors a row: a `Generator` prefills
+    # four 128-token prompts into 256 rows; a verification chunk of 16 rows.
+    "flash_prefill_generator_4x128": _prefill_case(128, 256, batch=4),
+    "flash_prefill_verify_3x16": _prefill_case(16, 1024, batch=3),
 }
 
 
@@ -387,8 +421,10 @@ def test_olmo_hybrid_engine_programs_at_the_published_widths_on_v5e(v5e, monkeyp
         assert named == {"gdn_decode", "flash_decode"} and engine.stats["decode_in_place"] == 1
         assert memory.temp_size_in_bytes < one_matrix
     else:
-        assert named == {"gdn_chunk"}
-        # scores of 30 heads x 256 queries x 2048 rows in float32 are 63 MB; no weights beside them
+        # The full layers' chunk attends through `flash_prefill`: every one in place.
+        assert named == {"gdn_chunk", "flash_prefill"}
+        assert engine._attention_paths[program] == (cfg.n_layers - cfg.n_linear_layers, 0)
+        # no scores of 30 heads x 256 queries x 2048 rows (63 MB in float32), no weights copied
         assert memory.temp_size_in_bytes < 6 * one_matrix
 
 
@@ -464,6 +500,53 @@ def test_engine_reads_int8_weight_stacks_in_place_on_v5e(v5e, monkeypatch, progr
     assert len(_s8_results(text, one_matrix)) >= 4
 
 
+@pytest.mark.parametrize("kernels, in_place", [("on", 1), ("off", 0)])
+def test_long_cell_prefill_chunk_holds_no_scores_of_the_whole_slot_on_v5e(v5e, monkeypatch, kernels, in_place):
+    """The long cell's 1024-row prefill program (int8 weights at the Mistral
+    widths, two layers, slots of 8192 rows), compiled for the described chip:
+    with `flash_prefill` no instruction's result is as large as the scores of
+    1024 queries of every kv head against the slot's 8192 rows (XLA's
+    lowering wrote ``bf16[8,8192,1024,4]`` and read it back), and the engine
+    counts every layer in place. With the kernels off the same check finds
+    the scores: it has teeth."""
+    from accelerate_tpu import serving
+    from accelerate_tpu.generation import GenerationConfig
+    from accelerate_tpu.native.pallas import dispatch
+    from accelerate_tpu.ops.int8 import with_int8_compute
+    from accelerate_tpu.utils.quantization import quantize_pytree
+
+    rows, slot_len, layers = 1024, 8192, 2
+    monkeypatch.setattr(dispatch, "_on_tpu", lambda: True)
+    monkeypatch.setenv("ATX_SERVE_CAPACITY_CHECK", "off")
+    monkeypatch.setattr(serving.engine.jax, "device_put", lambda x, device=None: x)  # shapes only
+    cfg = llama.LlamaConfig(
+        vocab_size=512, d_model=D, n_layers=layers, num_heads=H, num_kv_heads=K, head_dim=HD,
+        d_ff=FF, max_seq_len=slot_len,
+    )
+    params = jax.eval_shape(lambda: quantize_pytree(llama.init(jax.random.PRNGKey(0), cfg, BF16)))
+    one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree
+    )
+    engine = serving.Engine(
+        with_int8_compute(lambda p, t, c: llama.forward_with_cache(p, t, c, cfg)),
+        lambda b, m: jax.eval_shape(lambda: llama.init_cache(cfg, b, m)),
+        params, GenerationConfig(), slots=2, buckets=(256, rows), max_len=slot_len, prefix_cache=False,
+    )
+    decode_args = on_chip(engine.abstract_decode_args())
+    scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=one_chip)
+    chunk = jax.ShapeDtypeStruct((1, rows), I32, sharding=one_chip)
+    args = (decode_args[0], chunk, decode_args[3], *map(scalar, (I32, I32, I32, jnp.uint32)))
+    with force_kernels(kernels):
+        text = jax.jit(engine._prefill_fn, donate_argnums=(2,)).lower(*args).compile().as_text()
+    assert engine._attention_paths[rows] == ((layers, 0) if in_place else (0, layers))
+    assert len(set(re.findall(r"%(flash_prefill[.\d]*) = ", text))) == in_place
+    scores = rows * slot_len * K  # one query head of each kv head against every row
+    results = re.findall(r"= (?:bf16|f32|pred)\[([0-9,]+)\]", text)
+    as_large = [dims for dims in results if np.prod([int(d) for d in dims.split(",")]) >= scores]
+    assert bool(as_large) != bool(in_place), as_large[:5]
+
+
 def test_every_pallas_call_in_the_package_is_named():
     """Each `pl.pallas_call(` site takes its keywords from
     `tuned_call_kwargs`, which always gives it a name and the metadata that
@@ -491,7 +574,7 @@ def test_every_pallas_call_in_the_package_is_named():
                         for v in spread
                     )
                     sites.append((os.path.relpath(path, REPO), node.lineno, ok))
-    assert len(sites) == 12, sites
+    assert len(sites) == 13, sites
     assert [s for s in sites if not s[2]] == []
 
 
@@ -538,7 +621,8 @@ def _rehearse_kernel_parity():
     errors = chip_smoke.kernel_parity_phase(seq_len=64, cache_len=64, head_dim=16, seed=0)
     chip_smoke.check_parity(errors)
     assert set(errors) == {
-        "flash_fwd_bwd", "flash_decode", "flash_decode_int8_kv", "int8_matmul", "gdn_chunk", "gdn_decode",
+        "flash_fwd_bwd", "flash_decode", "flash_decode_int8_kv", "flash_prefill", "int8_matmul", "gdn_chunk",
+        "gdn_decode",
     }
 
 

@@ -20,7 +20,7 @@ from accelerate_tpu.native.pallas import (
     kernel_status,
     pallas_available,
 )
-from accelerate_tpu.native.pallas import decode_attention, fused_adamw, quant_matmul
+from accelerate_tpu.native.pallas import decode_attention, fused_adamw, prefill_attention, quant_matmul
 
 pytestmark = pytest.mark.skipif(
     not pallas_available(), reason="jax.experimental.pallas not importable"
@@ -67,10 +67,148 @@ class TestDispatch:
 
     def test_kernel_status_lists_all_kernels(self):
         names = {row["kernel"] for row in kernel_status()}
-        assert {"decode_attn", "int8_matmul", "fp8_matmul", "fused_adamw"} <= names
+        assert {"decode_attn", "prefill_attn", "int8_matmul", "fp8_matmul", "fused_adamw"} <= names
         with force_kernels("interpret"):
             modes = {row["kernel"]: row["mode"] for row in kernel_status()}
         assert modes["decode_attn"] == "interpret"
+
+
+# ===================================================== flash-prefill attention
+def _chunk_reference(q, k, v, start):
+    """What the three families' chunk lowering computes: key row ``j`` is
+    seen from the query at ``start + r`` iff ``j <= start + r``."""
+    from accelerate_tpu.models.layers import cache_positions, dot_product_attention
+
+    B, S = q.shape[:2]
+    positions = cache_positions(jnp.asarray(start, jnp.int32), S, B)
+    mask = jnp.arange(k.shape[1])[None, None, :] <= positions[:, :, None]
+    return dot_product_attention(q, k, v, mask=mask)
+
+
+def _chunk_operands(dtype, B, S, T, K, group, h=16, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, S, K * group, h), dtype)
+    k = jax.random.normal(ks[1], (B, T, K, h), dtype)
+    v = jax.random.normal(ks[2], (B, T, K, h), dtype)
+    return q, k, v
+
+
+class TestFlashPrefill:
+    T, S = 2048, 64  # four blocks of 512, four query tiles of 16
+
+    @pytest.mark.parametrize("group", [4, 7, 1])
+    @pytest.mark.parametrize(
+        "start",
+        [0, 512, 1000, 2048 - 64, (0, 1000, 1536)],
+        ids=["start-0", "block-multiple", "unaligned-1000", "slot-end", "per-row-cursors"],
+    )
+    def test_parity_with_the_masked_reference_on_a_layer_of_the_stack(self, start, group):
+        """A chunk written at the cursor against layer 1 of a stack of three
+        whose other layers and whose rows past the chunk hold noise: a wrong
+        layer, or a row past the cursor, cannot pass. The pad tail of a
+        bucket is computed like any row (its own keys are in the cache)."""
+        B = 3 if isinstance(start, tuple) else 1
+        q, k, v = _chunk_operands(jnp.float32, B, self.S, self.T, K=2, group=group)
+        starts = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (B,))
+        seen = jnp.arange(self.T)[None, :] < (starts[:, None] + self.S)
+        loud = lambda x: jnp.where(seen[:, :, None, None], x, 50.0)  # noqa: E731
+        out = jax.jit(
+            lambda i, at: prefill_attention.flash_prefill(
+                q, _stacked(loud(k), 1, 3), _stacked(loud(v), 1, 3), at, i, tiles=(16, 512), interpret=True
+            )
+        )(jnp.int32(1), jnp.asarray(start, jnp.int32))
+        np.testing.assert_allclose(out, _chunk_reference(q, k, v, start), rtol=2e-5, atol=2e-5)
+
+    def test_bf16_rows_and_several_query_tiles(self):
+        """bf16 rows (products in bf16, softmax in float32) and a chunk of
+        four query tiles whose last blocks differ."""
+        q, k, v = _chunk_operands(jnp.bfloat16, 1, 256, 1024, K=2, group=2, h=32)
+        out = prefill_attention.flash_prefill(
+            q, _stacked(k), _stacked(v), 300, tiles=(64, 128), interpret=True
+        )
+        ref = _chunk_reference(q, k, v, 300)
+        np.testing.assert_allclose(
+            out.astype(jnp.float32), ref.astype(jnp.float32), rtol=3e-2, atol=3e-2
+        )
+
+    def test_the_grid_ends_at_the_cursor(self):
+        """A query tile's blocks end at the one that holds its last position:
+        the steps follow the cursor, not the slot's length."""
+        bq, bk, T = 16, 512, 2048
+        for start, blocks in ((0, [1, 1, 1, 1]), (500, [2, 2, 2, 2]), (1000, [2, 3, 3, 3]), (T - 64, [4] * 4)):
+            ends = start + (jnp.arange(4, dtype=jnp.int32) + 1) * bq
+            n, row, _ = decode_attention.live_steps(ends, T, bk)
+            assert np.bincount(np.asarray(row)[: int(n)], minlength=4).tolist() == blocks, start
+
+    @pytest.mark.parametrize(
+        "case",
+        ["int8-kv", "window", "five-rows", "length-no-block-divides", "one-row"],
+    )
+    def test_unsupported_shapes_decline_and_the_caller_gets_the_reference(self, case):
+        """`supported()` says no, `maybe_flash_prefill` hands back None, and
+        `layers.cached_attention` then returns the sliced lowering's values
+        with the kernels forced to interpret mode."""
+        from accelerate_tpu.models.layers import cached_attention, quantize_kv, record_attention_paths
+
+        S, T = {"five-rows": (5, 64), "length-no-block-divides": (16, 1001), "one-row": (1, 64)}.get(case, (16, 64))
+        q, k, v = _chunk_operands(jnp.float32, 2, S, T, K=2, group=2)
+        kv = {"k": _stacked(k, 1, 2), "v": _stacked(v, 1, 2)}
+        kw = {}
+        if case == "int8-kv":
+            (kq, ksc), (vq, vsc) = quantize_kv(k), quantize_kv(v)
+            kv = {
+                "k": _stacked(kq, 1, 2), "v": _stacked(vq, 1, 2),
+                "k_scale": _stacked(ksc, 1, 2), "v_scale": _stacked(vsc, 1, 2),
+            }
+            kw["quantized"] = True
+        if case == "window":
+            kw["window"] = 8
+        assert not prefill_attention.supported(q, kv["k"], **kw)
+        positions = 7 + jnp.arange(S)[None, :]
+        mask = jnp.arange(T)[None, None, :] <= positions[:, :, None]
+        if case == "window":
+            mask = mask & (jnp.arange(T)[None, None, :] > positions[:, :, None] - 8)
+        mask = jnp.broadcast_to(mask, (2, S, T))
+        with force_kernels("interpret"), record_attention_paths() as paths:
+            assert prefill_attention.maybe_flash_prefill(q, kv["k"], kv["v"], 7, 1, **kw) is None
+            got = cached_attention(q, kv, jnp.int32(1), mask=mask, start=jnp.int32(7), window=kw.get("window"))
+        with force_kernels("off"):
+            want = cached_attention(q, kv, jnp.int32(1), mask=mask, start=jnp.int32(7), window=kw.get("window"))
+        assert paths == ["sliced"]
+        np.testing.assert_array_equal(got, want)
+
+    def test_cached_attention_takes_the_kernel_for_a_chunk_and_says_so(self):
+        """The one entry the three families share: a chunk with a cursor and
+        no window reads the stack in place under the kernel, with or without
+        the caller's mask, and slices under "off"; both agree."""
+        from accelerate_tpu.models.layers import (
+            cached_attention, note_attention_path, record_attention_paths, traced_once_for,
+        )
+
+        q, k, v = _chunk_operands(jnp.float32, 1, 32, 256, K=2, group=2)
+        kv = {"k": _stacked(k, 2, 3), "v": _stacked(v, 2, 3)}
+        start = jnp.int32(100)
+        with force_kernels("interpret"), record_attention_paths() as paths:
+            got = cached_attention(q, kv, jnp.int32(2), start=start, q_block=8)
+        with force_kernels("off"), record_attention_paths() as off:
+            want = cached_attention(q, kv, jnp.int32(2), start=start, q_block=8)
+        assert paths == ["in_place"] and off == ["sliced"]
+        # A scan's caller says how many layers its body's one trace stands
+        # for; nothing is read off the stacks, so a ring as long and as deep
+        # as the full-length stack beside it is still counted on its own.
+        ring = {name: jnp.zeros_like(buf) for name, buf in kv.items()}
+        with force_kernels("interpret"), record_attention_paths() as paths:
+            with traced_once_for(3):
+                jax.lax.scan(
+                    lambda c, _: (c + cached_attention(q, kv, jnp.int32(2), start=start).sum(), None),
+                    jnp.zeros(()), None, length=3,
+                )
+                assert ring["k"].shape == kv["k"].shape
+                note_attention_path("sliced")
+            cached_attention(q, kv, jnp.int32(0), start=start)  # outside a scan: one a call
+        assert (paths.count("in_place"), paths.count("sliced")) == (4, 3)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(got, _chunk_reference(q, k, v, 100), rtol=2e-5, atol=2e-5)
 
 
 # ====================================================== flash-decode attention

@@ -869,3 +869,84 @@ class TestStateLeaves:
         spied.run_until_idle()
         assert seen == {8: {"k", "v", "length"}, 1: {"k", "v", "length"}}
         assert spied.stats["state_slots_live"] == spied.stats["state_resets"] == spied.stats["prefix_cache_off_for_state"] == 0
+
+
+def _family_engines():
+    """name -> (engine factory, vocabulary, (in place, sliced) a chunk under
+    the kernels): the tiny llama, SmallThinker and Olmo-Hybrid configs."""
+    from accelerate_tpu.models import olmo_hybrid, smallthinker
+
+    def llama_engine():
+        cfg = llama.LlamaConfig.tiny(vocab_size=61, max_seq_len=256, n_layers=3)
+        return (
+            lambda p, t, c: llama.forward_with_cache(p, t, c, cfg),
+            lambda b, m: llama.init_cache(cfg, b, m, dtype=jnp.float32),
+            llama.init(jax.random.PRNGKey(1), cfg),
+        )
+
+    def smallthinker_engine():
+        cfg = smallthinker.SmallThinkerConfig.tiny()  # two full layers, six rings of 16 rows
+        return (
+            lambda p, t, c: smallthinker.forward_with_cache(p, t, c, cfg),
+            lambda b, m: smallthinker.init_cache(cfg, b, m, dtype=jnp.float32),
+            smallthinker.init(jax.random.PRNGKey(1), cfg),
+        )
+
+    def hybrid_engine():
+        cfg = olmo_hybrid.OlmoHybridConfig.tiny(n_layers=8, vocab_size=97)  # two full layers of eight
+        return (
+            lambda p, t, c: olmo_hybrid.forward_with_cache(p, t, c, cfg),
+            lambda b, m: olmo_hybrid.init_cache(cfg, b, m, jnp.float32),
+            olmo_hybrid.init(jax.random.PRNGKey(2), cfg),
+        )
+
+    return {
+        "llama": (llama_engine, 61, (3, 0)),
+        "smallthinker": (smallthinker_engine, 256, (2, 6)),
+        "olmo_hybrid": (hybrid_engine, 97, (2, 0)),
+    }
+
+
+@pytest.mark.parametrize("family", ["llama", "smallthinker", "olmo_hybrid"])
+def test_prefill_chunks_attend_through_the_kernel_and_the_stats_say_so(family):
+    """Prompts of several chunks through an engine whose chunks attend
+    through `flash_prefill` (interpret mode) and one with the kernels off:
+    the same greedy tokens, and ``prefill_attn_in_place`` /
+    ``prefill_attn_sliced`` grow at every chunk by the layers whose attention
+    read the stack in place and those that sliced theirs out (a ring
+    layer's chunk is sliced by hand and counts as sliced)."""
+    from accelerate_tpu import telemetry
+    from accelerate_tpu.native.pallas.dispatch import force_kernels
+
+    make, vocab, (in_place, sliced) = _family_engines()[family]
+    apply_fn, init_cache, weights = make()
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, vocab, n).astype(np.int32) for n in (70, 21, 45)]
+    served = {}
+    for mode in ("interpret", "off"):
+        # Slots of 96 rows are three blocks of 32; chunks of 16 and 32 rows.
+        engine = serving.Engine(
+            apply_fn, init_cache, weights, GenerationConfig(), slots=2, buckets=(16, 32), max_len=96
+        )
+        with force_kernels(mode):
+            for prompt in prompts:
+                engine.submit(prompt, max_new_tokens=6)
+            done = {c.rid: c.tokens for c in engine.run_until_idle()}
+        served[mode] = [done[i] for i in sorted(done)]
+        chunks = engine.stats["prefill_chunks"]
+        assert chunks == 3 + 1 + 2  # 32 + 32 + 16; 32; 32 + 16
+        want = (in_place, sliced) if mode == "interpret" else (0, in_place + sliced)
+        assert (engine.stats["prefill_attn_in_place"], engine.stats["prefill_attn_sliced"]) == tuple(
+            n * chunks for n in want
+        )
+        exported = {
+            m["name"]: [s["value"] for s in m["series"] if s["labels"] == engine.stats.labels]
+            for m in telemetry.snapshot()["metrics"]
+            if m["name"].startswith("serve_prefill_attn_")
+        }
+        assert exported == {
+            "serve_prefill_attn_in_place": [want[0] * chunks],
+            "serve_prefill_attn_sliced": [want[1] * chunks],
+        }
+    for a, b in zip(served["interpret"], served["off"]):
+        np.testing.assert_array_equal(a, b)
