@@ -48,6 +48,7 @@ from .layers import (
     cached_attention,
     cross_entropy_loss,
     dot_product_attention,
+    head_logits,
     init_attention,
     init_mlp_gelu,
     layer_norm,
@@ -376,7 +377,8 @@ def forward_with_cache(
     """Incremental forward (prefill or decode) against the KV cache.
 
     ``cache['length']`` is a scalar or per-row (B,) cursor — same contract
-    as `llama.forward_with_cache` (per-row = speculative decoding)."""
+    as `llama.forward_with_cache` (per-row = speculative decoding), and so is
+    ``cache['logits_at']`` (`layers.head_logits`)."""
     B, T_new = tokens.shape
     max_len = cache["k"].shape[2]
     start = cache["length"]
@@ -426,8 +428,9 @@ def forward_with_cache(
         (x, kv, _), _ = jax.lax.scan(
             scan_body, (x, kv, jnp.zeros((), jnp.int32)), params["blocks"]
         )
-    x = layer_norm(x, params["lnf_scale"], params["lnf_bias"], config.norm_eps)
-    logits = _logits(params, x, config)
+    logits = head_logits(x, cache, lambda h: _logits(
+        params, layer_norm(h, params["lnf_scale"], params["lnf_bias"], config.norm_eps), config
+    ))
     return logits, dict(kv, length=start + T_new)
 
 

@@ -588,6 +588,54 @@ def report_step_counts(counts: dict[str, jax.Array]) -> None:
             into[name] = into[name] + value if name in into else value
 
 
+_HEAD_ROWS: contextvars.ContextVar[list[int] | None] = contextvars.ContextVar(
+    "atx_head_rows", default=None
+)
+
+
+@contextlib.contextmanager
+def record_head_rows():
+    """Collect, an entry a call, the rows `head_logits` hands the vocabulary
+    head when the cache names the row it wants (``cache['logits_at']``); a
+    call without the key projects every row and notes nothing. Trace-time
+    bookkeeping only: the serving engine wraps its prefill programs' traces
+    in it for ``Engine.stats['prefill_head_rows']``."""
+    rows: list[int] = []
+    token = _HEAD_ROWS.set(rows)
+    try:
+        yield rows
+    finally:
+        _HEAD_ROWS.reset(token)
+
+
+def head_logits(x: jax.Array, cache: dict[str, jax.Array], head) -> jax.Array:
+    """Logits of the last layer's hidden states ``x`` (B, S, D) through
+    ``head``, the family's final norm and vocabulary projection.
+
+    Without ``cache['logits_at']``, every row's: (B, S, vocab). With it, a
+    (B,) int32 row index into the chunk, only that row's, gathered before
+    the norm: (B, 1, vocab). A negative index wants no row (its logits are
+    zeros), and where no row of the batch wants one the head does not run:
+    `jax.lax.cond` returns zeros of the same shape and dtype, in the same
+    program. The serving engine's prefill chunks set the key, since only a
+    prompt's last chunk is sampled, and at one row."""
+    at = cache.get("logits_at")
+    if at is None:
+        return head(x)
+    wanted = (at >= 0)[:, None, None]
+    rows = x[jnp.arange(x.shape[0]), jnp.maximum(at, 0)][:, None]
+    noted = _HEAD_ROWS.get()
+    if noted is not None:
+        noted.append(x.shape[0])
+    out = jax.eval_shape(head, rows)
+    return jax.lax.cond(
+        jnp.any(wanted),
+        lambda r: jnp.where(wanted, head(r), 0),
+        lambda r: jnp.zeros(out.shape, out.dtype),
+        rows,
+    )
+
+
 def cached_attention(
     q: jax.Array,
     kv: dict[str, jax.Array],

@@ -41,6 +41,7 @@ from .layers import (
     cached_attention,
     cross_entropy_loss,
     dot_product_attention,
+    head_logits,
     init_attention,
     init_swiglu,
     rms_norm,
@@ -465,6 +466,8 @@ def forward_with_cache(
     plain decode contract) or shape (B,) (per-row cursors: speculative
     decoding commits a different number of tokens per row, `speculative.py`).
     Positions, masks, and the KV writes are all per-row in the latter case.
+    ``cache['logits_at']``, where present, names the one row of each batch
+    row whose logits are wanted (`layers.head_logits`).
     """
     B, T_new = tokens.shape
     max_len = cache["k"].shape[2]
@@ -514,12 +517,15 @@ def forward_with_cache(
         ffn_out, _ = _ffn(block, h, config)  # aux unused at inference
         return (x + ffn_out, kv, i + 1), None
 
-    kv = {name: buf for name, buf in cache.items() if name != "length"}
+    kv = {name: buf for name, buf in cache.items() if name not in ("length", "logits_at")}
     with traced_once_for(config.n_layers):
         (x, kv, _), _ = jax.lax.scan(scan_body, (x, kv, jnp.zeros((), jnp.int32)), blocks)
     new_cache = dict(kv, length=start + T_new)
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, _lm_head(params, config).astype(x.dtype))
+    logits = head_logits(x, cache, lambda h: jnp.einsum(
+        "bsd,dv->bsv",
+        rms_norm(h, params["final_norm"], config.norm_eps),
+        _lm_head(params, config).astype(h.dtype),
+    ))
     return logits, new_cache
 
 

@@ -50,6 +50,7 @@ from .layers import (
     cache_positions,
     cached_attention,
     gated_mlp,
+    head_logits,
     init_attention,
     init_swiglu,
     matmul_einsum,
@@ -374,6 +375,8 @@ def forward_with_cache(
     present on a decode step, is the (B,) mask of the rows that are decoding:
     the others' states stay as they are (a row in mid-prefill rides along in
     the engine's decode steps, and a state has no cursor to hide behind).
+    ``cache['logits_at']``, where present, names the one row of each batch
+    row whose logits are wanted (`layers.head_logits`).
 
     A decode step (T_new == 1) runs the rule's recurrent form on the state
     stack in place (`gdn_decode`) and `layers.cached_attention` (the
@@ -434,8 +437,11 @@ def forward_with_cache(
         new_cache.update(state_gdn=carry["S"], state_conv=carry["conv"])
         if decode:
             report_step_counts({"state_slots_touched": carry["touched"]})
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, _lm_head(params, config).astype(x.dtype))
+    logits = head_logits(x, cache, lambda h: jnp.einsum(
+        "bsd,dv->bsv",
+        rms_norm(h, params["final_norm"], config.norm_eps),
+        _lm_head(params, config).astype(h.dtype),
+    ))
     return logits, new_cache
 
 

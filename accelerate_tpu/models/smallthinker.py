@@ -56,6 +56,7 @@ from .layers import (
     cache_append,
     cache_positions,
     cached_attention,
+    head_logits,
     init_attention,
     note_attention_path,
     position_masked_attention,
@@ -312,7 +313,8 @@ def forward_with_cache(
     (a scalar, or (B,) per-row cursors) and attend against what is cached.
     Returns (logits, new_cache). ``cache['valid']``, where present, is the
     number of real rows among the new ones (the rest is a bucket's pad tail):
-    the rings write only those.
+    the rings write only those. ``cache['logits_at']``, where present, names
+    the one row of each batch row whose logits are wanted (`layers.head_logits`).
 
     A decode step (T_new == 1) writes its row and reads both kinds of cache
     through `layers.cached_attention` (the flash-decode kernel, in place, with
@@ -394,8 +396,11 @@ def forward_with_cache(
     report_step_counts(counts)
     new_cache = {"length": start + T_new, **kv[False]}
     new_cache.update({n + "_win": buf for n, buf in kv[True].items()})
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, _lm_head(params, config).astype(x.dtype))
+    logits = head_logits(x, cache, lambda h: jnp.einsum(
+        "bsd,dv->bsv",
+        rms_norm(h, params["final_norm"], config.norm_eps),
+        _lm_head(params, config).astype(h.dtype),
+    ))
     return logits, new_cache
 
 

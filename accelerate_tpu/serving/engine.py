@@ -60,6 +60,7 @@ from ..models.layers import (
     cache_slot_write,
     is_state_leaf,
     record_attention_paths,
+    record_head_rows,
     record_step_counts,
 )
 from ..native.pallas.decode_attention import rows_fetched
@@ -223,6 +224,17 @@ class Engine:
     a ring does not have (``stats['prefix_cache_off_for_ring']``; asking for
     it explicitly is a ValueError).
 
+    A prefill chunk's cache also carries ``cache['logits_at']``, a (B,)
+    int32 row index into the chunk: the row the engine samples (the chunk's
+    last real row) on a prompt's last chunk, -1 on every other chunk, whose
+    token is never read. A forward that honours it projects that row alone
+    through its final norm and vocabulary head and returns (B, 1, vocab)
+    logits, and for -1 does not run the head at all
+    (`models/layers.py:head_logits`); one that ignores it returns every row
+    and stays correct: the engine takes the row it samples from either.
+    ``stats['prefill_head_rows']`` counts the rows the head projected. Decode
+    steps never carry the key: every slot samples.
+
     A family with recurrent layers keeps **state leaves** beside its rows
     (`models/layers.py:is_state_leaf`: named ``state`` / ``state_<what>``,
     laid out ``(L, B, ...)`` with no row axis). A state has no cursor to hide
@@ -375,31 +387,40 @@ class Engine:
             # for a dense model) leaves with the tokens: one fetch a step.
             return (nxt, counts), {k: new[k] for k in kv}
 
-        def prefill_fn(params, tokens, kv, slot, cursor, sample_pos, seed):
+        def prefill_fn(params, tokens, kv, slot, cursor, sample_pos, seed, last):
             """One bucket-padded prompt chunk into slot row ``slot`` at
             ``cursor``. Pad-tail KV lands at positions >= the row's real
             cursor — never attended before decode overwrites it. The
             returned token (sampled at ``sample_pos``, the chunk's last
-            REAL position) is only meaningful on a prompt's final chunk."""
+            REAL position) is only meaningful on a prompt's final chunk
+            (``last``): on any other the forward is asked for no logits."""
             row = cache_slot_view(kv, slot)
             for name in self._state_names:
                 # A request's first chunk starts from no state, whatever the
                 # slot's last occupant left: zeroed here, in this program.
                 row[name] = jnp.where(cursor == 0, jnp.zeros_like(row[name]), row[name])
-            cache = dict(row, length=cursor)
+            cache = dict(row, length=cursor, logits_at=jnp.where(last, sample_pos, -1)[None])
             if self._ring_len or self._state_names:
                 # In a ring the pad tail would land on rows still in the
                 # window; a state would advance over it.
                 cache["valid"] = sample_pos + 1
-            with record_attention_paths() as paths, record_weight_paths() as weights:
+            with (
+                record_attention_paths() as paths,
+                record_weight_paths() as weights,
+                record_head_rows() as head,
+            ):
                 logits, new = apply_fn(params, tokens, cache)
-            self._weight_paths[tokens.shape[1]] = _in_place_and_sliced(weights)
-            self._attention_paths[tokens.shape[1]] = (
-                paths.count("in_place"), paths.count("sliced")
-            )
+            S = tokens.shape[1]
+            self._weight_paths[S] = _in_place_and_sliced(weights)
+            self._attention_paths[S] = (paths.count("in_place"), paths.count("sliced"))
+            # Rows the head projects on a prompt's last chunk and on any
+            # other: every row, every time, where the forward ignored the key.
+            self._head_rows[S] = (sum(head), 0) if head else (S, S)
             kv = cache_slot_write(kv, {k: new[k] for k in row}, slot)
-            last = jnp.take_along_axis(logits[0], sample_pos[None, None], axis=0)[0]
-            tok = _sample(last, seed, jnp.zeros((), jnp.int32))
+            logits = logits[0]
+            if logits.shape[0] > 1:  # the forward returned every row
+                logits = jnp.take_along_axis(logits, sample_pos[None, None], axis=0)
+            tok = _sample(logits[0], seed, jnp.zeros((), jnp.int32))
             return tok, kv
 
         # Per program ("decode", or a prefill bucket's rows), as traced: how
@@ -410,6 +431,9 @@ class Engine:
         # cache a kernel computes on the stack where it lies (up to the
         # cursor), and how many slice their layer out (a ring's included).
         self._attention_paths: dict[int, tuple[int, int]] = {}
+        # Per prefill bucket, as traced: rows the vocabulary head projects on
+        # a prompt's last chunk, and on any other chunk.
+        self._head_rows: dict[int, tuple[int, int]] = {}
         self._decode_fn = decode_fn
         self._prefill_fn = prefill_fn
         self._decode = jax.jit(decode_fn, donate_argnums=(3,))
@@ -531,6 +555,10 @@ class Engine:
                 # and scored every row of the slot (a ring layer's chunk too).
                 "prefill_attn_in_place",
                 "prefill_attn_sliced",
+                # Rows of the prefill chunks dispatched that the vocabulary
+                # head projected: one on a prompt's last chunk, none on the
+                # others, where the forward honours ``cache['logits_at']``.
+                "prefill_head_rows",
                 "prefix_cache_off_for_ring",
                 # Rows of KV a decode step had to read, summed over the
                 # decoding slots and the steps: per full-length layer, and per
@@ -924,6 +952,7 @@ class Engine:
         slot_id = self._prefill_order[0]
         slot = self._slots[slot_id]
         buf, real = slot.chunks.pop(0)
+        last = not slot.chunks
         if slot.t_prefill0 == 0.0:
             slot.t_prefill0 = time.perf_counter()
             submitted = getattr(slot.req, "submitted_at", 0.0)
@@ -949,6 +978,7 @@ class Engine:
                 np.int32(slot.cursor),
                 np.int32(real - 1),
                 np.uint32(slot.req.seed),
+                np.bool_(last),
             )
             if self._state_names:
                 self.stats["state_resets"] += slot.cursor == 0
@@ -957,6 +987,7 @@ class Engine:
             slot.cursor += real
             self.stats["prefill_chunks"] += 1
             self._count_paths(buf.shape[1])
+            self.stats["prefill_head_rows"] += self._head_rows[buf.shape[1]][0 if last else 1]
             self.prefill_signatures.append(buf.shape[1])
             if self._trace:
                 _flight.record_span(
@@ -967,7 +998,7 @@ class Engine:
                     tokens=int(real),
                     compile_miss=self._prefill._cache_size() > compiles_before,
                 )
-        if slot.chunks:
+        if not last:
             return []  # more prompt to go; tok was a throwaway
         with _telemetry.span("serve_fetch"):
             first = int(tok)  # the host waits for the device here

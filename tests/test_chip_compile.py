@@ -370,6 +370,51 @@ def test_smallthinker_decode_reads_cache_and_experts_in_place_on_v5e(v5e, monkey
     assert memory.temp_size_in_bytes < ST_E * ST_D * ST_F * 2  # under one matrix of a layer's experts
 
 
+@pytest.mark.parametrize("key", ["honoured", "withheld"])
+def test_smallthinker_prefill_chunk_projects_no_vocabulary_wide_logits_on_v5e(v5e, monkeypatch, key):
+    """The mixed cell's 1024-row prefill program (one period at the published
+    widths, the whole 151,936-row vocabulary), compiled for the described
+    chip: with ``cache['logits_at']`` honoured the head's one row sits in a
+    conditional and no instruction's result holds a vocabulary row for each
+    of the chunk's rows (``bf16[1024,151936]``, 311 MB, which a forward that
+    projects every row writes every chunk); with the key withheld from the forward the
+    same check finds them: it has teeth."""
+    from accelerate_tpu import serving
+    from accelerate_tpu.generation import GenerationConfig
+    from accelerate_tpu.native.pallas import dispatch
+
+    monkeypatch.setattr(dispatch, "_on_tpu", lambda: True)
+    monkeypatch.setenv("ATX_SERVE_CAPACITY_CHECK", "off")
+    monkeypatch.setattr(serving.engine.jax, "device_put", lambda x, device=None: x)  # shapes only
+    cfg = smallthinker.SmallThinkerConfig(n_layers=4, window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1))
+    rows, vocab = 1024, cfg.vocab_size
+
+    def apply_fn(p, t, c):
+        if key == "withheld":
+            c = {k: v for k, v in c.items() if k != "logits_at"}
+        return smallthinker.forward_with_cache(p, t, c, cfg)
+
+    engine = serving.Engine(
+        apply_fn,
+        lambda b, m: jax.eval_shape(lambda: smallthinker.init_cache(cfg, b, m)),
+        jax.eval_shape(lambda: smallthinker.init(jax.random.PRNGKey(0), cfg, BF16)),
+        GenerationConfig(), slots=16, buckets=(256, rows), max_len=16384,
+    )
+    one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
+    decode_args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), engine.abstract_decode_args()
+    )
+    scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=one_chip)
+    chunk = jax.ShapeDtypeStruct((1, rows), I32, sharding=one_chip)
+    args = (decode_args[0], chunk, decode_args[3], *map(scalar, (I32, I32, I32, jnp.uint32, jnp.bool_)))
+    with force_kernels("on"):
+        text = jax.jit(engine._prefill_fn, donate_argnums=(2,)).lower(*args).compile().as_text()
+    every_row = re.findall(rf"= \w+\[(?:1,)?{rows},{vocab}\]", text)
+    assert bool(every_row) == (key == "withheld"), every_row[:3]
+    assert (" conditional(" in text) == (key == "honoured")
+    assert engine._head_rows[rows] == ((1, 0) if key == "honoured" else (rows, rows))
+
+
 @pytest.mark.parametrize("program", ["decode", 256])
 def test_olmo_hybrid_engine_programs_at_the_published_widths_on_v5e(v5e, monkeypatch, program):
     """The engine's decode step and 256-row prefill chunk of one period (three
@@ -408,7 +453,8 @@ def test_olmo_hybrid_engine_programs_at_the_published_widths_on_v5e(v5e, monkeyp
         else:
             scalar = lambda dt: jax.ShapeDtypeStruct((), dt)
             chunk = (args[0], jax.ShapeDtypeStruct((1, program), np.int32), args[3],
-                     scalar(np.int32), scalar(np.int32), scalar(np.int32), scalar(np.uint32))
+                     scalar(np.int32), scalar(np.int32), scalar(np.int32), scalar(np.uint32),
+                     scalar(np.bool_))
             compiled = jax.jit(engine._prefill_fn, donate_argnums=(2,)).lower(*on_chip(chunk)).compile()
     named = set(re.findall(r'kernel_metadata=\{\s*"kernel":"(\w+)"', compiled.as_text()))
     memory = compiled.memory_analysis()
@@ -482,7 +528,7 @@ def test_engine_reads_int8_weight_stacks_in_place_on_v5e(v5e, monkeypatch, progr
             else:
                 scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=one_chip)
                 chunk = jax.ShapeDtypeStruct((1, program), I32, sharding=one_chip)
-                args = (decode_args[0], chunk, decode_args[3], *map(scalar, (I32, I32, I32, jnp.uint32)))
+                args = (decode_args[0], chunk, decode_args[3], *map(scalar, (I32, I32, I32, jnp.uint32, jnp.bool_)))
                 fn, donate = engine._prefill_fn, 2
             text = jax.jit(fn, donate_argnums=(donate,)).lower(*args).compile().as_text()
         return text, engine._weight_paths[program]
@@ -536,7 +582,7 @@ def test_long_cell_prefill_chunk_holds_no_scores_of_the_whole_slot_on_v5e(v5e, m
     decode_args = on_chip(engine.abstract_decode_args())
     scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=one_chip)
     chunk = jax.ShapeDtypeStruct((1, rows), I32, sharding=one_chip)
-    args = (decode_args[0], chunk, decode_args[3], *map(scalar, (I32, I32, I32, jnp.uint32)))
+    args = (decode_args[0], chunk, decode_args[3], *map(scalar, (I32, I32, I32, jnp.uint32, jnp.bool_)))
     with force_kernels(kernels):
         text = jax.jit(engine._prefill_fn, donate_argnums=(2,)).lower(*args).compile().as_text()
     assert engine._attention_paths[rows] == ((layers, 0) if in_place else (0, layers))

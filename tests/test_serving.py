@@ -25,7 +25,7 @@ import pytest
 
 from accelerate_tpu import serving
 from accelerate_tpu.generation import GenerationConfig, Generator
-from accelerate_tpu.models import gpt, llama
+from accelerate_tpu.models import gpt, layers, llama
 from accelerate_tpu.utils.environment import patch_environment
 
 CFG = llama.LlamaConfig.tiny(vocab_size=61, max_seq_len=256, num_heads=4, num_kv_heads=2)
@@ -226,6 +226,7 @@ class TestCompileDiscipline:
                 scalar(np.int32),
                 scalar(np.int32),
                 scalar(np.uint32),
+                scalar(np.bool_),
             )
 
         report = analysis.lint_step(
@@ -854,7 +855,8 @@ class TestStateLeaves:
             np.testing.assert_array_equal(a, b)
 
     def test_a_cache_without_state_leaves_traces_what_it_did(self, params):
-        """No new operand and no new key for the families that keep rows only."""
+        """No new operand and no state key for the families that keep rows
+        only; a prefill chunk names the one row it samples (``logits_at``)."""
         engine = _engine(params)
         assert engine._state_names == () and len(engine.abstract_decode_args()) == 6
         assert engine.slot_state(0) == {}
@@ -867,7 +869,7 @@ class TestStateLeaves:
         spied = serving.Engine(spy, _init_cache, params, GenerationConfig(max_new_tokens=3), slots=2, buckets=(8,), max_len=32)
         spied.submit(np.arange(5), max_new_tokens=3)
         spied.run_until_idle()
-        assert seen == {8: {"k", "v", "length"}, 1: {"k", "v", "length"}}
+        assert seen == {8: {"k", "v", "length", "logits_at"}, 1: {"k", "v", "length"}}
         assert spied.stats["state_slots_live"] == spied.stats["state_resets"] == spied.stats["prefix_cache_off_for_state"] == 0
 
 
@@ -892,6 +894,14 @@ def _family_engines():
             smallthinker.init(jax.random.PRNGKey(1), cfg),
         )
 
+    def gpt_engine():
+        cfg = gpt.GPTConfig.tiny(vocab_size=67, max_seq_len=256)
+        return (
+            lambda p, t, c: gpt.forward_with_cache(p, t, c, cfg),
+            lambda b, m: gpt.init_cache(cfg, b, m, dtype=jnp.float32),
+            gpt.init(jax.random.PRNGKey(1), cfg),
+        )
+
     def hybrid_engine():
         cfg = olmo_hybrid.OlmoHybridConfig.tiny(n_layers=8, vocab_size=97)  # two full layers of eight
         return (
@@ -902,12 +912,13 @@ def _family_engines():
 
     return {
         "llama": (llama_engine, 61, (3, 0)),
+        "gpt": (gpt_engine, 67, (0, 2)),  # its chunks hand `cached_attention` no cursor
         "smallthinker": (smallthinker_engine, 256, (2, 6)),
         "olmo_hybrid": (hybrid_engine, 97, (2, 0)),
     }
 
 
-@pytest.mark.parametrize("family", ["llama", "smallthinker", "olmo_hybrid"])
+@pytest.mark.parametrize("family", ["llama", "gpt", "smallthinker", "olmo_hybrid"])
 def test_prefill_chunks_attend_through_the_kernel_and_the_stats_say_so(family):
     """Prompts of several chunks through an engine whose chunks attend
     through `flash_prefill` (interpret mode) and one with the kernels off:
@@ -949,4 +960,93 @@ def test_prefill_chunks_attend_through_the_kernel_and_the_stats_say_so(family):
             "serve_prefill_attn_sliced": [want[1] * chunks],
         }
     for a, b in zip(served["interpret"], served["off"]):
+        np.testing.assert_array_equal(a, b)
+
+
+def _vocab_dots(jaxpr, vocab, in_branch=False):
+    """For every contraction in ``jaxpr`` whose result is ``vocab`` wide:
+    whether it sits inside a branch of a `cond`."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and eqn.outvars[0].aval.shape[-1] == vocab:
+            found.append(in_branch)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _vocab_dots(sub, vocab, in_branch or eqn.primitive.name == "cond")
+    return found
+
+
+@pytest.mark.parametrize("family", ["llama", "gpt", "smallthinker", "olmo_hybrid"])
+def test_a_cached_forward_projects_only_the_row_the_cache_names(family):
+    """``cache['logits_at']`` (`layers.head_logits`): a batch row's index
+    gives that row's logits alone, equal to that row of the every-row
+    forward, and -1 gives zeros. Where no row wants one, the vocabulary-wide
+    contraction sits only in the branch that is not taken; the every-row
+    forward holds it outside any branch, so the check has teeth."""
+    make, vocab, _ = _family_engines()[family]
+    apply_fn, init_cache, weights = make()
+    tokens = jnp.asarray(np.random.default_rng(0).integers(0, vocab, (2, 16)), jnp.int32)
+    cache = init_cache(2, 32)
+    forward = jax.jit(apply_fn)
+    full = np.asarray(forward(weights, tokens, cache)[0])
+    with layers.record_head_rows() as rows:  # one trace for the three calls below
+        for at in ([5, 15], [-1, 3]):
+            one, new = forward(weights, tokens, dict(cache, logits_at=jnp.asarray(at, jnp.int32)))
+            assert one.shape == (2, 1, vocab) and "logits_at" not in new
+            want = np.where(np.array(at)[:, None] < 0, 0.0, full[np.arange(2), np.maximum(at, 0)])
+            np.testing.assert_allclose(np.asarray(one)[:, 0], want, rtol=1e-5, atol=1e-5)
+        none = dict(cache, logits_at=jnp.full((2,), -1, jnp.int32))
+        np.testing.assert_array_equal(forward(weights, tokens, none)[0], np.zeros((2, 1, vocab)))
+    assert rows == [2]
+    assert _vocab_dots(jax.make_jaxpr(apply_fn)(weights, tokens, none).jaxpr, vocab) == [True]
+    assert _vocab_dots(jax.make_jaxpr(apply_fn)(weights, tokens, cache).jaxpr, vocab) == [False]
+
+
+@pytest.mark.parametrize("family", ["llama", "gpt", "smallthinker", "olmo_hybrid"])
+def test_a_prefill_chunk_projects_the_head_only_where_it_samples(family):
+    """Prompts of several chunks: an engine whose forward honours
+    ``cache['logits_at']`` emits the greedy tokens of one whose ``apply_fn``
+    withholds the key and so returns every row, from which the engine takes
+    the row it samples. A chunk asks for its last real row on a prompt's
+    last chunk and for none (-1) on the others, as the program runs it.
+    ``prefill_head_rows`` (exported ``serve_prefill_head_rows``) counts one
+    row a finished prefill with the key, and every row of every chunk
+    without it."""
+    from accelerate_tpu import telemetry
+
+    make, vocab, _ = _family_engines()[family]
+    apply_fn, init_cache, weights = make()
+    asked = []
+
+    def honoured(p, t, c):
+        if "logits_at" in c:
+            jax.debug.callback(lambda at: asked.append(int(at[0])), c["logits_at"])
+        return apply_fn(p, t, c)
+
+    withheld = lambda p, t, c: apply_fn(p, t, {k: v for k, v in c.items() if k != "logits_at"})
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, vocab, n).astype(np.int32) for n in (70, 21, 45)]
+    served, head_rows = {}, {}
+    for name, fn in (("honoured", honoured), ("withheld", withheld)):
+        engine = serving.Engine(
+            fn, init_cache, weights, GenerationConfig(), slots=2, buckets=(16, 32), max_len=96
+        )
+        for prompt in prompts:
+            engine.submit(prompt, max_new_tokens=6)
+        done = {c.rid: c.tokens for c in engine.run_until_idle()}
+        served[name] = [done[i] for i in sorted(done)]
+        head_rows[name] = engine.stats["prefill_head_rows"]
+        exported = [
+            s["value"]
+            for m in telemetry.snapshot()["metrics"] if m["name"] == "serve_prefill_head_rows"
+            for s in m["series"] if s["labels"] == engine.stats.labels
+        ]
+        assert exported == [head_rows[name]]
+        assert engine._prefill._cache_size() == 2  # one program a bucket either way
+    assert head_rows == {"honoured": len(prompts), "withheld": 32 + 32 + 16 + 32 + 32 + 16}
+    jax.effects_barrier()
+    assert sorted(asked) == [-1, -1, -1, 5, 12, 20]  # 70 = 32 + 32 + 6, 21, 45 = 32 + 13
+    for a, b in zip(served["honoured"], served["withheld"]):
         np.testing.assert_array_equal(a, b)
