@@ -71,6 +71,7 @@ from ..utils.environment import (
     get_str_from_env,
     parse_flag_from_env,
 )
+from .fetch_stalls import FetchStalls, fetch_key
 from .prefix_cache import PrefixCache
 
 __all__ = [
@@ -585,6 +586,11 @@ class Engine:
                 "state_rows_real",
                 "state_rows_padded",
                 "state_resets",  # first chunks: a slot's states zeroed
+                # Fetches that waited over 1.5 x the usual wait for the same
+                # work (`fetch_stalls.FetchStalls`), and the ms they waited
+                # beyond it.
+                "stalled_fetches",
+                "stalled_fetch_ms",
                 *MOE_COUNTS,  # the expert layer's, summed over layers and decode steps
             ),
             label="engine",
@@ -623,6 +629,11 @@ class Engine:
         # HOST dispatch only — recording never adds a device sync, so
         # greedy outputs are bit-identical with tracing on or off.
         self._trace = _flight.trace_requests_enabled()
+        # What the next fetch waits on: the programs dispatched since the
+        # last one (a prefill chunk by its bucket, "decode", "copy").
+        self._waiting_on: dict[int | str, int] = {}
+        self._fetch_seq = 0
+        self._stalls = FetchStalls()
 
     # ------------------------------------------------------------- submit
     def submit(
@@ -985,6 +996,7 @@ class Engine:
                 self.stats["state_rows_real"] += real
                 self.stats["state_rows_padded"] += buf.shape[1]
             slot.cursor += real
+            self._dispatched(int(buf.shape[1]))
             self.stats["prefill_chunks"] += 1
             self._count_paths(buf.shape[1])
             self.stats["prefill_head_rows"] += self._head_rows[buf.shape[1]][0 if last else 1]
@@ -1000,8 +1012,7 @@ class Engine:
                 )
         if not last:
             return []  # more prompt to go; tok was a throwaway
-        with _telemetry.span("serve_fetch"):
-            first = int(tok)  # the host waits for the device here
+        first = int(self._fetch(tok))
         with _telemetry.span("serve_emit"):
             self._prefill_order.popleft()
             slot.first_token_at = time.perf_counter()
@@ -1024,6 +1035,7 @@ class Engine:
                 self._kv, self._pool,
                 np.int32(slot_id), np.int32(node.row), np.int32(off), ln,
             )
+            self._dispatched("copy")
             self.copy_signatures.append(ln)
             self.stats["prefix_copy_chunks"] += 1
             off += ln
@@ -1097,6 +1109,7 @@ class Engine:
                     self.params, tokens, lengths.copy(), self._kv, seeds, steps.copy(), *extra
                 )
                 fetched.append((tokens, counts))
+                self._dispatched("decode")
                 self._count_paths("decode")
                 attended = lengths + 1  # what the attention is handed, slot by slot
                 lengths[decoding] += 1
@@ -1111,8 +1124,7 @@ class Engine:
                     self.stats["kv_rows_fetched_window"] += self._kv_rows_fetched(
                         attended, self._ring_len
                     )
-        with _telemetry.span("serve_fetch"):
-            host = jax.device_get(fetched)
+        host = self._fetch(fetched)
         out: list[Completion] = []
         with _telemetry.span("serve_emit"):
             self.stats["decode_steps"] += block
@@ -1128,6 +1140,48 @@ class Engine:
                     slot.cursor += 1
                     out.extend(self._emit(i, int(nxt[i])))
         return out
+
+    def _dispatched(self, program: int | str) -> None:
+        """Note one program dispatched for the next fetch to wait on."""
+        self._waiting_on[program] = self._waiting_on.get(program, 0) + 1
+
+    def _fetch(self, pending: Any) -> Any:
+        """Bring ``pending`` to the host: the one place a step waits for the
+        device, which by then has to finish every program dispatched since
+        the last fetch. The ``serve_fetch`` span names the fetch (``seq``)
+        and what it waits on (``chunks``, ``decodes``). A wait the stall
+        rule flags adds to ``stalled_fetches`` / ``stalled_fetch_ms`` and
+        leaves one ``fetch_stall`` record in the flight recorder, with the
+        process's CPU time over the wait: about none, the process sat
+        blocked; about the wait, some thread of it (the runtime's too) was
+        busy."""
+        waiting_on, self._waiting_on = self._waiting_on, {}
+        self._fetch_seq += 1
+        seq = self._fetch_seq
+        decodes = waiting_on.get("decode", 0)
+        chunks = sum(waiting_on.values()) - decodes - waiting_on.get("copy", 0)
+        with _telemetry.span("serve_fetch", seq=seq, chunks=chunks, decodes=decodes):
+            c0 = time.process_time()
+            t0 = time.perf_counter()
+            host = jax.device_get(pending)
+            t1 = time.perf_counter()
+        usual = self._stalls.judge(frozenset(waiting_on.items()), t1 - t0)
+        if usual is not None:
+            cpu_ms = (time.process_time() - c0) * 1e3
+            self.stats["stalled_fetches"] += 1
+            self.stats["stalled_fetch_ms"] += (t1 - t0 - usual) * 1e3
+            _flight.record_span(
+                "fetch_stall",
+                t0=t0,
+                t1=t1,
+                engine=self.stats.instance,
+                seq=seq,
+                key=fetch_key(waiting_on),
+                usual_ms=usual * 1e3,
+                wait_ms=(t1 - t0) * 1e3,
+                cpu_ms=cpu_ms,
+            )
+        return host
 
     def _kv_rows_fetched(self, attended: np.ndarray, rows: int) -> int:
         """Rows one decode step's attention copies out of a layer whose
@@ -1268,6 +1322,7 @@ class Engine:
                 self._pool, self._kv,
                 np.int32(row), np.int32(slot_id), np.int32(off), ln,
             )
+            self._dispatched("copy")
             self.copy_signatures.append(ln)
             self.stats["prefix_copy_chunks"] += 1
             off += ln

@@ -16,7 +16,9 @@ The ISSUE-15 acceptance matrix:
   `request` record per completion, its five timestamps in order;
 - **engine step phases**: in a capture the engine knows nothing of, the
   `serve_admit` / `serve_dispatch` / `serve_fetch` / `serve_emit` spans
-  tile each `serve_prefill` / `serve_decode` parent;
+  tile each `serve_prefill` / `serve_decode` parent; the fetches' `chunks`
+  and `decodes` name every program dispatched once, and a fetch made to
+  wait is counted and its `fetch_stall` record carries its span's `seq`;
 - **exactly-once semantics through failover**: a replica killed
   mid-decode leaves BOTH dispatch spans in the trace (attempt 1 and the
   retry), while stream spans still count each delivered token once;
@@ -33,12 +35,13 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import jax
 import numpy as np
 import pytest
 
-from accelerate_tpu import serving
+from accelerate_tpu import serving, telemetry
 from accelerate_tpu.commands import trace as trace_cmd
 from accelerate_tpu.generation import GenerationConfig
 from accelerate_tpu.models import llama
@@ -185,8 +188,9 @@ class TestTracedServing:
         with patch_environment(ATX_TRACE_REQUESTS="0"):
             off = self._serve(params, reqs)
         # Off is the always-on black box and nothing finer: one `request`
-        # record per completion.
-        records = flight.recorder().last()
+        # record per completion (and a `fetch_stall` record for a fetch a
+        # loaded machine made wait, which is rare and not a finer span).
+        records = [r for r in flight.recorder().last() if r["name"] != "fetch_stall"]
         assert [r["name"] for r in records] == ["request"] * 8
         assert {r["rid"] for r in records} == set(off)
         for r in records:
@@ -267,6 +271,66 @@ class TestTracedServing:
         blocks = [e["stats"]["block"] for e in events
                   if e["name"] == "serve_dispatch" and "block" in e["stats"]]
         assert max(blocks) == decode_block
+
+    @pytest.mark.parametrize("decode_block", [1, 3])
+    def test_every_dispatched_program_is_waited_on_by_one_fetch(self, params, host_capture, decode_block):
+        """Over a run to idle, the `serve_fetch` spans, numbered by `seq`,
+        name as many prefill chunks and decode steps as the engine
+        dispatched: each program is waited on by exactly one fetch."""
+        engine = _engine(params, decode_block=decode_block)
+        for r in _requests(5, seed=2):
+            engine.submit_request(r)
+        events = host_capture(engine.run_until_idle)
+        fetches = [e["stats"] for e in events if e["name"] == "serve_fetch"]
+        assert [f["seq"] for f in fetches] == list(range(1, len(fetches) + 1))
+        assert sum(f["chunks"] for f in fetches) == engine.stats["prefill_chunks"] > len(_requests(5, seed=2))
+        assert sum(f["decodes"] for f in fetches) == engine.stats["decode_steps"]
+        if decode_block > 1:
+            assert max(f["decodes"] for f in fetches) > 1
+
+    def test_a_stalled_fetch_is_counted_and_joins_its_span(self, params, host_capture, monkeypatch):
+        """A fetch that waits 0.2 s longer than the same work usually does is
+        counted, and its `fetch_stall` record carries its span's `seq`. Other
+        waits of a loaded machine may be flagged too: only the injected one
+        is asserted."""
+        engine = _engine(params)
+        reqs = lambda seed: [
+            serving.Request(prompt=np.arange(1, 9, dtype=np.int32) + seed, max_new_tokens=16, rid=-1, seed=seed)
+            for seed in range(seed, seed + 4)
+        ]
+        for r in reqs(0):  # compiles; a prompt of one chunk, so a decode fetch waits on "decode" alone
+            engine.submit_request(r)
+        engine.run_until_idle()
+        real, decode_fetches = jax.device_get, []
+
+        def slow(pending):
+            if isinstance(pending, list):  # the decode step's fetch
+                decode_fetches.append(1)
+                if len(decode_fetches) == 6:
+                    time.sleep(0.2)
+            return real(pending)
+
+        monkeypatch.setattr(jax, "device_get", slow)
+        for r in reqs(10):
+            engine.submit_request(r)
+        events = host_capture(engine.run_until_idle)
+        (slow_span,) = [e for e in events if e["name"] == "serve_fetch" and e["end"] - e["start"] >= 0.2e9]
+        stalls = {r["attrs"]["seq"]: r for r in _spans_by_name("fetch_stall")}
+        record = stalls[slow_span["stats"]["seq"]]
+        assert record["attrs"]["key"] == "decode" and slow_span["stats"]["decodes"] == 1
+        assert record["attrs"]["wait_ms"] >= 200.0 > 1.5 * record["attrs"]["usual_ms"]
+        assert record["t1"] - record["t0"] == pytest.approx(record["attrs"]["wait_ms"] / 1e3)
+        assert record["attrs"]["cpu_ms"] >= 0.0 and record["attrs"]["engine"] == engine.stats.instance
+        assert engine.stats["stalled_fetches"] == len(stalls) >= 1
+        assert engine.stats["stalled_fetch_ms"] == pytest.approx(
+            sum(r["attrs"]["wait_ms"] - r["attrs"]["usual_ms"] for r in stalls.values())
+        )
+        exported = {
+            m["name"]: s["value"]
+            for m in telemetry.snapshot()["metrics"] if m["name"] in ("serve_stalled_fetches", "serve_stalled_fetch_ms")
+            for s in m["series"] if s["labels"] == engine.stats.labels
+        }
+        assert exported == {"serve_stalled_fetches": len(stalls), "serve_stalled_fetch_ms": engine.stats["stalled_fetch_ms"]}
 
     def test_request_lifecycle_spans_present(self, params):
         with patch_environment(ATX_TRACE_REQUESTS="1"):
