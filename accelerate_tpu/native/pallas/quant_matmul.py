@@ -22,7 +22,13 @@ prefill, C = 14336, does not fit VMEM whole):
   nothing) and the elementwise ops replicate `quantize_act` literally; the
   one divergence from the fallback is the activation-scale divide, which
   Pallas lowers with TPU semantics (reciprocal-multiply, 1 ulp off IEEE) —
-  parity is ~1e-7 relative, not bitwise. The weight may be a layer stack
+  parity is ~1e-7 relative, not bitwise. Where those rows would be split
+  into several row tiles (a 1024-row prefill chunk), every weight tile
+  would be read once a row tile and every activation tile quantized once a
+  column tile: there `quantize_act` runs once, in XLA, and the kernel takes
+  the int8 rows as one row tile (:func:`one_row_tile_plan`) — each weight
+  byte read once, the kernel only the integer dot and the rescale, and the
+  output equal to the fallback's bit for bit. The weight may be a layer stack
   ``(L, ...)`` read in place at a traced ``layer``: the index rides scalar
   prefetch and the weight block's index map picks the layer, so a scan over
   layers hands the kernel the whole stack and no layer is sliced out of it
@@ -42,6 +48,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ...ops.int8 import quantize_act
 from .dispatch import kernel_mode, pallas_available, register_kernel
 from .moe_experts import min_tile_rows
 
@@ -118,11 +125,13 @@ def _tile(dim: int, unit: int, pick) -> int:
     return blk if blk is not None and blk % unit == 0 else dim
 
 
-def _plan(eq: str, a, b, out_dtype):
+def _plan(eq: str, a, b, out_dtype, *, quantized_rows: bool = False):
     """2D views + tiles for ``eq``:
     ``(oa, ob, M, N, C, bm, bn, bc, a_rest, b_rest)`` (the output shape is
     ``a_rest + b_rest``), or ``None`` when the equation is not a matmul or
-    no tiling fits the VMEM budget."""
+    no tiling fits the VMEM budget. With ``quantized_rows``, ``a`` holds
+    int8 rows quantized before the call, taken as one row tile: the kernel
+    stages no quantization temporaries."""
     parsed = _parse_matmul_eq(eq)
     if parsed is None:
         return None
@@ -138,31 +147,57 @@ def _plan(eq: str, a, b, out_dtype):
         return None
     # M is the sublane axis of the output tile, and the lane axis of a
     # leading-contracted (C, M) operand view.
-    bm = _tile(M, 128 if oa == "lead" else 8, pick_block)
+    bm = M if quantized_rows else _tile(M, 128 if oa == "lead" else 8, pick_block)
     bn = _tile(N, 128, pick_block)
     bc = _tile(C, 128, lambda d: _divisor(d, _CONTRACT_BLOCKS))
     a_item, b_item = jnp.dtype(a.dtype).itemsize, jnp.dtype(b.dtype).itemsize
     out_item = jnp.dtype(out_dtype).itemsize
 
-    def staged(bn):
+    def staged(bn, bc):
         return (
-            2 * bm * bc * a_item
+            # int8 rows of the whole array are one block, staged once
+            (1 if quantized_rows and bc == C else 2) * bm * bc * a_item
             + 2 * bc * bn * b_item
             + 2 * bm * bn * out_item
             + bm * bn * 4  # accumulator scratch
-            + bm * bc * 9  # in-kernel quantization temporaries (2 x f32 + int8)
+            # in-kernel quantization temporaries (2 x f32 + int8)
+            + (0 if quantized_rows else bm * bc * 9)
         )
 
-    if bm == M and bn % 128 == 0:
+    if quantized_rows:
+        # One row tile of int8 rows: the widest contraction tile the budget
+        # holds beside `pick_block`'s weight tile. Each tile then adds to the
+        # int32 accumulator fewer times; on a v5e that, not the weight
+        # tile's width, set the time (PERF.md §6: 1024 x 4096 x 14336
+        # in 0.352 ms at (bn, bc) = (512, 4096), 0.410 at (512, 2048), 0.418
+        # at (1024, 1024); 1024 x 14336 x 4096 in 0.470 at (512, 2048), 0.478
+        # at (1024, 1024), 0.504 at (256, 2048)).
+        fits = [d for d in range(128, C + 1, 128) if C % d == 0 and staged(bn, d) <= _VMEM_BUDGET]
+        bc = max(fits, default=bc)
+    elif bm == M and bn % 128 == 0:
         # One row tile: every weight byte is read once, from HBM, and the
         # (bc, bn) tile is the DMA that reads it. As wide as the budget
         # holds: 16 grid steps of 3.7 MB for a 4096 x 14336 matrix, not 112
         # of 0.5 MB whose fixed cost a step is half their transfer time.
-        wider = [n for n in range(bn, N + 1, 128) if N % n == 0 and staged(n) <= _VMEM_BUDGET]
+        wider = [n for n in range(bn, N + 1, 128) if N % n == 0 and staged(n, bc) <= _VMEM_BUDGET]
         bn = max(wider, default=bn)
-    if staged(bn) > _VMEM_BUDGET:
+    if staged(bn, bc) > _VMEM_BUDGET:
         return None
     return oa, ob, M, N, C, bm, bn, bc, tuple(a_rest), tuple(b_rest)
+
+
+def one_row_tile_plan(eq: str, x, w):
+    """The plan of `int8_matmul_fused` over int8 rows quantized once, taken
+    as one row tile, where ``x``'s own plan would split its rows into
+    several row tiles; else ``None`` (``x``'s plan stands). ``w`` is one
+    layer's weight. A shape rule: only the row count can make it engage,
+    and only where the one row tile's contraction tile is no narrower than
+    the split plan's (the accumulator is rewritten no more often)."""
+    plan = _plan(eq, x, w, x.dtype)
+    if plan is None or plan[0] != "trail" or plan[5] == plan[2]:  # bm == M
+        return None
+    wide = _plan(eq, jax.ShapeDtypeStruct(x.shape, jnp.int8), w, x.dtype, quantized_rows=True)
+    return wide if wide is not None and wide[7] >= plan[7] else None
 
 
 def _views(oa, ob, a, b, M, N, C):
@@ -218,10 +253,14 @@ def _int8_kernel(layer_ref, a_ref, sx_ref, b_ref, ws_ref, o_ref, acc_ref, *, dim
     del layer_ref  # only the weight's block index map reads it
     # `quantize_act`'s rounding verbatim on one (bm, bc) tile against the
     # row scale, then an exact integer dot; only the scale divide (TPU
-    # reciprocal semantics) can differ from the fallback, by 1 ulp.
+    # reciprocal semantics) can differ from the fallback, by 1 ulp. Rows
+    # that come quantized (int8, by `quantize_act` itself) go to the dot
+    # as they are: the output is then the fallback's, bit for bit.
     sx = sx_ref[...]  # (bm, 1)
-    xf = a_ref[...].astype(jnp.float32)
-    q = jnp.clip(jnp.round(xf / sx), -127, 127).astype(jnp.int8)
+    q = a_ref[...]
+    if q.dtype != jnp.int8:
+        xf = q.astype(jnp.float32)
+        q = jnp.clip(jnp.round(xf / sx), -127, 127).astype(jnp.int8)
     _accumulate(
         acc_ref,
         jax.lax.dot_general(q, b_ref[...], dims, preferred_element_type=jnp.int32),
@@ -277,10 +316,13 @@ def int8_matmul_fused(
     trailing axes (per-row groups = rows of the 2D view) and w on its
     leading axes — true for every int8 forward equation. With ``layer``,
     ``wq`` is a stack ``(L, ...)`` of such weights, read in place at that
-    (traced) index; ``w_scale`` is the one layer's either way. ``None`` when
-    the equation/shapes aren't supported (caller falls back)."""
+    (traced) index; ``w_scale`` is the one layer's either way. Rows that
+    would be split into several row tiles are quantized here, once, and
+    taken as one (:func:`one_row_tile_plan`). ``None`` when the
+    equation/shapes aren't supported (caller falls back)."""
     w_shape = wq.shape if layer is None else wq.shape[1:]
-    plan = _plan(eq, x, jax.ShapeDtypeStruct(w_shape, wq.dtype), x.dtype)
+    w = jax.ShapeDtypeStruct(w_shape, wq.dtype)
+    plan = _plan(eq, x, w, x.dtype)
     if plan is None:
         return None
     oa, ob, M, N, C, bm, bn, bc, a_rest, b_rest = plan
@@ -288,7 +330,14 @@ def int8_matmul_fused(
         return None
     if layer is not None and not _view_is_free(w_shape, len(b_rest), wq.dtype):
         return None  # (L, C, N) would be a relayout of the whole stack, every call
-    x2 = x.reshape(M, C)
+    wide = one_row_tile_plan(eq, x, w)
+    if wide is None:
+        x2 = x.reshape(M, C)
+    else:
+        plan = wide
+        oa, ob, M, N, C, bm, bn, bc, a_rest, b_rest = plan
+        qx, sx = quantize_act(x, tuple(range(len(a_rest), x.ndim)))
+        x2, sx = qx.reshape(M, C), sx.reshape(M, 1)
     a_spec, w_spec = _specs(oa, ob, bm, bn, bc)
     if layer is None:
         w_view = wq.reshape(C, N)  # a lone matrix: the operand it always was
@@ -296,9 +345,10 @@ def int8_matmul_fused(
         w_view = wq.reshape(-1, C, N)
         w_spec = pl.BlockSpec((None, bc, bn), lambda i, j, c, ly: (ly[0], c, j))
     layer = jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1)
-    # `quantize_act`'s per-row scale, over the whole contraction.
-    amax = jnp.max(jnp.abs(x2.astype(jnp.float32)), axis=1, keepdims=True)
-    sx = jnp.maximum(amax, 1e-12) / 127.0
+    if wide is None:
+        # `quantize_act`'s per-row scale, over the whole contraction.
+        amax = jnp.max(jnp.abs(x2.astype(jnp.float32)), axis=1, keepdims=True)
+        sx = jnp.maximum(amax, 1e-12) / 127.0
     # w_scale has w's rank: contracted axes are size 1 (quantizer keepdims)
     # and so is any output axis that shares one scale (per-head weights
     # quantize per head_dim channel) — broadcast to the per-column vector.

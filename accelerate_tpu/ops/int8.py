@@ -172,7 +172,9 @@ def record_weight_paths():
     """Collect how every quantized contraction traced inside the block got
     its weights: ``"in_place"`` (the kernel read the layer out of the stack
     where it lies) or ``"sliced"`` (it, or the XLA fallback, was handed one
-    layer's matrix). Trace-time bookkeeping only, as
+    layer's matrix); after either, ``"act_quantized_once"`` where the kernel
+    took its rows as one row tile of int8 activations quantized once
+    (`quant_matmul.one_row_tile_plan`). Trace-time bookkeeping only, as
     `models.layers.record_attention_paths`: the serving engine wraps its
     programs' traces in it, so a silent fall to the slice shows in
     ``Engine.stats['weights_sliced']``."""
@@ -245,13 +247,17 @@ def int8_einsum(
     When the `int8_matmul` Pallas kernel is enabled (`native/pallas/`),
     the quantize -> dot -> rescale runs as one fused kernel — integer
     accumulation exact, parity within 1 ulp of the activation scale —
-    without the intermediate HBM round-trips. With ``layer``, ``wq`` is a
+    without the intermediate HBM round-trips; rows it would split into
+    several row tiles are quantized once, in XLA, by :func:`quantize_act`,
+    and the kernel's dot and rescale equal this fallback's bit for bit
+    (``"act_quantized_once"`` in `record_weight_paths`). With ``layer``, ``wq`` is a
     layer stack ``(L, ...)`` that the kernel reads in place at that index
     (``w_scale`` is the layer's own); where it cannot (kernel off, or the
     kernel's 2D view of this stack would be a relayout), the layer is
     sliced out here and everything goes on as for a lone matrix."""
-    from ..native.pallas.quant_matmul import maybe_int8_matmul
+    from ..native.pallas.quant_matmul import maybe_int8_matmul, one_row_tile_plan
 
+    w = jax.ShapeDtypeStruct(wq.shape if layer is None else wq.shape[1:], wq.dtype)
     out = None
     if layer is not None:
         out = maybe_int8_matmul(eq, x, wq, w_scale, layer)
@@ -263,6 +269,8 @@ def int8_einsum(
     if out is None:
         out = maybe_int8_matmul(eq, x, wq, w_scale)
     if out is not None:
+        if paths is not None and one_row_tile_plan(eq, x, w) is not None:
+            paths.append("act_quantized_once")
         return out
     qx, sx = quantize_act(x, _x_contracted_axes(eq))
     acc = jnp.einsum(eq, qx, wq, preferred_element_type=jnp.int32)

@@ -204,8 +204,8 @@ class _Slot:
         self.occ_n = 0
 
 
-def _in_place_and_sliced(paths: list[str]) -> tuple[int, int]:
-    return paths.count("in_place"), paths.count("sliced")
+def _weight_path_counts(paths: list[str]) -> tuple[int, int, int]:
+    return paths.count("in_place"), paths.count("sliced"), paths.count("act_quantized_once")
 
 
 class Engine:
@@ -382,7 +382,7 @@ class Engine:
             self.stats["decode_in_place"] = int(
                 bool(paths) and all(p == "in_place" for p in paths)
             )
-            self._weight_paths["decode"] = _in_place_and_sliced(weights)
+            self._weight_paths["decode"] = _weight_path_counts(weights)
             nxt = jax.vmap(_sample)(logits[:, -1, :], seeds, steps)
             # What the forward counted (an expert layer's routing; nothing
             # for a dense model) leaves with the tokens: one fetch a step.
@@ -412,7 +412,7 @@ class Engine:
             ):
                 logits, new = apply_fn(params, tokens, cache)
             S = tokens.shape[1]
-            self._weight_paths[S] = _in_place_and_sliced(weights)
+            self._weight_paths[S] = _weight_path_counts(weights)
             self._attention_paths[S] = (paths.count("in_place"), paths.count("sliced"))
             # Rows the head projects on a prompt's last chunk and on any
             # other: every row, every time, where the forward ignored the key.
@@ -426,8 +426,9 @@ class Engine:
 
         # Per program ("decode", or a prefill bucket's rows), as traced: how
         # many of its quantized contractions read their weight stack in
-        # place, and how many were handed a slice.
-        self._weight_paths: dict[Any, tuple[int, int]] = {}
+        # place, how many were handed a slice, and how many took their rows
+        # as one row tile of int8 activations quantized once.
+        self._weight_paths: dict[Any, tuple[int, int, int]] = {}
         # Per prefill bucket, as traced: how many layers' attention over the
         # cache a kernel computes on the stack where it lies (up to the
         # cursor), and how many slice their layer out (a ring's included).
@@ -550,6 +551,11 @@ class Engine:
                 # one layer's matrix sliced out of it (a copy a layer).
                 "weights_in_place",
                 "weights_sliced",
+                # Of those, the contractions whose rows the kernel took as one
+                # row tile of int8 activations quantized once, in XLA: those
+                # it would otherwise split into several row tiles (a 1024-row
+                # chunk's seven; none at 512 rows or fewer).
+                "int8_act_quantized_once",
                 # Layers of the prefill chunks dispatched whose attention
                 # over the cache the flash-prefill kernel computed on the
                 # stack where it lies, and those that sliced their layer out
@@ -1195,9 +1201,10 @@ class Engine:
 
     def _count_paths(self, program: Any) -> None:
         """One dispatch of ``program``: add what its trace recorded."""
-        in_place, sliced = self._weight_paths.get(program, (0, 0))
+        in_place, sliced, quantized_once = self._weight_paths.get(program, (0, 0, 0))
         self.stats["weights_in_place"] += in_place
         self.stats["weights_sliced"] += sliced
+        self.stats["int8_act_quantized_once"] += quantized_once
         in_place, sliced = self._attention_paths.get(program, (0, 0))  # prefill buckets only
         self.stats["prefill_attn_in_place"] += in_place
         self.stats["prefill_attn_sliced"] += sliced

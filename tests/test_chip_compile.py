@@ -184,6 +184,17 @@ KERNELS = {
     "int8_matmul_stack_prefill": (
         _int8_matmul_stack, [((1024, D), BF16), ((32, D, FF), I8), ((1, FF), F32), ((), I32)], ["int8_matmul"],
     ),
+    # The long cell's 1024-row chunk: one row tile of rows quantized once,
+    # inside Mosaic's default scoped VMEM.
+    "int8_matmul_prefill_1024_4096x14336": (
+        _int8_matmul, [((1024, D), BF16), ((D, FF), I8), ((1, FF), F32)], ["int8_matmul"],
+    ),
+    "int8_matmul_prefill_1024_14336x4096": (
+        _int8_matmul, [((1024, FF), BF16), ((FF, D), I8), ((1, D), F32)], ["int8_matmul"],
+    ),
+    "int8_matmul_stack_prefill_1024_14336x4096": (
+        _int8_matmul_stack, [((1024, FF), BF16), ((32, FF, D), I8), ((1, D), F32), ((), I32)], ["int8_matmul"],
+    ),
     "fp8_scaled_matmul": (_fp8_matmul, [((2048, D), F8), ((D, FF), F8), ((), F32)], ["scaled_matmul"]),
     "fused_adamw_leaf": (_adamw, [_LEAF, _LEAF, _LEAF, _LEAF, ((), I32), ((), F32)], ["fused_adamw"]),
     # Three 3.9 MB weight blocks an expert, double-buffered: past Mosaic's
@@ -535,14 +546,14 @@ def test_engine_reads_int8_weight_stacks_in_place_on_v5e(v5e, monkeypatch, progr
 
     one_matrix = rf"^(1,)?({D},{FF}|{FF},{D}|{H},{HD},{D})$"
     text, paths = compile_program()
-    assert paths == (4, 3)  # in place, sliced
+    assert paths == (4, 3, 0)  # in place, sliced; no rows quantized once at 256 or fewer
     assert len(set(re.findall(r"%(int8_matmul[.\d]*) = ", text))) == 7
     assert _s8_results(text, one_matrix) == []
     assert _s8_results(text, "^8,") == []  # no stack is copied into the loop
     assert len(_s8_results(text, rf"^{D},{D}$")) == 1  # wq: sliced and laid out anew
     monkeypatch.setattr(llama, "hoist_layer_stacks", lambda blocks: (blocks, {}))
     text, paths = compile_program()
-    assert paths == (0, 7)
+    assert paths == (0, 7, 0)
     assert len(_s8_results(text, one_matrix)) >= 4
 
 
@@ -591,6 +602,54 @@ def test_long_cell_prefill_chunk_holds_no_scores_of_the_whole_slot_on_v5e(v5e, m
     results = re.findall(r"= (?:bf16|f32|pred)\[([0-9,]+)\]", text)
     as_large = [dims for dims in results if np.prod([int(d) for d in dims.split(",")]) >= scores]
     assert bool(as_large) != bool(in_place), as_large[:5]
+
+
+def test_long_cell_prefill_chunk_quantizes_each_activation_once_on_v5e(v5e, monkeypatch):
+    """The long cell's 1024-row prefill program (int8 weights at the Mistral
+    widths, two layers), compiled for the described chip: each of the seven
+    `int8_matmul` calls of the layer body takes int8 rows, and those come
+    from four passes, one for each distinct activation (`wq`/`wk`/`wv` share
+    the attention norm's, gate and up the feed-forward norm's), each fused
+    with what produces its activation; the engine counts the seven."""
+    from accelerate_tpu import serving
+    from accelerate_tpu.generation import GenerationConfig
+    from accelerate_tpu.native.pallas import dispatch
+    from accelerate_tpu.ops.int8 import with_int8_compute
+    from accelerate_tpu.utils.quantization import quantize_pytree
+
+    rows, slot_len, layers = 1024, 8192, 2
+    monkeypatch.setattr(dispatch, "_on_tpu", lambda: True)
+    monkeypatch.setenv("ATX_SERVE_CAPACITY_CHECK", "off")
+    monkeypatch.setattr(serving.engine.jax, "device_put", lambda x, device=None: x)  # shapes only
+    cfg = llama.LlamaConfig(
+        vocab_size=512, d_model=D, n_layers=layers, num_heads=H, num_kv_heads=K, head_dim=HD,
+        d_ff=FF, max_seq_len=slot_len,
+    )
+    params = jax.eval_shape(lambda: quantize_pytree(llama.init(jax.random.PRNGKey(0), cfg, BF16)))
+    one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree
+    )
+    engine = serving.Engine(
+        with_int8_compute(lambda p, t, c: llama.forward_with_cache(p, t, c, cfg)),
+        lambda b, m: jax.eval_shape(lambda: llama.init_cache(cfg, b, m)),
+        params, GenerationConfig(), slots=2, buckets=(rows,), max_len=slot_len, prefix_cache=False,
+    )
+    decode_args = on_chip(engine.abstract_decode_args())
+    scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=one_chip)
+    chunk = jax.ShapeDtypeStruct((1, rows), I32, sharding=one_chip)
+    args = (decode_args[0], chunk, decode_args[3], *map(scalar, (I32, I32, I32, jnp.uint32, jnp.bool_)))
+    with force_kernels("on"):
+        text = jax.jit(engine._prefill_fn, donate_argnums=(2,)).lower(*args).compile().as_text()
+    assert engine._weight_paths[rows] == (4, 3, 7)
+    # A kernel's operands: the layer index, the int8 rows, their scales, ...
+    rows_in = dict(re.findall(r"%(int8_matmul[.\d]*) = \S+ custom-call\(%[\w.\-]+, %([\w.\-]+),", text))
+    assert len(rows_in) == 7 and len(set(rows_in.values())) == 4
+    for name in set(rows_in.values()):
+        fused = re.search(rf"%{re.escape(name)} = s8\[{rows},\d+\]\S* fusion\(.*calls=%([\w.\-]+)", text)
+        assert fused is not None, name
+        body = text[text.index(f"%{fused.group(1)} ("):]
+        assert "round-nearest-even(" in body[: body.index("\n}")]
 
 
 def test_every_pallas_call_in_the_package_is_named():

@@ -481,3 +481,153 @@ class TestLlamaReadsItsStacksInPlace:
             )
         assert paths == ["sliced"] * 7  # one jitted layer step, traced once
         assert logits.shape == (2, 6, 64) and bool(jnp.isfinite(logits).all())
+
+
+class TestRowsQuantizedOnce:
+    """Rows that `_plan` would split into several row tiles (M > 512) are
+    quantized once by `quantize_act` and taken by `int8_matmul` as one row
+    tile: the same integers and the same rescale as the XLA fallback."""
+
+    @staticmethod
+    def _weights(c, n, layers=None):
+        lead = () if layers is None else (layers,)
+        return quantize_array(jax.random.normal(jax.random.PRNGKey(1), lead + (c, n)))
+
+    @pytest.mark.parametrize("c, n", [(256, 512), (512, 256)])
+    def test_a_lone_matrix_equals_the_fallback_bitwise(self, c, n):
+        from accelerate_tpu.native.pallas import quant_matmul
+        from accelerate_tpu.native.pallas.dispatch import force_kernels
+        from accelerate_tpu.ops.int8 import record_weight_paths
+
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 512, c), jnp.bfloat16)
+        node = self._weights(c, n)
+        w = jax.ShapeDtypeStruct((c, n), jnp.int8)
+        assert quant_matmul.one_row_tile_plan("bsd,df->bsf", x, w)[5] == 1024
+        with force_kernels("interpret"), record_weight_paths() as paths:
+            got = jax.jit(lambda x: int8_einsum_quantized("bsd,df->bsf", x, node))(x)
+        assert paths == ["sliced", "act_quantized_once"]
+        with force_kernels("off", "int8_matmul"):
+            want = int8_einsum_quantized("bsd,df->bsf", x, node)
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+    @pytest.mark.parametrize("c, n", [(256, 512), (512, 256)])
+    def test_a_stack_read_in_place_equals_the_fallback_bitwise(self, c, n):
+        from accelerate_tpu.native.pallas.dispatch import force_kernels
+        from accelerate_tpu.ops.int8 import _LAYER_KEY, record_weight_paths
+
+        layers = 3
+        xs = jax.random.normal(jax.random.PRNGKey(0), (layers, 1024, c), jnp.bfloat16)
+        node = self._weights(c, n, layers)
+
+        @jax.jit
+        def scanned(stack, scales, xs):
+            def body(i, layer_in):
+                scale, x = layer_in
+                layer = {"__quant__": stack, "scale": scale, _LAYER_KEY: i}
+                return i + 1, int8_einsum_quantized("md,df->mf", x, layer)
+
+            return jax.lax.scan(body, jnp.zeros((), jnp.int32), (scales, xs))[1]
+
+        with force_kernels("interpret"), record_weight_paths() as paths:
+            got = scanned(node["__quant__"], node["scale"], xs)
+        assert paths == ["in_place", "act_quantized_once"]
+        with force_kernels("off", "int8_matmul"):
+            for i in range(layers):
+                one = {"__quant__": node["__quant__"][i], "scale": node["scale"][i]}
+                want = int8_einsum_quantized("md,df->mf", xs[i], one)
+                np.testing.assert_array_equal(
+                    np.asarray(got[i], np.float32), np.asarray(want, np.float32)
+                )
+
+    @pytest.mark.parametrize(
+        "rows, c, n, tiles",
+        [
+            (32, 4096, 14336, (32, 3584, 1024)),  # the chat cell's decode step
+            (256, 4096, 14336, (256, 2048, 1024)),  # a 256-row chunk
+            (256, 14336, 4096, (256, 2048, 1024)),
+            (512, 4096, 14336, (512, 896, 1024)),
+        ],
+    )
+    def test_up_to_512_rows_the_plan_is_as_it_was(self, rows, c, n, tiles):
+        from accelerate_tpu.native.pallas import quant_matmul
+
+        x = jax.ShapeDtypeStruct((rows, c), jnp.bfloat16)
+        w = jax.ShapeDtypeStruct((c, n), jnp.int8)
+        assert quant_matmul._plan("mc,cn->mn", x, w, jnp.bfloat16) == (
+            "trail", "lead", rows, n, c, *tiles, (rows,), (n,)
+        )
+        assert quant_matmul.one_row_tile_plan("mc,cn->mn", x, w) is None
+
+    @pytest.mark.parametrize(
+        "c, n, tiles",
+        [
+            (4096, 14336, (512, 4096)),  # gate, up: the whole contraction a tile
+            (14336, 4096, (512, 2048)),  # down
+            (4096, 4096, (512, 4096)),  # wq, wo
+            (4096, 1024, (512, 4096)),  # wk, wv
+        ],
+    )
+    def test_1024_rows_are_one_row_tile_within_the_budget(self, c, n, tiles):
+        from accelerate_tpu.native.pallas import quant_matmul
+
+        w = jax.ShapeDtypeStruct((c, n), jnp.int8)
+        x = jax.ShapeDtypeStruct((1024, c), jnp.bfloat16)
+        assert quant_matmul._plan("mc,cn->mn", x, w, jnp.bfloat16)[5] == 512  # two row tiles
+        plan = quant_matmul.one_row_tile_plan("mc,cn->mn", x, w)
+        assert plan[2:8] == (1024, n, c, 1024, *tiles)
+        q = jax.ShapeDtypeStruct((1024, c), jnp.int8)
+        assert quant_matmul._plan("mc,cn->mn", q, w, jnp.bfloat16, quantized_rows=True) == plan
+        # At 2048 rows one row tile would leave a narrower contraction tile
+        # than the split plan's: the split plan stands.
+        x = jax.ShapeDtypeStruct((2048, c), jnp.bfloat16)
+        assert quant_matmul.one_row_tile_plan("mc,cn->mn", x, w) is None
+
+    @pytest.mark.parametrize("rows, once", [(1024, 7), (32, 0)])
+    def test_a_llama_block_records_its_seven_contractions(self, rows, once):
+        """A llama forward traced at ``rows`` rows a chunk: each of the layer
+        body's seven contractions (traced once for every layer) takes its
+        rows quantized once at 1024 rows and none at 32, beside the 4 : 3
+        of the weights' paths."""
+        from accelerate_tpu.models import llama
+        from accelerate_tpu.native.pallas.dispatch import force_kernels
+        from accelerate_tpu.ops.int8 import record_weight_paths, with_int8_compute
+        from accelerate_tpu.utils.quantization import quantize_pytree
+
+        cfg = llama.LlamaConfig.tiny(vocab_size=64, head_dim=32, n_layers=2, max_seq_len=rows)
+        params = quantize_pytree(llama.init(jax.random.PRNGKey(0), cfg), min_size=512)
+        step = jax.jit(with_int8_compute(lambda p, t, c: llama.forward_with_cache(p, t, c, cfg)))
+        toks = jnp.zeros((1, rows), jnp.int32)
+        with force_kernels("interpret"), record_weight_paths() as paths:
+            jax.eval_shape(step, params, toks, llama.init_cache(cfg, 1, rows))
+        assert (paths.count("in_place"), paths.count("sliced")) == (4, 3)
+        assert paths.count("act_quantized_once") == once
+
+    def test_a_llama_chunk_of_1024_rows_equals_the_fallback_bitwise(self):
+        """The whole forward of a 1024-row chunk through a layer scan that
+        reads the stacks in place: with every contraction on the one-row-tile
+        path, the logits and the cache are the XLA fallback's, bit for bit
+        (every other kernel the same on both sides)."""
+        from accelerate_tpu.models import llama
+        from accelerate_tpu.native.pallas.dispatch import force_kernels
+        from accelerate_tpu.ops.int8 import record_weight_paths, with_int8_compute
+        from accelerate_tpu.utils.quantization import quantize_pytree
+
+        cfg = llama.LlamaConfig.tiny(vocab_size=64, head_dim=32, n_layers=2, max_seq_len=1024)
+        params = quantize_pytree(llama.init(jax.random.PRNGKey(0), cfg), min_size=512)
+        toks = jax.random.randint(jax.random.PRNGKey(1), (1, 1024), 0, 64, jnp.int32)
+
+        def run():
+            # A new function object: the trace cache must not hand back a
+            # program traced under another kernel mode.
+            step = jax.jit(with_int8_compute(lambda p, t, c: llama.forward_with_cache(p, t, c, cfg)))
+            logits, cache = step(params, toks, llama.init_cache(cfg, 1, 1024))
+            return np.asarray(logits, np.float32), np.asarray(cache["k"], np.float32)
+
+        with force_kernels("interpret"):
+            with record_weight_paths() as paths:
+                got = run()
+            assert paths.count("act_quantized_once") == 7
+            with force_kernels("off", "int8_matmul"):
+                want = run()
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(a, b)

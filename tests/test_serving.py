@@ -307,26 +307,36 @@ class TestCompileDiscipline:
             assert eng._kv_rows_fetched(full - [0, 0, 16], 64) == 3 * 64 - 16
 
     @pytest.mark.parametrize(
-        "weights, mode, in_place, sliced",
-        [("int8", "interpret", 4, 3), ("int8", "off", 0, 7), ("bf16", "interpret", 0, 0)],
+        "weights, mode, buckets, in_place, sliced, quantized_once",
+        [
+            ("int8", "interpret", (8, 16), 4, 3, 0),
+            ("int8", "off", (8, 16), 0, 7, 0),
+            ("bf16", "interpret", (8, 16), 0, 0, 0),
+            ("int8", "interpret", (1024,), 4, 3, 7),
+        ],
     )
-    def test_stats_count_how_programs_get_their_int8_weights(self, weights, mode, in_place, sliced):
+    def test_stats_count_how_programs_get_their_int8_weights(
+        self, weights, mode, buckets, in_place, sliced, quantized_once
+    ):
         """Every dispatch of a decode step or a prefill chunk adds how many of
         its quantized contractions read the layer stack in place and how many
         were handed a slice, as its trace recorded them: a silent fall to the
-        slice path shows among the counters."""
+        slice path shows among the counters. A 1024-row chunk's seven take
+        their rows as one row tile quantized once; a decode step's none."""
+        from accelerate_tpu import telemetry
         from accelerate_tpu.native.pallas.dispatch import force_kernels
         from accelerate_tpu.ops.int8 import with_int8_compute
         from accelerate_tpu.utils.quantization import quantize_pytree
 
-        cfg = llama.LlamaConfig.tiny(vocab_size=61, max_seq_len=64, head_dim=32)
+        max_len = max(64, *buckets)
+        cfg = llama.LlamaConfig.tiny(vocab_size=61, max_seq_len=max_len, head_dim=32)
         params = llama.init(jax.random.PRNGKey(1), cfg)
         if weights == "int8":
             params = quantize_pytree(params, min_size=512)
         apply_fn = with_int8_compute(lambda p, t, c: llama.forward_with_cache(p, t, c, cfg))
         eng = serving.Engine(
             apply_fn, lambda b, m: llama.init_cache(cfg, b, m), params, GenerationConfig(),
-            slots=2, buckets=(8, 16), max_len=64,
+            slots=2, buckets=buckets, max_len=max_len,
         )
         requests = [
             serving.Request(prompt=np.arange(1, 1 + n, dtype=np.int32), max_new_tokens=4)
@@ -334,10 +344,18 @@ class TestCompileDiscipline:
         ]
         with force_kernels(mode):
             done = eng.serve(requests)
-        dispatched = eng.stats["decode_steps"] + eng.stats["prefill_chunks"]
-        assert eng.stats["prefill_chunks"] == 3 and eng.stats["decode_steps"] >= 3
+        chunks = eng.stats["prefill_chunks"]
+        dispatched = eng.stats["decode_steps"] + chunks
+        assert chunks == (3 if buckets == (8, 16) else 2) and eng.stats["decode_steps"] >= 3
         assert eng.stats["weights_in_place"] == in_place * dispatched
         assert eng.stats["weights_sliced"] == sliced * dispatched
+        assert eng.stats["int8_act_quantized_once"] == quantized_once * chunks  # 0 a decode step
+        exported = [
+            s["value"]
+            for m in telemetry.snapshot()["metrics"] if m["name"] == "serve_int8_act_quantized_once"
+            for s in m["series"] if s["labels"] == eng.stats.labels
+        ]
+        assert exported == [quantized_once * chunks]
         assert [len(d.tokens) for d in done] == [4, 4]
 
 
